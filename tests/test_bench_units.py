@@ -5,7 +5,7 @@ produce them deserve the same pinning as product code. The key invariant:
 MFU must use ONE FLOPs convention across plain/fused/accum variants of
 the same config — XLA's `cost_analysis` counts a `lax.scan` body once
 (not x trip count), which historically made the accum4 arm report MFU/4
-(BENCH_live r4: plain 0.110 vs accum4 0.025 at equal throughput).
+(plain 0.110 vs accum4 0.025 at equal throughput in an old capture).
 """
 
 import sys
@@ -13,6 +13,17 @@ import sys
 import pytest
 
 sys.path.insert(0, __file__.rsplit("/", 2)[0])
+
+
+@pytest.fixture(autouse=True)
+def _scratch_bench_history(tmp_path, monkeypatch):
+    """Every tiny section appends `tiny_*` rows to $BENCH_HISTORY_PATH
+    (default: the TRACKED BENCH_HISTORY.jsonl at the repo root). Tier-1
+    must not write into a tracked file: each test of this module gets a
+    scratch history; tests that assert on it set their own path."""
+    monkeypatch.setenv(
+        "BENCH_HISTORY_PATH", str(tmp_path / "BENCH_HISTORY.jsonl")
+    )
 
 
 @pytest.fixture(scope="module")

@@ -87,7 +87,7 @@ def test_cost_model_static_fallback_and_gauges():
     import jax.numpy as jnp
 
     reg = Registry()
-    cm = CostModel(registry=reg)
+    cm = CostModel.for_device("TPU v5 lite", registry=reg)
 
     class NoCosts:
         def cost_analysis(self):

@@ -1,7 +1,7 @@
 """Per-op device-time anatomy of a jax.profiler trace.
 
-Round-4's headline anatomy (docs/notes/NOTES_r04.md §"Headline trace anatomy") was
-parsed by hand; this makes the method repeatable: point it at a profiler
+An early headline anatomy was parsed by hand; this makes the method
+repeatable: point it at a profiler
 trace dir (the newest `plugins/profile/<ts>/` capture inside), and it
 prints mean device time per XLA op per step, sorted, with the step count
 inferred from the top-level module activity.

@@ -101,8 +101,8 @@ class _Block(nn.Module):
         current tokens, already projected by THIS block's kv projections —
         see TransformerCore); mask `[B, T, S]` bool; q_pos `[B, T]` int32.
 
-        `pallas_ctx` (dict with seg_q `[B, T]`, seg_ctx `[B, S]`, W,
-        interpret) routes the dense path through the fused Pallas kernel
+        `pallas_ctx` (dict with seg_q `[B, T]`, seg_ctx `[B, S]`, W)
+        routes the dense path through the fused Pallas kernel
         (ops/attention_pallas.py) — same parameters, same outputs, the
         mask derived in-kernel from the segment ids instead of being
         materialized."""
@@ -155,7 +155,6 @@ class _Block(nn.Module):
                 pallas_ctx["seg_q"],
                 pallas_ctx["seg_ctx"],
                 pallas_ctx["W"],
-                pallas_ctx["interpret"],
             ).reshape(B, T, D)
         else:
             k = k_ctx.reshape(B, -1, H, dh)  # rotary'd at projection
@@ -327,17 +326,10 @@ class TransformerCore(nn.Module):
         if use_pallas:
             # Loop-invariant (every layer sees the same segments/window),
             # so build it once like the einsum mask below.
-            from torched_impala_tpu.ops.vtrace import (
-                _default_backend_is_tpu,
-            )
-
             pallas_ctx = {
                 "seg_q": seg_q,
                 "seg_ctx": jnp.concatenate([state.kv_seg, seg_q], axis=1),
                 "W": W,
-                # Interpreter mode off-TPU so CPU tests/meshes run the
-                # same code path (mirrors vtrace_pallas).
-                "interpret": not _default_backend_is_tpu(),
             }
         mask = None
         if not sp and not use_pallas:

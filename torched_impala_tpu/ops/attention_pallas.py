@@ -59,6 +59,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from torched_impala_tpu.ops.pallas_util import pallas_call
+
 NEG_INF = -1e30
 _PAD_SEG = -2_147_483_000  # matches no real segment id (kv empty is -1)
 
@@ -248,7 +250,7 @@ def _tile_specs(Tb: int, Sb: int, dh: int, t_inner: bool):
     )
 
 
-def _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret: bool):
+def _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret):
     """Returns (out `[B, T, H, dh]` f32, lse `[B, H, Tp, 1]` f32)."""
     B, T, H, dh = q.shape
     S = k_ctx.shape[1]
@@ -284,7 +286,7 @@ def _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret: bool):
     q_spec, kv_spec, lse_spec, segq_spec, segc_spec = _tile_specs(
         Tb, Sb, dh, t_inner=False
     )
-    out, lse = pl.pallas_call(
+    out, lse = pallas_call(
         kernel,
         grid=(B, H, Tp // Tb, Sp // Sb),
         in_specs=[q_spec, kv_spec, kv_spec, segq_spec, segc_spec],
@@ -435,7 +437,7 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
     t_spec, s_spec, row_spec, segq_spec, segc_spec = _tile_specs(
         Tb, Sb, dh, t_inner=False
     )
-    dq = pl.pallas_call(
+    dq = pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, W=W, num_s=Sp // Sb
         ),
@@ -455,7 +457,7 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
     t_spec2, s_spec2, row_spec2, segq_spec2, segc_spec2 = _tile_specs(
         Tb, Sb, dh, t_inner=True
     )
-    dk, dv = pl.pallas_call(
+    dk, dv = pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, W=W, num_t=Tp // Tb
         ),
@@ -494,7 +496,7 @@ def _visibility(seg_q, seg_ctx, T: int, S: int, W: int):
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def windowed_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=False):
+def windowed_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None):
     """Masked single-device flash attention, Pallas-fused fwd + bwd.
 
     Args:
@@ -504,7 +506,9 @@ def windowed_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=False):
       seg_q: `[B, T]` int32 query segment (episode) ids.
       seg_ctx: `[B, S]` int32 context segment ids (-1 = empty cache slot).
       W: static int, number of cache slots at the front of the context.
-      interpret: run the kernels in interpreter mode (CPU tests).
+      interpret: None (default) compiles the kernels where the call is
+        lowered for a TPU and interprets them elsewhere
+        (ops/pallas_util.py); True/False forces one mode.
 
     Returns `[B, T, H, dh]` attention output in q's dtype (math in f32),
     differentiable w.r.t. q/k_ctx/v_ctx.
@@ -513,7 +517,7 @@ def windowed_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=False):
     return out.astype(q.dtype)
 
 
-def _fwd(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=False):
+def _fwd(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None):
     out, lse = _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret)
     # Residuals carry the f32 output (for D) + row logsumexp (for tile
     # probability recomputation) — O(T*dh + T) per (b, h), never [T, S].

@@ -19,8 +19,9 @@ contract XLA's TPU conv emitters use.
 `vtrace_pallas`-style analytic VJP in plain jnp: conv transposes are
 the same nine-shift matmuls with flipped shifts and transposed kernels
 (`_bwd` derives them in closed form), so autodiff never sees the Pallas
-call. Off-TPU the kernel runs in interpret mode (statically unrolled
-shifts, no `fori_loop`) — tier-1 exercises the kernel body on CPU.
+call. Lowered for anything but a TPU the kernel runs in interpret mode
+(ops/pallas_util.py; statically unrolled shifts, no `fori_loop`) —
+tier-1 exercises the kernel body on CPU.
 Parity against the flax reference block is pinned in
 tests/test_pallas_conv.py (f32 ulp-level tolerance).
 """
@@ -32,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from torched_impala_tpu.ops.vtrace import _default_backend_is_tpu
+from torched_impala_tpu.ops.pallas_util import pallas_call
 
 
 def _nine_shift(xp, k, h, w):
@@ -43,7 +44,15 @@ def _nine_shift(xp, k, h, w):
     acc = jnp.zeros((h * w, f), jnp.float32)
     for dy in range(3):
         for dx in range(3):
-            patch = xp[dy : dy + h, dx : dx + w, :].reshape(h * w, c)
+            # Flatten in f32: Mosaic has no [H, W, C] -> [H*W, C] shape
+            # cast for packed (bf16) vectors when W is not a multiple of
+            # the sublane tile; the round trip through f32 is exact.
+            patch = (
+                xp[dy : dy + h, dx : dx + w, :]
+                .astype(jnp.float32)
+                .reshape(h * w, c)
+                .astype(xp.dtype)
+            )
             acc = acc + jnp.dot(
                 patch, k[dy, dx], preferred_element_type=jnp.float32
             )
@@ -82,7 +91,7 @@ def _block_forward(x, k1, b1, k2, b2):
     img = lambda i: (i, 0, 0, 0)  # noqa: E731
     rep = lambda *_: (0,) * 4  # noqa: E731
     vec = lambda *_: (0,)  # noqa: E731
-    return pl.pallas_call(
+    return pallas_call(
         _residual_block_kernel,
         grid=grid,
         in_specs=[
@@ -96,7 +105,11 @@ def _block_forward(x, k1, b1, k2, b2):
         out_specs=pl.BlockSpec((1, h, w, c), img),
         out_shape=jax.ShapeDtypeStruct((n, h, w, c), dtype),
         scratch_shapes=[pltpu.VMEM((h + 2, w + 2, c), dtype)],
-        interpret=not _default_backend_is_tpu(),
+        # C=16/32 channels occupy 128-lane tiles, so one 42x42x16 image
+        # with its nine shifted patches needs ~21M of VMEM in f32 — more
+        # than the 16M a kernel is scoped to by default on a v5e (128M
+        # physical).
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
     )(x, xp, k1.astype(dtype), b1, k2.astype(dtype), b2)
 
 

@@ -301,6 +301,12 @@ def impala_loss(
             discounts=discounts,
             mask=mask,
             config=config,
+            # The fused path follows the same resolution as the separate
+            # V-trace: the Learner turns 'auto' into 'scan' on a
+            # multi-device mesh, and the kernel must give way with it.
+            implementation={"scan": "xla", "pallas": "kernel"}.get(
+                config.vtrace_implementation, "auto"
+            ),
         )
         if not config.health_diagnostics:
             return out

@@ -19,9 +19,16 @@ documented tolerance (<= 1e-6 absolute on unit-scale probes).
 `vtrace_pallas`-style analytic VJP: the forward saves the activated
 gates, the backward is closed-form elementwise algebra plus four plain
 matmuls (jnp — the XLA fallback precedent from `_fused_core_bwd`), so
-autodiff never differentiates through the kernel. Off-TPU the kernel
-runs in interpret mode (no `fori_loop` inside, so interpretation is a
-plain jnp evaluation) — tier-1 exercises the exact kernel body on CPU.
+autodiff never differentiates through the kernel. Lowered for anything
+but a TPU the kernel runs in interpret mode (ops/pallas_util.py; no
+`fori_loop` inside, so interpretation is a plain jnp evaluation) —
+tier-1 exercises the exact kernel body on CPU, and an actor pinned to
+the host CPU beside a TPU learner runs the same body.
+
+Fast memory: the grid runs over blocks of `_BLOCK_ROWS` batch rows with
+the weights resident, so the VMEM footprint is bounded by the block and
+not by B (a single whole-array call was refused by the v5e compiler at
+B=1024, F=512: 18.8M scoped vs the 16M limit).
 
 Accumulator contract (ops/precision.py): the carry is the policy's
 "lstm_carry" role — f32 only. Inputs are promoted to f32 on entry.
@@ -35,7 +42,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from torched_impala_tpu.ops.vtrace import _default_backend_is_tpu
+from torched_impala_tpu.ops.pallas_util import pallas_call
+
+# Batch rows per grid step. 256 rows of [rows, 4H] f32 gates at H=256
+# are 1 MiB per temporary; with both weight matrices resident the block
+# stays well inside the 16 MiB scoped-VMEM limit of a v5e
+# (tests/test_tpu_compile.py compiles B=1024 at F=512 and F=256).
+_BLOCK_ROWS = 256
 
 
 def _lstm_cell_kernel(
@@ -76,22 +89,55 @@ def _lstm_cell_kernel(
 
 
 def _lstm_forward(x, h, c, wi, wh, b):
-    """(new_c, new_h, acts) via the Pallas kernel (interpret off-TPU)."""
+    """(new_c, new_h, acts) via the Pallas kernel, gridded over batch
+    rows (weights broadcast to every block)."""
     batch, hidden = c.shape
+    feat = x.shape[-1]
     f32 = jnp.float32
     x, h, c, wi, wh, b = (
         a.astype(f32) for a in (x, h, c, wi, wh, b)
     )
+    # One block when the batch fits (a block equal to the array needs no
+    # row alignment); otherwise pad the rows to whole blocks — rows are
+    # independent, the padding is sliced off.
+    rows = batch if batch <= _BLOCK_ROWS else _BLOCK_ROWS
+    padded = -(-batch // rows) * rows
+    if padded != batch:
+        pad = ((0, padded - batch), (0, 0))
+        x, h, c = (jnp.pad(a, pad) for a in (x, h, c))
+
+    def row_block(width):
+        return pl.BlockSpec((rows, width), lambda i: (i, 0))
+
+    def whole(shape):
+        return pl.BlockSpec(shape, lambda i: (0, 0))
+
     kernel = functools.partial(_lstm_cell_kernel, hidden=hidden)
-    return pl.pallas_call(
+    new_c, new_h, acts = pallas_call(
         kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((batch, hidden), f32),
-            jax.ShapeDtypeStruct((batch, hidden), f32),
-            jax.ShapeDtypeStruct((batch, 4 * hidden), f32),
+        grid=(padded // rows,),
+        in_specs=[
+            row_block(feat),
+            row_block(hidden),
+            row_block(hidden),
+            whole((feat, 4 * hidden)),
+            whole((hidden, 4 * hidden)),
+            whole((1, 4 * hidden)),
+        ],
+        out_specs=(
+            row_block(hidden),
+            row_block(hidden),
+            row_block(4 * hidden),
         ),
-        interpret=not _default_backend_is_tpu(),
+        out_shape=(
+            jax.ShapeDtypeStruct((padded, hidden), f32),
+            jax.ShapeDtypeStruct((padded, hidden), f32),
+            jax.ShapeDtypeStruct((padded, 4 * hidden), f32),
+        ),
     )(x, h, c, wi, wh, b.reshape(1, -1))
+    if padded != batch:
+        new_c, new_h, acts = (a[:batch] for a in (new_c, new_h, acts))
+    return new_c, new_h, acts
 
 
 @jax.custom_vjp

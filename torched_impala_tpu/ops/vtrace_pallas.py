@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from torched_impala_tpu.ops import precision
+from torched_impala_tpu.ops.pallas_util import pallas_call
 from torched_impala_tpu.ops.vtrace import VTraceOutput
 
 _LANES = 128
@@ -104,13 +105,11 @@ def vtrace_pallas(
 ) -> VTraceOutput:
     """V-trace via the fused Pallas TPU kernel. Same contract as `vtrace_scan`.
 
-    `interpret=None` auto-selects interpreter mode off-TPU so tests and CPU
-    meshes run the same code path.
+    `interpret=None` compiles the kernel where the call is lowered for a
+    TPU and interprets it anywhere else (ops/pallas_util.py), so tests, CPU
+    meshes and a CPU-placed call in a TPU-default process run the same code
+    path.
     """
-    if interpret is None:
-        from torched_impala_tpu.ops.vtrace import _default_backend_is_tpu
-
-        interpret = not _default_backend_is_tpu()
     T, B = rewards.shape
     f32 = jnp.float32
 
@@ -153,7 +152,7 @@ def vtrace_pallas(
         (1, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
     )
     out_struct = jax.ShapeDtypeStruct((T, Bp), f32)
-    vs, pg, err = pl.pallas_call(
+    vs, pg, err = pallas_call(
         kernel,
         grid=(Bp // _LANES,),
         in_specs=[tb_spec, tb_spec, tb_spec, tb_spec, boot_spec],
@@ -229,7 +228,8 @@ def _fused_loss_kernel(
     """`_vtrace_kernel` + the loss epilogue in one VMEM-resident pass:
     after the recursion, the per-tile policy-gradient / baseline /
     entropy partial sums are reduced in place (padded lanes carry
-    mask 0, so they contribute nothing)."""
+    mask 0, so they contribute nothing). The three `(grid, 1)` sum
+    arrays sit whole in SMEM; each grid step writes its own row."""
     rhos = jnp.exp(log_rhos_ref[:])  # [T, 128]
     discounts = discounts_ref[:]
     values = values_ref[:]
@@ -259,9 +259,10 @@ def _fused_loss_kernel(
     adv_ref[:] = adv
 
     m = mask_ref[:]
-    pg_sum_ref[0, 0] = jnp.sum(-adv * log_pi_a_ref[:] * m)
-    bl_sum_ref[0, 0] = 0.5 * jnp.sum(jnp.square(vs - values) * m)
-    ent_sum_ref[0, 0] = jnp.sum(-entropy_ref[:] * m)
+    tile = pl.program_id(0)
+    pg_sum_ref[tile, 0] = jnp.sum(-adv * log_pi_a_ref[:] * m)
+    bl_sum_ref[tile, 0] = 0.5 * jnp.sum(jnp.square(vs - values) * m)
+    ent_sum_ref[tile, 0] = jnp.sum(-entropy_ref[:] * m)
 
 
 def _fused_sums_kernel_call(
@@ -299,12 +300,13 @@ def _fused_sums_kernel_call(
     boot_spec = pl.BlockSpec(
         (1, _LANES), lambda i: (0, i), memory_space=pltpu.VMEM
     )
-    sum_spec = pl.BlockSpec(
-        (1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM
-    )
+    # Whole (grid, 1) array resident in SMEM across the (sequential)
+    # grid: a (1, 1) block per tile is refused by the TPU lowering as
+    # soon as the grid exceeds one tile (B > 128).
+    sum_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     tb_struct = jax.ShapeDtypeStruct((T, Bp), f32)
     sum_struct = jax.ShapeDtypeStruct((grid, 1), f32)
-    vs, adv, pg_p, bl_p, ent_p = pl.pallas_call(
+    vs, adv, pg_p, bl_p, ent_p = pallas_call(
         kernel,
         grid=(grid,),
         in_specs=[
@@ -334,7 +336,7 @@ def _fused_core_fwd(
     statics, target_logits, actions, values, bootstrap, log_mu_a,
     discounts, rewards, mask,
 ):
-    clip_rho, clip_c, clip_pg_rho, lambda_, use_kernel, interpret = statics
+    clip_rho, clip_c, clip_pg_rho, lambda_, implementation = statics
     f32 = jnp.float32
     log_p = jax.nn.log_softmax(target_logits, axis=-1)  # [T, B, A]
     p = jnp.exp(log_p)
@@ -346,20 +348,24 @@ def _fused_core_fwd(
     # The [T, B] scalars feeding the recursion are f32 from here on —
     # only the [T, B, A] cube above ran at compute_dtype.
     log_rhos = log_pi_a - log_mu_a
-    if use_kernel:
-        pg, bl, en, vs, adv = _fused_sums_kernel_call(
-            log_pi_a, ent, values, bootstrap, log_rhos, discounts,
-            rewards, mask,
-            clip_rho=clip_rho,
-            clip_c=clip_c,
-            clip_pg_rho=clip_pg_rho,
-            lambda_=lambda_,
-            interpret=interpret,
+    clips = dict(
+        clip_rho=clip_rho,
+        clip_c=clip_c,
+        clip_pg_rho=clip_pg_rho,
+        lambda_=lambda_,
+    )
+
+    def kernel_path(interpret, *operands):
+        return _fused_sums_kernel_call(
+            *operands, **clips, interpret=interpret
         )
-    else:
-        # Off-TPU product path: the interpreter would crawl; XLA fuses
-        # the same math around a lax.scan recursion. Same reductions,
-        # same analytic VJP below.
+
+    def xla_path(
+        log_pi_a, ent, values, bootstrap, log_rhos, discounts, rewards,
+        mask,
+    ):
+        # XLA fuses the same math around a lax.scan recursion. Same
+        # reductions, same analytic VJP below.
         from torched_impala_tpu.ops.vtrace import vtrace_scan
 
         vt = vtrace_scan(
@@ -377,6 +383,27 @@ def _fused_core_fwd(
         pg = jnp.sum(-adv * log_pi_a * mask)
         bl = 0.5 * jnp.sum(jnp.square(vs - values) * mask)
         en = jnp.sum(-ent * mask)
+        return pg, bl, en, vs, adv
+
+    operands = (
+        log_pi_a, ent, values, bootstrap, log_rhos, discounts, rewards,
+        mask,
+    )
+    if implementation == "kernel":
+        # Compiled where lowered for a TPU, interpreted elsewhere
+        # (ops/pallas_util.py).
+        pg, bl, en, vs, adv = kernel_path(None, *operands)
+    elif implementation == "xla":
+        pg, bl, en, vs, adv = xla_path(*operands)
+    else:
+        # 'auto': the lowering platform picks — the compiled kernel on
+        # a TPU, the scan epilogue anywhere else (the interpreter would
+        # crawl there).
+        pg, bl, en, vs, adv = jax.lax.platform_dependent(
+            *operands,
+            tpu=functools.partial(kernel_path, False),
+            default=xla_path,
+        )
     out = (pg, bl, en, jnp.mean(vs), jnp.mean(adv))
     return out, (p, plp, ent, adv, vs, values, mask, actions)
 
@@ -425,7 +452,7 @@ def _fused_core(
 ):
     """(pg_sum, bl_sum, ent_sum, vs_mean, adv_mean) of the V-trace loss
     epilogue; `statics` = (clip_rho, clip_c, clip_pg_rho, lambda_,
-    use_kernel, interpret)."""
+    implementation)."""
     out, _ = _fused_core_fwd(
         statics, target_logits, actions, values, bootstrap, log_mu_a,
         discounts, rewards, mask,
@@ -455,9 +482,12 @@ def fused_vtrace_loss(
     Same contract and log dict as `ops.losses.impala_loss`. ONE
     log_softmax over `[T, B, A]` serves the importance ratios, the
     policy-gradient term, and the entropy term; the recursion plus the
-    three masked reductions run inside the Pallas kernel on TPU
-    (`implementation='auto'|'kernel'`; `'xla'` = lax.scan epilogue,
-    the off-TPU product path) behind one analytic-VJP custom_vjp.
+    three masked reductions run inside the Pallas kernel
+    (`implementation='kernel'`, interpreted where not lowered for a
+    TPU) or a lax.scan epilogue (`'xla'`) behind one analytic-VJP
+    custom_vjp. `'auto'` lets the lowering platform pick: kernel on
+    TPU, scan elsewhere. A multi-device mesh must use `'xla'` (Mosaic
+    kernels are not auto-partitioned; the Learner resolves that).
 
     `config.train_dtype='bfloat16'` runs the `[T, B, A]` softmax /
     elementwise phase in bf16 (the allow-listed half entry point —
@@ -466,7 +496,6 @@ def fused_vtrace_loss(
     stay within the parity gate pinned in tests/test_losses.py.
     """
     from torched_impala_tpu.ops.losses import assemble_loss
-    from torched_impala_tpu.ops.vtrace import _default_backend_is_tpu
 
     compute_dtype = getattr(config, "train_dtype", "float32")
     if compute_dtype not in _FUSED_COMPUTE_DTYPES:
@@ -476,12 +505,6 @@ def fused_vtrace_loss(
         )
     if implementation not in ("auto", "kernel", "xla"):
         raise ValueError(f"unknown implementation: {implementation!r}")
-    on_tpu = _default_backend_is_tpu()
-    use_kernel = (
-        implementation == "kernel"
-        or (implementation == "auto" and on_tpu)
-    )
-    interpret = not on_tpu
 
     f32 = jnp.float32
     if mask is None:
@@ -509,8 +532,7 @@ def fused_vtrace_loss(
         if config.clip_pg_rho_threshold is None
         else float(config.clip_pg_rho_threshold),
         float(config.lambda_),
-        use_kernel,
-        interpret,
+        implementation,
     )
     # ONE log_softmax inside the core serves ratios + pg + entropy; the
     # astype here puts the whole [T, B, A] cube phase (forward AND the

@@ -303,16 +303,14 @@ def naive_data_sharding(shape: Sequence[int], mesh):
 
 
 # --------------------------------------------------------------------------
-# shard_map compatibility: `jax.shard_map` only exists on newer jax; the
-# supported spelling on this build is jax.experimental.shard_map. One
-# compat symbol so callers never touch the moving target directly.
+# shard_map: one keyword-only spelling over `jax.shard_map`, so call
+# sites build their specs from the tables above.
 # --------------------------------------------------------------------------
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
     import jax
 
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    return impl(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    return jax.shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs
+    )

@@ -8,10 +8,11 @@ table, report anatomy, and the perfgate workflow.
 """
 
 from torched_impala_tpu.perf.costmodel import (
-    PEAK_FLOPS_BF16,
-    PEAK_HBM_BYTES_PER_S,
+    DEVICE_PEAKS,
     CostModel,
+    DevicePeaks,
     RootCost,
+    device_peaks,
     extract_compiled_cost,
     param_count,
     static_flops_estimate,
@@ -30,10 +31,11 @@ from torched_impala_tpu.perf.report import (
 )
 
 __all__ = [
-    "PEAK_FLOPS_BF16",
-    "PEAK_HBM_BYTES_PER_S",
+    "DEVICE_PEAKS",
     "CostModel",
+    "DevicePeaks",
     "RootCost",
+    "device_peaks",
     "extract_compiled_cost",
     "param_count",
     "static_flops_estimate",

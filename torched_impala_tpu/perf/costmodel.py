@@ -22,10 +22,11 @@ Two sources, in preference order:
   (convs reuse params), but it keeps the gauges and the doctor
   self-check alive off-TPU.
 
-Peak constants default to the repo-wide v5e numbers (197 TFLOP/s bf16,
-819 GB/s HBM) — the same 197e12 denominator bench.py and
-docs/SCALING.md already use, so live MFU and bench MFU are the same
-unit.
+Peaks come from ONE table keyed by `device_kind` (DEVICE_PEAKS below,
+with its source). A device that is not in the table has no peaks: the
+utilisation gauges then stay unset (`CostModel.for_device`) or the
+lookup raises (`device_peaks`, for code that reports a utilisation) —
+no device is a v5e by default.
 """
 
 from __future__ import annotations
@@ -35,19 +36,51 @@ from typing import Any, Dict, Optional
 
 from torched_impala_tpu.telemetry.registry import Registry, get_registry
 
-# TPU v5e (v5 lite): bf16 peak and HBM bandwidth per chip. Overridable
-# per CostModel for other backends; MFU on CPU is not meaningful but the
-# flops gauge still is.
-PEAK_FLOPS_BF16 = 197e12
-PEAK_HBM_BYTES_PER_S = 819e9
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks for one `device_kind`."""
 
-# Interconnect bandwidth for the data-axis gradient all-reduce cost
-# model (learner perf/allreduce_* telemetry). v5e ICI is a 1D ring at
-# ~45 GB/s per link per direction — ~9e10 B/s of ring all-reduce
-# bandwidth per chip. Simulated CPU pods move gradients over loopback
-# gloo TCP; 4 GB/s is the measured order of magnitude on this image.
-ICI_BYTES_PER_S = 9e10
+    flops_per_s: float  # dense bf16
+    hbm_bytes_per_s: float
+    # Ring all-reduce bandwidth per chip over the interconnect, for the
+    # data-axis gradient all-reduce estimate (allreduce_ns).
+    allreduce_bytes_per_s: float
+    source: str
+
+
+# Keyed by `jax.devices()[0].device_kind`. Add a device WITH its source;
+# never let a lookup default to some other device's numbers.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(
+        flops_per_s=197e12,
+        hbm_bytes_per_s=819e9,
+        # v5e ICI links run ~45 GB/s per direction; a bidirectional
+        # ring sustains ~9e10 B/s of all-reduce bandwidth per chip (of
+        # the 1,600 Gbit/s published per-chip interconnect total).
+        allreduce_bytes_per_s=9e10,
+        source=(
+            'Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+            "16 GB HBM at 819 GB/s, 1,600 Gbit/s chip-to-chip"
+        ),
+    ),
+}
+
+# Simulated CPU pods (parallel/simhost.py) move gradients over loopback
+# gloo TCP; 4 GB/s is the measured order of magnitude. Not a device
+# peak: it only feeds the all-reduce OVERLAP estimate of a CPU mesh.
 LOOPBACK_BYTES_PER_S = 4e9
+
+
+def device_peaks(device_kind: str) -> DevicePeaks:
+    """Peaks for `device_kind`; a device not in the table is an error."""
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"DEVICE_PEAKS knows {sorted(DEVICE_PEAKS)} — add the "
+            "device with its source instead of assuming another's"
+        ) from None
 
 
 def allreduce_ns(nbytes: float, n_shards: int, bytes_per_s: float) -> int:
@@ -137,10 +170,14 @@ class CostModel:
     def __init__(
         self,
         *,
-        peak_flops: float = PEAK_FLOPS_BF16,
-        peak_bytes_per_s: float = PEAK_HBM_BYTES_PER_S,
+        peak_flops: Optional[float] = None,
+        peak_bytes_per_s: Optional[float] = None,
         registry: Optional[Registry] = None,
     ):
+        """Without peaks (`None`) the model still counts FLOPs and bytes
+        (`perf/flops_per_step`, `roofline`'s raw counts) but sets no
+        utilisation gauge: a ratio against another device's peak would
+        be a number about nothing."""
         reg = registry if registry is not None else get_registry()
         self.peak_flops = peak_flops
         self.peak_bytes_per_s = peak_bytes_per_s
@@ -148,6 +185,21 @@ class CostModel:
         self._g_mfu = reg.gauge("perf/mfu")
         self._g_membw = reg.gauge("perf/membw_util")
         self._g_flops = reg.gauge("perf/flops_per_step")
+
+    @classmethod
+    def for_device(
+        cls, device_kind: str, *, registry: Optional[Registry] = None
+    ) -> "CostModel":
+        """Peaks from DEVICE_PEAKS for `device_kind`; none (utilisation
+        gauges stay unset) for a device the table does not know."""
+        peaks = DEVICE_PEAKS.get(device_kind)
+        if peaks is None:
+            return cls(registry=registry)
+        return cls(
+            peak_flops=peaks.flops_per_s,
+            peak_bytes_per_s=peaks.hbm_bytes_per_s,
+            registry=registry,
+        )
 
     def register_root(
         self,
@@ -190,11 +242,16 @@ class CostModel:
         update the live gauges and return the instantaneous MFU (0.0
         when the root is unknown or costless)."""
         root = self.roots.get(name)
-        if root is None or root.flops <= 0 or dt_seconds <= 0:
+        if (
+            root is None
+            or root.flops <= 0
+            or dt_seconds <= 0
+            or not self.peak_flops
+        ):
             return 0.0
         mfu = (root.flops / dt_seconds) / self.peak_flops
         self._g_mfu.set(mfu)
-        if root.bytes_accessed > 0:
+        if root.bytes_accessed > 0 and self.peak_bytes_per_s:
             self._g_membw.set(
                 (root.bytes_accessed / dt_seconds) / self.peak_bytes_per_s
             )
@@ -218,6 +275,8 @@ class CostModel:
             "peak_flops": self.peak_flops,
             "peak_bytes_per_s": self.peak_bytes_per_s,
         }
+        if not (self.peak_flops and self.peak_bytes_per_s):
+            return out  # unknown device: counts only, no ridge/bound
         ridge = self.peak_flops / self.peak_bytes_per_s
         out["ridge_intensity"] = ridge
         if root.bytes_accessed > 0 and root.flops > 0:

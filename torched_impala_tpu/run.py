@@ -466,6 +466,11 @@ def main(argv=None) -> int:
     args = parse_args(argv)
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
+    from torched_impala_tpu.utils.compile_cache import (
+        configure_compile_cache,
+    )
+
+    configure_compile_cache()
     if args.doctor:
         from torched_impala_tpu.doctor import run_doctor
 
@@ -510,7 +515,7 @@ def main(argv=None) -> int:
         )
     from torched_impala_tpu import configs
     from torched_impala_tpu.parallel import make_mesh
-    from torched_impala_tpu.runtime.loop import train
+    from torched_impala_tpu.runtime.loop import resolve_actor_device, train
     from torched_impala_tpu.utils.checkpoint import Checkpointer
 
     cfg = build_config(args)
@@ -718,7 +723,8 @@ def main(argv=None) -> int:
         f"config={cfg.name} actors={cfg.num_actors} T={cfg.unroll_length} "
         f"B={cfg.batch_size} steps={total_steps} "
         f"mesh={None if mesh is None else dict(mesh.shape)} "
-        f"backend={jax.default_backend()}",
+        f"backend={jax.default_backend()} "
+        f"actor_device={resolve_actor_device()[1]}",
         file=sys.stderr,
     )
 

@@ -40,8 +40,8 @@ import jax.numpy as jnp
 import optax
 
 from torched_impala_tpu.models.agent import Agent
-from torched_impala_tpu.ops import vtrace as vtrace_ops
 from torched_impala_tpu.ops.losses import ImpalaLossConfig, impala_loss
+from torched_impala_tpu.runtime.learner import resolve_kernels
 from torched_impala_tpu.parallel.mesh import (
     model_shardings,
     DATA_AXIS,
@@ -81,10 +81,8 @@ class AnakinRunner:
         rng: jax.Array,
         mesh=None,
     ) -> None:
-        self._agent = agent
         self._env = env
         self._optimizer = optimizer
-        self._config = config
         self._mesh = mesh
         E = config.num_envs
         if mesh is not None and E % mesh.shape[DATA_AXIS]:
@@ -92,18 +90,12 @@ class AnakinRunner:
                 f"num_envs {E} not divisible by data axis "
                 f"{mesh.shape[DATA_AXIS]}"
             )
-        if config.loss.vtrace_implementation == "auto":
-            # Same device-aware resolution as runtime.Learner.
-            impl = vtrace_ops.resolve_implementation(
-                "auto",
-                mesh.devices.flat if mesh is not None else None,
-            )
-            self._config = dataclasses.replace(
-                config,
-                loss=dataclasses.replace(
-                    config.loss, vtrace_implementation=impl
-                ),
-            )
+        # Same device-aware kernel resolution as runtime.Learner.
+        agent, loss, self.kernels = resolve_kernels(
+            agent, config.loss, mesh
+        )
+        self._agent = agent
+        self._config = dataclasses.replace(config, loss=loss)
 
         init_key, env_key, carry_key = jax.random.split(rng, 3)
         env_state = jax.vmap(env.reset)(

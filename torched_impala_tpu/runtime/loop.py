@@ -43,6 +43,32 @@ class TrainResult:
     learner: Learner
     num_frames: int
     actor_restarts: int = 0
+    actor_device: str = ""  # where actor inference ran (str(Device))
+
+
+def resolve_actor_device(actor_device: Optional[str] = "cpu"):
+    """`(device, note)` for actor inference. `device` is the first LOCAL
+    device of the `actor_device` platform; when that platform is not
+    enabled in this process (e.g. JAX_PLATFORMS=tpu hides the host CPU)
+    or `actor_device` is None, actors share the default backend with the
+    learner — `device` is then None and `note` says so, for the start-up
+    line: an actor stepping on the learner's chip is a different system
+    from one stepping on the host, and must not be entered silently."""
+    default = jax.local_devices()[0]
+    if actor_device is None:
+        return None, f"{default} (default backend; shared with the learner)"
+    try:
+        # LOCAL devices: under multi-controller (jax.distributed),
+        # jax.devices()[0] is GLOBAL device 0 — non-addressable from
+        # every other process, so actor inference there dies with
+        # "spans non-addressable devices".
+        device = jax.local_devices(backend=actor_device)[0]
+    except RuntimeError:
+        return None, (
+            f"{default} (platform {actor_device!r} is not enabled; "
+            "actors share the default backend with the learner)"
+        )
+    return device, str(device)
 
 
 def train(
@@ -87,10 +113,11 @@ def train(
     resumed run performs only the remainder, keeping the lr schedule and the
     frame budget aligned with a single uninterrupted run.
 
-    `actor_device="cpu"` pins actor inference to a host CPU device when that
-    platform is available (falls back to the default backend otherwise), so
+    `actor_device="cpu"` pins actor inference to a host CPU device, so
     env-paced single-step policy calls don't pay per-step dispatch latency to
-    the accelerator the learner owns.
+    the accelerator the learner owns. Where that platform is not enabled the
+    actors share the default backend — `resolve_actor_device` names the
+    outcome and `TrainResult.actor_device` records it.
 
     `mesh` shards the learner over a device mesh (DP; SURVEY.md §3b).
     `checkpointer` (a `utils.Checkpointer`) saves learner state every
@@ -197,16 +224,7 @@ def train(
     # telemetry/runtime/thread_crashes + stderr instead of dying silently
     # (telemetry/excepthook.py; idempotent, process-wide).
     install_thread_excepthook()
-    device = None
-    if actor_device is not None:
-        try:
-            # LOCAL devices: under multi-controller (jax.distributed),
-            # jax.devices()[0] is GLOBAL device 0 — non-addressable from
-            # every other process, so actor inference there dies with
-            # "spans non-addressable devices".
-            device = jax.local_devices(backend=actor_device)[0]
-        except RuntimeError:
-            device = None  # platform not enabled; use default backend
+    device, actor_device_note = resolve_actor_device(actor_device)
 
     episode_returns: collections.deque = collections.deque(maxlen=10_000)
     returns_lock = threading.Lock()
@@ -732,6 +750,7 @@ def train(
         final_logs=dict(step_logs),
         learner=learner,
         num_frames=learner.num_frames,
+        actor_device=actor_device_note,
         actor_restarts=supervisor.restarts
         + sum(pool.restarts for pool in env_pools),
     )

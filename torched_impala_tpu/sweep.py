@@ -12,6 +12,9 @@ Usage (ALE-equipped host):
         --out runs/atari57.csv --total-env-frames 200000000 \
         [--games Pong Breakout ...] [--eval-only] [-- <extra run.py flags>]
 
+This parent never imports jax (nor anything that does): a chip belongs to
+one process at a time, and each child `run.py` takes it in turn.
+
 Games default to the standard 57-game suite; names are bare (e.g.
 "Pong") and expand to `<Game>NoFrameskip-v4`. Each game trains
 sequentially (one TPU client at a time); a sweep is resumable at two

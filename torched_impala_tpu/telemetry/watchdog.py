@@ -2,7 +2,7 @@
 
 A distributed actor-learner pipeline has many ways to deadlock quietly —
 a full trajectory queue with a dead consumer, an env worker stuck in a
-native emulator call, a tunnel-backed device hanging a `device_put` — and
+native emulator call, a device hanging a `device_put` — and
 the symptom is always the same: the process sits at 0% progress forever.
 The watchdog closes that gap: pipeline stages record liveness via
 `Registry.heartbeat(component)` (the learner after every SGD step, the
