@@ -229,6 +229,78 @@ def test_analyze_pipelined_feeder_only_charges_gap_portion():
     assert learner["coverage_frac"] == pytest.approx(1.0)
 
 
+def test_in_flight_bracket_is_the_compute_not_the_dispatch():
+    """On an asynchronous backend `learner/train_step` is the dispatch: a
+    2 ms dispatch inside a 60 ms in-flight bracket is 60 ms of compute,
+    and `publish` is charged the copy alone, not the wait for the device
+    that `learner/publish` also holds."""
+    records = []
+    for k, t0 in enumerate((0, 70), start=1):
+        tag = {"step": k}
+        records += [
+            _span(t0, 2, "learner/train_step", dict(tag, batch=k)),
+            _span(t0 + 2, 1, "learner/bookkeeping", tag),
+            _span(t0 + 3, 57, "learner/step_wait", tag),
+            _span(t0, 60, "learner/step_in_flight", tag),
+            _span(t0 + 60, 4, "learner/publish_copy", tag),
+            _span(t0 + 3, 61, "learner/publish", {"version": k}),
+            _span(t0 + 64, 1, "learner/bookkeeping", tag),
+            _span(t0 + 65, 3, "learner/outside_step", tag),
+            _span(t0 + 68, 2, "learner/batch_wait", tag),
+        ]
+    learner = analyze_records(records)["learner"]
+    assert learner["compute_source"] == "step_in_flight"
+    assert learner["steps"] == 2
+    assert learner["compute_s"] == pytest.approx(0.120)
+    assert learner["wall_clock_s"] == pytest.approx(0.130)
+    assert learner["gaps_s"]["publish"] == pytest.approx(0.004)
+    assert learner["gaps_s"]["bookkeeping"] == pytest.approx(0.001)
+    assert learner["gaps_s"]["outside_step"] == pytest.approx(0.003)
+    # the batch wait with no feeder span beside it
+    assert learner["gaps_s"]["unattributed"] == pytest.approx(0.002)
+    assert learner["coverage_frac"] == pytest.approx(1.0)
+    assert "dispatch to device done" in render_report({"learner": learner})
+
+
+def test_without_a_bracket_the_dispatch_stands_in_and_the_report_says_so():
+    # publish_interval 2: only step 2 waited for the device
+    records = [
+        _span(0, 2, "learner/train_step", {"step": 1}),
+        _span(2, 1, "learner/bookkeeping", {"step": 1}),
+        _span(3, 2, "learner/train_step", {"step": 2}),
+        _span(3, 60, "learner/step_in_flight", {"step": 2}),
+        _span(63, 5, "learner/publish_copy", {"step": 2}),
+        _span(5, 63, "learner/publish", {"version": 2}),
+        _span(68, 2, "learner/train_step", {"step": 3}),
+    ]
+    learner = analyze_records(records)["learner"]
+    assert learner["compute_source"] == "mixed"
+    assert learner["compute_s"] == pytest.approx(0.064)
+    assert learner["gaps_s"]["publish"] == pytest.approx(0.005)
+    assert learner["gaps_s"]["bookkeeping"] == pytest.approx(0.001)
+    none = analyze_records(
+        [
+            _span(0, 10, "learner/train_step", {"step": 1}),
+            _span(10, 4, "learner/publish"),
+            _span(14, 10, "learner/train_step", {"step": 2}),
+        ]
+    )["learner"]
+    assert none["compute_source"] == "train_step"
+    assert none["gaps_s"]["publish"] == pytest.approx(0.004)
+    assert "DISPATCH only" in render_report({"learner": none})
+
+
+def test_step_loop_phases_are_gap_categories():
+    assert categorize_span("learner/publish_copy") == "publish"
+    assert categorize_span("learner/bookkeeping") == "bookkeeping"
+    assert categorize_span("learner/outside_step") == "outside_step"
+    assert categorize_span("learner/step_in_flight") is None
+    assert categorize_span("learner/compile") == "compile"
+    # the waits are not causes: what overlaps them is
+    assert categorize_span("learner/batch_wait") is None
+    assert categorize_span("learner/step_wait") is None
+
+
 def test_categorize_donated_h2d_span():
     # The donated-ring staging span is H2D time like device_put
     # (ISSUE 13 zero-copy feed path).

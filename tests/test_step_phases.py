@@ -1,0 +1,201 @@
+"""The learner's step loop tells its own time (ISSUE 24).
+
+One period of the loop (an entry of `step_once` to the next) is cut into
+phases that do not overlap and add up to it; each is a registry timer and
+a flight-recorder span carrying the step's number. CPU, tiny sizes: the
+arithmetic is what is tested, not a speed.
+"""
+
+import queue
+import threading
+import time
+
+import jax
+import numpy as np
+import optax
+import pytest
+
+from torched_impala_tpu.runtime.learner import Learner, LearnerConfig
+from torched_impala_tpu.runtime.types import Trajectory
+from torched_impala_tpu.telemetry import FlightRecorder, Registry
+
+PHASES = (
+    "learner/batch_wait",
+    "learner/train_step",
+    "learner/bookkeeping",
+    "learner/step_wait",
+    "learner/publish_copy",
+    "learner/outside_step",
+)
+T, B = 4, 4
+
+
+def _agent():
+    from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
+
+    return Agent(ImpalaNet(num_actions=2, torso=MLPTorso(hidden_sizes=(16,))))
+
+
+def _trajectory(i: int) -> Trajectory:
+    rng = np.random.default_rng(i)
+    return Trajectory(
+        obs=rng.normal(size=(T + 1, 4)).astype(np.float32),
+        first=np.zeros((T + 1,), bool),
+        actions=rng.integers(0, 2, size=(T,)).astype(np.int32),
+        behaviour_logits=rng.normal(size=(T, 2)).astype(np.float32),
+        rewards=rng.normal(size=(T,)).astype(np.float32),
+        cont=np.ones((T,), np.float32),
+        agent_state=(),
+    )
+
+
+def _learner(reg, rec, publish_interval: int = 1) -> Learner:
+    return Learner(
+        agent=_agent(),
+        optimizer=optax.sgd(1e-2),
+        config=LearnerConfig(
+            batch_size=B, unroll_length=T, publish_interval=publish_interval
+        ),
+        example_obs=np.zeros((4,), np.float32),
+        rng=jax.random.key(0),
+        telemetry=reg,
+        tracer=rec,
+    )
+
+
+def _drive(publish_interval: int, steps: int):
+    """`steps` steps of a tiny learner fed from a thread, with a pause
+    between calls so that `outside_step` is not empty. Returns (registry,
+    recorder, window seconds, timer seconds in the window): the window
+    opens and closes just after a return of `step_once`."""
+    reg, rec = Registry(), FlightRecorder(capacity=1 << 14)
+    learner = _learner(reg, rec, publish_interval)
+
+    def feed():
+        try:
+            for i in range((steps + 2) * B):
+                learner.enqueue(_trajectory(i))
+        except Exception:  # QueueClosed at the end
+            pass
+
+    feeder = threading.Thread(target=feed, daemon=True)
+    learner.start()
+    feeder.start()
+
+    def totals():
+        return {name: reg.timer(name).seconds for name in PHASES}
+
+    try:
+        learner.step_once(timeout=60)  # the compile
+        t_open, before = time.monotonic(), totals()
+        for _ in range(steps):
+            time.sleep(0.002)
+            learner.step_once(timeout=60)
+        t_close, after = time.monotonic(), totals()
+    finally:
+        learner.stop()
+        feeder.join(timeout=30)
+    spent = {name: after[name] - before[name] for name in PHASES}
+    return reg, rec, t_close - t_open, spent
+
+
+def _spans(rec, name):
+    return [r for r in rec.tail() if r[3] == name and r[2] == "X"]
+
+
+@pytest.mark.parametrize("publish_interval", [1, 4])
+def test_phases_add_up_to_the_window(publish_interval):
+    reg, _, window, spent = _drive(publish_interval, steps=20)
+    assert sum(spent.values()) == pytest.approx(window, rel=0.02)
+    assert spent["learner/outside_step"] >= 20 * 0.002
+    # step_wait and publish_copy only in periods that publish
+    published = 20 // publish_interval
+    assert reg.timer("learner/step_wait").calls in (published, published + 1)
+    assert (
+        reg.timer("learner/publish_copy").calls
+        == reg.timer("learner/step_wait").calls
+    )
+    assert reg.timer("learner/bookkeeping").calls == 21
+    assert reg.timer("learner/outside_step").calls == 20
+    # publish is the timer around the two new ones
+    assert reg.timer("learner/publish").seconds == pytest.approx(
+        reg.timer("learner/step_wait").seconds
+        + reg.timer("learner/publish_copy").seconds,
+        rel=1e-6,
+        abs=1e-3,  # the two publishes outside a step: __init__'s
+    )
+
+
+@pytest.mark.parametrize("publish_interval", [1, 4])
+def test_every_span_of_a_step_carries_its_number_and_they_tile(
+    publish_interval,
+):
+    _, rec, _, _ = _drive(publish_interval, steps=20)
+    by_step: dict = {}
+    for ts, dur, _ph, name, _tid, args in rec.tail():
+        if name in PHASES + ("learner/step_in_flight", "learner/loop_overhead"):
+            by_step.setdefault(args["step"], []).append((ts, dur, name, args))
+    # 21 steps ran; the last one's period was not closed by a next entry
+    assert sorted(by_step) == list(range(1, 22))
+    for step in range(1, 21):
+        spans = by_step[step]
+        names = [s[2] for s in spans]
+        published = step % publish_interval == 0
+        for name in PHASES + ("learner/loop_overhead",):
+            want = name not in ("learner/step_wait", "learner/publish_copy")
+            assert (name in names) == (want or published), (step, name)
+        assert ("learner/step_in_flight" in names) == published
+        # the phases tile the period: each starts where the last ended
+        tiles = sorted(s for s in spans if s[2] in PHASES)
+        for a, b in zip(tiles, tiles[1:]):
+            assert a[0] + a[1] == b[0], (step, a[2], b[2])
+        (period,) = [s for s in spans if s[2] == "learner/loop_overhead"]
+        assert period[0] == tiles[0][0]
+        assert period[1] == sum(s[1] for s in tiles)
+        waits = sum(
+            s[1]
+            for s in tiles
+            if s[2] in ("learner/batch_wait", "learner/step_wait")
+        )
+        assert period[3]["overhead_ns"] == period[1] - waits
+        if published:
+            (flight,) = [s for s in spans if s[2] == "learner/step_in_flight"]
+            (dispatch,) = [s for s in spans if s[2] == "learner/train_step"]
+            (wait,) = [s for s in spans if s[2] == "learner/step_wait"]
+            assert flight[0] == dispatch[0]
+            assert flight[0] + flight[1] == wait[0] + wait[1]
+
+
+def test_loop_overhead_timer_is_the_period_less_the_waits():
+    reg, rec, _, _ = _drive(1, steps=20)
+    overheads = [s[5]["overhead_ns"] for s in _spans(rec, "learner/loop_overhead")]
+    timer = reg.timer("learner/loop_overhead")
+    assert timer.calls == len(overheads) == 20
+    assert timer.seconds == pytest.approx(sum(overheads) / 1e9, rel=1e-9)
+
+
+def test_a_timed_out_wait_is_counted_and_leaves_the_period_open():
+    reg, rec = Registry(), FlightRecorder(capacity=256)
+    learner = _learner(reg, rec)
+    learner.start()
+    try:
+        for _ in range(2):
+            with pytest.raises(queue.Empty):
+                learner.step_once(timeout=0.01)
+        assert reg.timer("learner/batch_wait").calls == 2
+        assert reg.timer("learner/batch_wait").seconds >= 0.02
+        assert reg.timer("learner/outside_step").calls == 1
+        assert reg.timer("learner/loop_overhead").calls == 0
+        for i in range(B):
+            learner.enqueue(_trajectory(i))
+        learner.step_once(timeout=60)
+        with pytest.raises(queue.Empty):
+            learner.step_once(timeout=0.01)
+    finally:
+        learner.stop()
+    # one period: from the first entry to the entry after the step
+    assert reg.timer("learner/loop_overhead").calls == 1
+    (period,) = _spans(rec, "learner/loop_overhead")
+    waits = sum(s[1] for s in _spans(rec, "learner/batch_wait")[:3])
+    waits += sum(s[1] for s in _spans(rec, "learner/step_wait"))
+    assert period[5]["overhead_ns"] == period[1] - waits

@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from torched_impala_tpu.telemetry import (
+    FlightRecorder,
     ProfilerCapture,
     Registry,
     StallWatchdog,
@@ -109,6 +110,55 @@ def test_histogram_single_bucket_edge_case():
     h2.observe(2.0)
     snap = reg.snapshot()
     assert 0.0 < snap["telemetry/test/single2_ms_p99"] <= 5.0
+
+
+def test_timer_keeps_a_total_beside_the_average():
+    """A share of a window is a difference of two sums: `<name>_total_s`
+    is the sum of every observation, under the timer's own lock, and goes
+    through the exposition like `_calls`."""
+    from torched_impala_tpu.telemetry import parse_openmetrics, to_openmetrics
+
+    reg = Registry()
+    t = reg.timer("test/stage")
+    assert t.seconds == 0.0
+    for seconds in (0.25, 0.5, 1.0):
+        t.observe(seconds)
+    assert t.seconds == pytest.approx(1.75)
+    snap = reg.snapshot()
+    assert snap["telemetry/test/stage_total_s"] == pytest.approx(1.75)
+    assert snap["telemetry/test/stage_calls"] == 3
+    scraped = parse_openmetrics(to_openmetrics(snap))
+    assert scraped["impala_test_stage_total_s"] == pytest.approx(1.75)
+    assert scraped["impala_test_stage_calls"] == 3
+    # frozen while the registry is disabled, like every other record
+    reg.enabled = False
+    t.observe(5.0)
+    assert t.seconds == pytest.approx(1.75)
+    # concurrent observers lose nothing
+    reg2 = Registry()
+    t2 = reg2.timer("test/stage")
+    threads = [
+        threading.Thread(target=lambda: [t2.observe(0.5) for _ in range(2000)])
+        for _ in range(4)
+    ]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join()
+    assert t2.seconds == 4000.0 and t2.calls == 8000
+
+
+def test_the_benchmarks_summing_timer_does_not_count_twice():
+    """`benchmark/program.py::SummingTimer` adds to its own `total_s`
+    after `EwmaTimer.observe`; the program's total must stay its own."""
+    from benchmark import program
+
+    reg = program.make_registry()
+    t = reg.timer("learner/publish")
+    t.observe(1.0)
+    assert reg.timer_totals() == {"learner/publish": (1.0, 1)}
+    assert t.seconds == 1.0
+    assert reg.snapshot()["telemetry/learner/publish_total_s"] == 1.0
 
 
 def test_histogram_empty_is_nan_not_crash():
@@ -370,6 +420,141 @@ def test_profiler_capture_writes_trace(tmp_path):
         for f in fs
     ]
     assert files, "trace directory is empty"
+
+
+def test_capture_options_reach_the_profiler(tmp_path, monkeypatch):
+    """An operator's capture runs with the Python tracer off and the host
+    tracer at `HOST_TRACER_LEVEL`: the default options make a DMLab step
+    take 3.7 s instead of 96 ms (PERF.md)."""
+    import jax
+
+    from torched_impala_tpu.telemetry import profiling
+
+    seen = {}
+
+    def fake_start(path, **kwargs):
+        seen["path"], seen["kwargs"] = path, kwargs
+
+    monkeypatch.setattr(jax.profiler, "start_trace", fake_start)
+    monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    cap = ProfilerCapture(
+        str(tmp_path / "traces"), registry=Registry(), recorder=FlightRecorder(64)
+    )
+    path = cap.start(tag="t")
+    options = seen["kwargs"]["profiler_options"]
+    assert seen["path"] == path
+    assert options.python_tracer_level == profiling.PYTHON_TRACER_LEVEL == 0
+    assert options.host_tracer_level == profiling.HOST_TRACER_LEVEL
+    assert profiling.HOST_TRACER_LEVEL in (0, 1)
+    cap.stop()
+
+
+def test_capture_leaves_the_recorders_spans_beside_the_xplane(tmp_path):
+    """`stop()` writes `host_spans.json` next to the `.xplane.pb`: a valid
+    Chrome trace on the epoch clock that holds the records of the capture
+    and none from before it."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    from torched_impala_tpu.telemetry import validate_chrome_trace
+    from torched_impala_tpu.telemetry.profiling import HOST_SPANS_FILE
+
+    rec = FlightRecorder(capacity=64)
+    cap = ProfilerCapture(
+        str(tmp_path / "traces"), registry=Registry(), recorder=rec
+    )
+    t_old = time.monotonic_ns()
+    rec.complete("learner/train_step", t_old, 1000, {"step": 1})
+    time.sleep(0.002)
+    wall_before = time.time_ns()
+    path = cap.start(tag="t")
+    jax.jit(lambda x: x * 2)(jnp.ones((8,))).block_until_ready()
+    t_in = time.monotonic_ns()
+    rec.complete("learner/step_in_flight", t_in, 2_000_000, {"step": 2})
+    rec.instant("queue/enqueue", {"lid": "a0u0"})
+    cap.stop()
+    wall_after = time.time_ns()
+    (pb,) = glob.glob(os.path.join(path, "**", "*.xplane.pb"), recursive=True)
+    with open(os.path.join(os.path.dirname(pb), HOST_SPANS_FILE)) as f:
+        doc = json.load(f)
+    assert validate_chrome_trace(doc) == []
+    events = [e for e in doc["traceEvents"] if e["ph"] != "M"]
+    assert [e["name"] for e in events] == [
+        "learner/step_in_flight",
+        "queue/enqueue",
+    ]
+    assert events[0]["args"] == {"step": 2}
+    assert events[0]["dur"] == pytest.approx(2000.0)
+    # on the epoch clock, in microseconds
+    for e in events:
+        assert wall_before / 1e3 <= e["ts"] <= wall_after / 1e3
+    pair = doc["metadata"]["clock_pair_ns"]
+    assert events[0]["ts"] * 1e3 == pytest.approx(
+        t_in - pair["monotonic"] + pair["epoch"], abs=1.0
+    )
+    assert "epoch" in doc["metadata"]["clock"]
+
+
+def test_recorder_export_since_and_epoch(tmp_path):
+    rec = FlightRecorder(capacity=64)
+    mono, wall = rec.clock_pair
+    assert abs((time.time_ns() - wall) - (time.monotonic_ns() - mono)) < 50e6
+    rec.complete("test/old", 1_000, 500)
+    rec.complete("test/straddles", 1_800, 400)  # ends at 2_200
+    rec.complete("test/new", 3_000, 100)
+    kept = rec.to_chrome_events(since_ns=2_000)
+    assert [e["name"] for e in kept if e["ph"] == "X"] == [
+        "test/straddles",
+        "test/new",
+    ]
+    plain = [e for e in rec.to_chrome_events() if e["ph"] == "X"]
+    shifted = [e for e in rec.to_chrome_events(epoch=True) if e["ph"] == "X"]
+    for a, b in zip(plain, shifted):
+        assert b["ts"] - a["ts"] == pytest.approx((wall - mono) / 1e3)
+        assert a["dur"] == b["dur"]
+    out = str(tmp_path / "t.json")
+    assert rec.export(out, since_ns=2_900, metadata={"k": 1}) == 1
+    with open(out) as f:
+        doc = json.load(f)
+    assert doc["metadata"] == {"k": 1}
+    assert rec.sync_clock() == rec.clock_pair != (mono, wall)
+
+
+def test_a_compile_shows_in_the_registrys_timers():
+    """`jit/backend_compile` and `jit/trace` count this process's compiles
+    with their seconds: a fresh jit bumps them, its second call does not."""
+    import jax
+    import jax.numpy as jnp
+
+    from torched_impala_tpu.telemetry.profiling import watch_compiles
+
+    x = jnp.ones((7, 3))  # before the watch: making it compiles too
+    reg = Registry()
+    watch_compiles(reg)
+    watch_compiles(reg)  # asking twice listens once
+    compiles, traces = reg.timer("jit/backend_compile"), reg.timer("jit/trace")
+    assert (compiles.calls, traces.calls) == (0, 0)
+    fresh = jax.jit(lambda x: jnp.tanh(x) * 3.0 + 1.0)
+    fresh(x).block_until_ready()
+    first = (compiles.calls, traces.calls)
+    assert first[0] == 1 and first[1] >= 1
+    assert compiles.seconds > 0.0
+    fresh(x).block_until_ready()
+    assert (compiles.calls, traces.calls) == first
+    snap = reg.snapshot()
+    assert snap["telemetry/jit/backend_compile_calls"] == 1
+    assert snap["telemetry/jit/backend_compile_total_s"] == compiles.seconds
+    # a registry nobody holds any more is dropped, not fed for ever
+    import gc
+    import weakref
+
+    ref = weakref.ref(reg)
+    del reg, compiles, traces
+    gc.collect()
+    assert ref() is None
+    jax.jit(lambda x: x - 1.0)(x).block_until_ready()
 
 
 @pytest.mark.skipif(

@@ -1,3 +1,3 @@
 # Repo tooling package (makes `python -m tools.lint` work from the repo
 # root). Scripts that predate the package (check_metric_names.py,
-# soak.py, trace_anatomy.py) still run as plain files.
+# soak.py) still run as plain files.

@@ -3,14 +3,24 @@ recorder, emit a per-run roofline + pipeline-attribution report.
 
 The flight recorder (telemetry/tracing.py) already holds the answer to
 "where did the step time go" — host_stack / device_put / publish spans
-from the feeder threads interleaved with the learner's train_step spans
+from the feeder threads interleaved with the learner's step spans
 — but nobody was doing the interval arithmetic. This module replays the
-ring: the learner wall-clock is tiled into compute (train_step spans)
-plus the gaps between consecutive steps, and each gap is attributed to
-the highest-priority pipeline activity that overlapped it:
+ring: the learner wall-clock is tiled into compute plus the gaps between
+consecutive steps, and each gap is attributed to the highest-priority
+pipeline activity that overlapped it:
 
-    publish > h2d (device_put) > feed (host_stack/queue/ring/pool/actor)
+    publish > bookkeeping > outside_step (the step loop's own thread)
+    > h2d (device_put) > feed (host_stack/queue/ring/pool/actor)
     > compile > unattributed
+
+Compute is the host's bracket around the device's work on a step,
+`learner/step_in_flight` (dispatch start to the moment the wait for the
+device returns), where the step has one. `learner/train_step` times only
+the DISPATCH of the step: on an asynchronous backend it is a few ms of a
+60 ms step, and tiling the wall clock with it would book the device's
+compute as a `publish` gap. A step without the bracket (nothing was
+published in its period, so the host never waited for it) falls back to
+its `learner/train_step` span; `compute_source` says which was used.
 
 Attribution is by interval union-and-subtract, so a feeder span that
 overlaps a train_step (healthy pipelining) only charges the part that
@@ -42,11 +52,15 @@ from torched_impala_tpu.telemetry.tracing import (
 SCHEMA_VERSION = 1
 
 TRAIN_STEP = "learner/train_step"
+STEP_IN_FLIGHT = "learner/step_in_flight"
 
 # Gap categories in attribution priority order (first match wins a
-# disputed interval). "compile" is matched by name substring so future
-# explicit compile spans land without a code change here.
-GAP_CATEGORIES = ("publish", "h2d", "feed", "compile")
+# disputed interval): the step loop's own phases first, since that thread
+# is what launches the next step. "compile" is matched by name substring
+# so future explicit compile spans land without a code change here.
+GAP_CATEGORIES = (
+    "publish", "bookkeeping", "outside_step", "h2d", "feed", "compile",
+)
 _FEED_COMPONENTS = frozenset(
     {"actor", "pool", "queue", "ring", "env", "replay"}
 )
@@ -55,11 +69,16 @@ _FEED_COMPONENTS = frozenset(
 def categorize_span(name: str) -> Optional[str]:
     """Gap category for one trace-span name (None = not attributable,
     e.g. the train_step spans themselves)."""
-    if name == TRAIN_STEP:
+    if name in (TRAIN_STEP, STEP_IN_FLIGHT):
         return None
     component, _, sub = name.partition("/")
-    if name == "learner/publish":
+    if name in ("learner/publish", "learner/publish_copy"):
+        # `learner/publish` also holds the wait for the device; where the
+        # step has an in-flight bracket that wait lies inside compute, so
+        # only the copy (`learner/publish_copy`) is ever left in a gap.
         return "publish"
+    if name in ("learner/bookkeeping", "learner/outside_step"):
+        return sub
     if name in ("learner/device_put", "learner/h2d"):
         # learner/h2d is the donated-ring staging span (zero-copy feed
         # path); learner/device_put the copying one. Same category: both
@@ -143,9 +162,24 @@ def analyze_records(
         spans.append((ts_ns, ts_ns + dur_ns, name, args))
         span_counts[name] = span_counts.get(name, 0) + 1
 
-    steps = sorted(
-        (s, e, args) for s, e, name, args in spans if name == TRAIN_STEP
-    )
+    # One compute interval per step: its in-flight bracket, else its
+    # dispatch; the lineage args ride the dispatch span either way.
+    in_flight = {
+        (args or {}).get("step"): (s, e)
+        for s, e, name, args in spans
+        if name == STEP_IN_FLIGHT
+    }
+    bracketed = 0
+    steps = []
+    for s, e, name, args in spans:
+        if name != TRAIN_STEP:
+            continue
+        step = (args or {}).get("step")
+        if step is not None and step in in_flight:
+            s, e = in_flight[step]
+            bracketed += 1
+        steps.append((s, e, args))
+    steps.sort(key=lambda step: step[:2])
     report: Dict[str, Any] = {
         "schema": SCHEMA_VERSION,
         "span_counts": dict(sorted(span_counts.items())),
@@ -192,6 +226,11 @@ def analyze_records(
         pos = max(pos, e)
     total_gap_ns = measure(uncovered)
 
+    compute_source = (
+        "step_in_flight" if bracketed == len(steps)
+        else "train_step" if bracketed == 0
+        else "mixed"
+    )
     by_category = {
         cat: union(
             [
@@ -223,6 +262,7 @@ def analyze_records(
     learner: Dict[str, Any] = {
         "steps": len(steps),
         "wall_clock_s": _s(wall_ns),
+        "compute_source": compute_source,
         "compute_s": _s(compute_ns),
         "compute_frac": compute_ns / wall_ns if wall_ns else 0.0,
         "gap_total_s": _s(total_gap_ns),
@@ -271,6 +311,18 @@ def analyze_records(
     return report
 
 
+_COMPUTE_SOURCE_NOTE = {
+    "step_in_flight": "learner/step_in_flight: dispatch to device done",
+    "train_step": (
+        "learner/train_step: the DISPATCH only, no step waited for the "
+        "device"
+    ),
+    "mixed": (
+        "learner/step_in_flight where a step has one, else its dispatch"
+    ),
+}
+
+
 def render_report(report: Dict[str, Any]) -> str:
     """Human-readable rendering (the .txt sibling of the JSON)."""
     lines = ["== perf report =="]
@@ -285,12 +337,13 @@ def render_report(report: Dict[str, Any]) -> str:
             f"({learner['compute_frac']:.1%} compute)"
         )
         lines.append(
-            f"  compute       {learner['compute_s']:9.3f}s  "
-            f"{learner['compute_frac']:6.1%}"
+            f"  compute         {learner['compute_s']:9.3f}s  "
+            f"{learner['compute_frac']:6.1%}  "
+            f"({_COMPUTE_SOURCE_NOTE[learner['compute_source']]})"
         )
         for cat in (*GAP_CATEGORIES, "unattributed"):
             lines.append(
-                f"  gap:{cat:<10s}{learner['gaps_s'][cat]:9.3f}s  "
+                f"  gap:{cat:<12s}{learner['gaps_s'][cat]:9.3f}s  "
                 f"{learner['gap_frac'][cat]:6.1%}"
             )
         lines.append(

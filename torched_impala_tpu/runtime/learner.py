@@ -56,6 +56,7 @@ from torched_impala_tpu.parallel import multihost
 from torched_impala_tpu.replay import ReplayConfig, TargetParamStore
 from torched_impala_tpu.runtime.param_store import ParamStore
 from torched_impala_tpu.runtime.traj_ring import TrajectoryRing
+from torched_impala_tpu.telemetry.profiling import watch_compiles
 from torched_impala_tpu.telemetry.registry import Registry, get_registry
 from torched_impala_tpu.telemetry.tracing import (
     FlightRecorder,
@@ -655,6 +656,9 @@ class Learner:
         # global registry (benchmarks isolate runs with fresh ones).
         reg = telemetry if telemetry is not None else get_registry()
         self._telemetry = reg
+        # jit/trace, jit/backend_compile: a compile inside a measured
+        # window shows in that window's timers with its seconds.
+        watch_compiles(reg)
         # Flight recorder (telemetry/tracing.py): the batcher stamps a
         # monotone batch id on every assembled batch and the stage spans
         # (host_stack / device_put / train_step / publish) carry it plus
@@ -673,6 +677,37 @@ class Learner:
         self._m_train_step = reg.timer("learner/train_step")
         self._m_publish = reg.timer("learner/publish")
         self._m_batch_wait = reg.timer("learner/batch_wait")
+        # The step loop's own time. One period runs from one entry of
+        # step_once to the next and is cut, on this one thread and with
+        # shared stamps, into phases that do not overlap and add up to it:
+        #   batch_wait    the blocking get on the device-batch queue
+        #   train_step    the call that ENQUEUES the compiled step
+        #   bookkeeping   _finish_step but for _publish
+        #   step_wait     in _publish: blocked until the device has
+        #                 finished the step (periods that publish only)
+        #   publish_copy  the rest of _publish: issuing the D2H copies
+        #                 before that wait; after it the copies,
+        #                 host_snapshot, ParamStore.publish
+        #   outside_step  from the return to the next entry: the caller
+        # `loop_overhead` is the period less batch_wait and step_wait:
+        # what the host itself spent while it was waiting for nobody.
+        # Each phase is also a flight-recorder span carrying the step's
+        # number, and `learner/step_in_flight` (span only) brackets the
+        # device's work on the step from the host's side: dispatch start
+        # to the moment step_wait returns.
+        self._m_bookkeeping = reg.timer("learner/bookkeeping")
+        self._m_step_wait = reg.timer("learner/step_wait")
+        self._m_publish_copy = reg.timer("learner/publish_copy")
+        self._m_outside_step = reg.timer("learner/outside_step")
+        self._m_loop_overhead = reg.timer("learner/loop_overhead")
+        # Step-loop thread only: the open period's entry stamp, the
+        # seconds it has spent waiting (batch_wait + step_wait), the last
+        # return's stamp, and whether a step completed since the entry (a
+        # call that timed out on the queue leaves the period open).
+        self._period_t0_ns: Optional[int] = None
+        self._period_wait_ns = 0
+        self._period_stepped = False
+        self._returned_ns: Optional[int] = None
         self._m_steps_per_sec = reg.gauge("learner/steps_per_sec")
         self._m_param_lag = reg.gauge("learner/param_lag_frames")
         self._m_enqueue_block = reg.histogram("queue/enqueue_block_ms")
@@ -1453,10 +1488,11 @@ class Learner:
                 return jax.ShapeDtypeStruct(x.shape, x.dtype)
 
             state = (self._params, self._opt_state, self._popart_state)
-            compiled = self._auto_jit.lower(
-                *jax.tree.map(aval, state),
-                *jax.tree.map(aval, example_arrays),
-            ).compile()
+            with self._tracer.span("learner/compile"):
+                compiled = self._auto_jit.lower(
+                    *jax.tree.map(aval, state),
+                    *jax.tree.map(aval, example_arrays),
+                ).compile()
             fmt_args, _ = compiled.input_formats
             state_fmts, batch_fmts = fmt_args[:3], fmt_args[3:]
             # One-time on-device relayout of the live state into the
@@ -2082,31 +2118,45 @@ class Learner:
 
     # ---- stepping ------------------------------------------------------
 
-    def _publish(self) -> None:
+    def _publish(self) -> tuple:
+        """Copy the parameters to the host and hand them to the actors.
+        Returns the stamps (entry, wait start, device done, end) that cut
+        the call into `learner/step_wait` (the middle) and
+        `learner/publish_copy` (the rest)."""
         pub_t0 = time.monotonic_ns()
-        with self._m_publish.time():
-            # Kick off all leaf D2H copies before materializing any:
-            # np.asarray alone would serialize one synchronous transfer
-            # per leaf.
-            for leaf in jax.tree.leaves(self._params):
-                if hasattr(leaf, "copy_to_host_async"):
-                    leaf.copy_to_host_async()
-
-            # host_snapshot, not bare np.asarray: the train step DONATES
-            # the param buffers, so a zero-copy view here would let
-            # actors' params silently morph when XLA reuses the memory
-            # (see types.host_snapshot).
-            self.param_store.publish(
-                self.num_frames, host_snapshot(self._params)
-            )
+        # Kick off all leaf D2H copies before materializing any:
+        # np.asarray alone would serialize one synchronous transfer
+        # per leaf.
+        leaves = jax.tree.leaves(self._params)
+        for leaf in leaves:
+            if hasattr(leaf, "copy_to_host_async"):
+                leaf.copy_to_host_async()
+        # The moment the device is done with the step: the parameters
+        # are outputs of one program and become ready together.
+        # host_snapshot would block on them an instant later; blocking
+        # here first waits for nothing more and adds no launch, copy or
+        # synchronisation, it only tells the wait from the copy.
+        wait_t0 = time.monotonic_ns()
+        jax.block_until_ready(leaves[:1])  # lint: allow(jit-boundary/host-sync-in-hot-loop)
+        ready = time.monotonic_ns()
+        # host_snapshot, not bare np.asarray: the train step DONATES
+        # the param buffers, so a zero-copy view here would let
+        # actors' params silently morph when XLA reuses the memory
+        # (see types.host_snapshot).
+        self.param_store.publish(
+            self.num_frames, host_snapshot(self._params)
+        )
+        end = time.monotonic_ns()
+        self._m_publish.observe((end - pub_t0) / 1e9)
         # Publish closes the lineage loop: the version stamped here is
         # what the next unrolls' lineage records carry as param_version.
         self._tracer.complete(
             "learner/publish",
             pub_t0,
-            time.monotonic_ns() - pub_t0,
+            end - pub_t0,
             {"version": self.num_frames},
         )
+        return pub_t0, wait_t0, ready, end
 
     def step_once(self, timeout: Optional[float] = None) -> Mapping[str, Any]:  # lint: hot-loop
         """Block for one device batch, take one SGD step, publish params.
@@ -2117,7 +2167,10 @@ class Learner:
         """
         if self.error is not None:
             raise RuntimeError("learner batcher thread died") from self.error
-        t0 = time.monotonic()
+        entered = time.monotonic_ns()
+        self._close_period(entered)
+        # The step this call is for, on every span of its period.
+        step_tag = {"step": self.num_steps + self._config.steps_per_dispatch}
         try:
             arrays, batch_version, meta = self._batch_q.get(
                 timeout=timeout
@@ -2126,11 +2179,14 @@ class Learner:
             # Count timed-out waits too (queue.Empty propagates to the run
             # loop): starvation time must not vanish from the diagnostic
             # exactly when starvation is worst.
-            wait = time.monotonic() - t0
-            self._wait_accum += wait
-            self._m_batch_wait.observe(wait)
-        step_t0 = time.monotonic()
-        step_t0_ns = time.monotonic_ns()
+            step_t0_ns = self._returned_ns = time.monotonic_ns()
+            wait_ns = step_t0_ns - entered
+            self._period_wait_ns += wait_ns
+            self._wait_accum += wait_ns / 1e9
+            self._m_batch_wait.observe(wait_ns / 1e9)
+            self._tracer.complete(
+                "learner/batch_wait", entered, wait_ns, step_tag
+            )
         # Mark the step in flight for the batcher's H2D-overlap scoring
         # (_note_h2d); _finish_step records the closed interval.
         self._step_active_since_ns = step_t0_ns
@@ -2153,15 +2209,12 @@ class Learner:
                 target_params,
                 *arrays,
             )
-            return self._finish_step(
-                logs, batch_version, meta, step_t0, step_t0_ns
-            )
+            return self._finish_step(logs, batch_version, meta, step_t0_ns)
         if self._fused_fallback_k:
             return self._finish_step(
                 self._run_fused_chunked(arrays),
                 batch_version,
                 meta,
-                step_t0,
                 step_t0_ns,
             )
         step = (
@@ -2259,9 +2312,36 @@ class Learner:
                         *arrays,
                     )
                 )
-        return self._finish_step(
-            logs, batch_version, meta, step_t0, step_t0_ns
-        )
+        return self._finish_step(logs, batch_version, meta, step_t0_ns)
+
+    def _close_period(self, entered_ns: int) -> None:
+        """At an entry of step_once: what the caller took since the last
+        return (`learner/outside_step`) and, where a step completed since
+        the period opened, the period less its waits
+        (`learner/loop_overhead`); both carry that step's number."""
+        tag = {"step": self.num_steps}
+        if self._returned_ns is not None:
+            outside_ns = entered_ns - self._returned_ns
+            self._m_outside_step.observe(outside_ns / 1e9)
+            self._tracer.complete(
+                "learner/outside_step", self._returned_ns, outside_ns, tag
+            )
+        if self._period_t0_ns is None:
+            self._period_t0_ns = entered_ns
+        elif self._period_stepped:
+            overhead_ns = (
+                entered_ns - self._period_t0_ns - self._period_wait_ns
+            )
+            self._m_loop_overhead.observe(overhead_ns / 1e9)
+            self._tracer.complete(
+                "learner/loop_overhead",
+                self._period_t0_ns,
+                entered_ns - self._period_t0_ns,
+                dict(tag, overhead_ns=overhead_ns),
+            )
+            self._period_t0_ns = entered_ns
+            self._period_wait_ns = 0
+            self._period_stepped = False
 
     def _run_fused_chunked(self, arrays):
         """Fused-dispatch layout fallback: run the [K, ...] superbatch
@@ -2334,19 +2414,20 @@ class Learner:
         self._cost_model.observe_call("train_step", step_dur_ns / 1e9)
 
     def _finish_step(
-        self, logs, batch_version, meta, step_t0, step_t0_ns
+        self, logs, batch_version, meta, step_t0_ns
     ) -> Mapping[str, Any]:
         """Post-step bookkeeping shared by the standard and replay
         paths: counters, trace span, publish/log cadence, target-network
         refresh and ring staleness watermark."""
-        # Host-observed dispatch+compute time of the XLA step. On an
-        # async-dispatch backend the tail of the compute may overlap the
-        # next host iteration; the steady-state EWMA still tracks the
-        # device step (the pipeline re-synchronizes on the batch queue).
-        step_dur_ns = time.monotonic_ns() - step_t0_ns
-        self._step_intervals.append((step_t0_ns, step_t0_ns + step_dur_ns))
+        # The DISPATCH of the XLA step as the host saw it: on an
+        # asynchronous backend the call returns once the step is
+        # enqueued, and the device's time on it is the
+        # `learner/step_in_flight` span (or a device trace).
+        dispatched = time.monotonic_ns()
+        step_dur_ns = dispatched - step_t0_ns
+        self._step_intervals.append((step_t0_ns, dispatched))
         self._step_active_since_ns = None
-        self._m_train_step.observe(time.monotonic() - step_t0)
+        self._m_train_step.observe(step_dur_ns / 1e9)
         self._observe_perf(step_dur_ns)
         T = self._config.unroll_length
         K = self._config.steps_per_dispatch
@@ -2432,10 +2513,29 @@ class Learner:
         logs["num_frames"] = self.num_frames
         logs["num_steps"] = self.num_steps
         logs["param_lag_frames"] = self.num_frames - batch_version
+        step_tag = {"step": self.num_steps}
+        # Bookkeeping is cut in two by _publish: `booked_ns` is the piece
+        # before it, and `resumed` where the second piece starts.
+        booked_ns = 0
+        resumed = dispatched
         if crossed_interval(
             self.num_steps, K, self._config.publish_interval
         ):
-            self._publish()
+            pub_t0, wait_t0, ready, resumed = self._publish()
+            booked_ns = pub_t0 - dispatched
+            self._period_wait_ns += ready - wait_t0
+            self._m_step_wait.observe((ready - wait_t0) / 1e9)
+            self._m_publish_copy.observe(
+                (wait_t0 - pub_t0 + resumed - ready) / 1e9
+            )
+            for name, t0, t1 in (
+                ("learner/bookkeeping", dispatched, pub_t0),
+                ("learner/publish_copy", pub_t0, wait_t0),
+                ("learner/step_wait", wait_t0, ready),
+                ("learner/step_in_flight", step_t0_ns, ready),
+                ("learner/publish_copy", ready, resumed),
+            ):
+                self._tracer.complete(name, t0, t1 - t0, step_tag)
         if (
             self._logger is not None or self._health is not None
         ) and crossed_interval(
@@ -2488,6 +2588,13 @@ class Learner:
                 self._health.observe(host_logs, lineage=meta)
         if self.post_step is not None:
             self.post_step(self.num_steps)
+        self._period_stepped = True
+        self._returned_ns = time.monotonic_ns()
+        tail_ns = self._returned_ns - resumed
+        self._m_bookkeeping.observe((booked_ns + tail_ns) / 1e9)
+        self._tracer.complete(
+            "learner/bookkeeping", resumed, tail_ns, step_tag
+        )
         return logs
 
     def attach_health(self, monitor) -> None:

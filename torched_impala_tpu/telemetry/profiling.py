@@ -13,7 +13,12 @@ directory (open with TensorBoard's profile plugin or Perfetto):
   it) — the "why is it slow right now" affordance, no restart needed.
 
 Each capture writes into a fresh `<trace_dir>/<tag>` subdirectory so
-repeated captures never clobber each other. Capture state is guarded by a
+repeated captures never clobber each other. Beside the capture's
+`.xplane.pb`, `stop()` leaves `host_spans.json`: the flight recorder's
+records of the capture on the epoch clock, so that the program's own spans
+(the learner's step phases, `learner/step_in_flight`) can be laid beside
+the device's operations. The profiler's host tracer cannot supply them:
+see `HOST_TRACER_LEVEL`. Capture state is guarded by a
 lock: the signal handler, the learner thread, and test code may all
 toggle; `jax.profiler.start_trace` is process-global, so exactly one
 capture can be active at a time.
@@ -21,14 +26,91 @@ capture can be active at a time.
 
 from __future__ import annotations
 
+import glob
 import os
 import signal
 import sys
 import threading
 import time
+import weakref
 from typing import Optional, Tuple
 
 from torched_impala_tpu.telemetry.registry import Registry, get_registry
+from torched_impala_tpu.telemetry.tracing import (
+    FlightRecorder,
+    get_recorder,
+)
+
+HOST_SPANS_FILE = "host_spans.json"
+CLOCK_NOTE = (
+    "ts: microseconds since the Unix epoch (time.time_ns). The .xplane.pb "
+    "beside this file counts start_ns from the profile_start_time of its "
+    "'Task Environment' plane, itself epoch ns: subtract it to lay the two "
+    "side by side."
+)
+
+# What a capture asks of the profiler's host side: nothing. Chip readings in
+# the DMLab-30 cell's shapes (T=100, B=64; PERF.md, my chip runs, PR 24): the
+# step period is 98.8 ms untraced and 99.7 ms inside a capture at level 0;
+# at level 1 it is 1,818 ms (14 million host events in 2.5 s from the
+# runtime threads that transpose the observation batch on its way to the
+# device, a 467 MB capture that takes 64 s to write); at the default, 2, PR
+# 23 read 3.7 s a step. So the host's side of a capture is the flight
+# recorder's own spans, written beside it (`host_spans.json`).
+HOST_TRACER_LEVEL = 0
+PYTHON_TRACER_LEVEL = 0
+
+
+def profile_options():
+    """`jax.profiler.ProfileOptions` of every capture this module starts."""
+    import jax
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = PYTHON_TRACER_LEVEL
+    options.host_tracer_level = HOST_TRACER_LEVEL
+    return options
+
+
+# ---- compiles, seen ------------------------------------------------------
+
+# jax.monitoring duration events -> the timer each feeds. The backend-compile
+# event brackets `compile_or_get_cached`, so a hit in the persistent cache
+# counts too (with the seconds the retrieval took).
+COMPILE_EVENT_TIMERS = {
+    "/jax/core/compile/backend_compile_duration": "jit/backend_compile",
+    "/jax/core/compile/jaxpr_trace_duration": "jit/trace",
+}
+_compile_watchers: "weakref.WeakSet[Registry]" = weakref.WeakSet()
+_compile_lock = threading.Lock()
+_compile_listening = False
+
+
+def _on_jax_duration(event: str, duration_secs: float, **_kwargs) -> None:
+    name = COMPILE_EVENT_TIMERS.get(event)
+    if name is None:
+        return
+    for reg in tuple(_compile_watchers):
+        reg.timer(name).observe(duration_secs)
+
+
+def watch_compiles(registry: Registry) -> None:
+    """Feed every jit trace and backend compile of this process into
+    `registry`'s `jit/trace` and `jit/backend_compile` timers, so that a
+    compile inside a measured window shows there with its seconds. One
+    `jax.monitoring` listener per process, however many registries ask;
+    a registry is dropped when nothing else holds it."""
+    global _compile_listening
+    import jax
+
+    with _compile_lock:
+        for name in COMPILE_EVENT_TIMERS.values():
+            registry.timer(name)  # the series exists before the first compile
+        _compile_watchers.add(registry)
+        if not _compile_listening:
+            jax.monitoring.register_event_duration_secs_listener(
+                _on_jax_duration
+            )
+            _compile_listening = True
 
 
 def parse_profile_steps(spec: str) -> Tuple[int, int]:
@@ -60,10 +142,13 @@ class ProfilerCapture:
         self,
         trace_dir: str = "traces",
         registry: Optional[Registry] = None,
+        recorder: Optional[FlightRecorder] = None,
     ):
         self.trace_dir = trace_dir
+        self._recorder = recorder if recorder is not None else get_recorder()
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
+        self._since_ns = 0
         self._captures = 0
         reg = registry if registry is not None else get_registry()
         self._capture_counter = reg.counter("profiler/captures")
@@ -87,7 +172,10 @@ class ProfilerCapture:
             tag = tag or f"capture_{self._captures:03d}_{int(time.time())}"
             path = os.path.join(self.trace_dir, tag)
             os.makedirs(path, exist_ok=True)
-            jax.profiler.start_trace(path)
+            self._since_ns, _ = self._recorder.sync_clock()
+            jax.profiler.start_trace(
+                path, profiler_options=profile_options()
+            )
             self._active_dir = path
             self._capture_counter.inc()
             print(
@@ -108,6 +196,7 @@ class ProfilerCapture:
             path, self._active_dir = self._active_dir, None
             try:
                 jax.profiler.stop_trace()
+                self._write_host_spans(path)
             finally:
                 print(
                     f"[profiler] trace written -> {path}",
@@ -115,6 +204,30 @@ class ProfilerCapture:
                     flush=True,
                 )
             return path
+
+    def _write_host_spans(self, path: str) -> None:
+        """The recorder's records of this capture, as a Chrome trace on the
+        epoch clock, beside the capture's `.xplane.pb` (in `path` itself
+        when the profiler wrote none)."""
+        written = glob.glob(
+            os.path.join(path, "**", "*.xplane.pb"), recursive=True
+        )
+        where = (
+            os.path.dirname(max(written, key=os.path.getmtime))
+            if written
+            else path
+        )
+        mono_ns, wall_ns = self._recorder.clock_pair
+        self._recorder.export(
+            os.path.join(where, HOST_SPANS_FILE),
+            since_ns=self._since_ns,
+            epoch=True,
+            metadata={
+                "clock": CLOCK_NOTE,
+                "clock_pair_ns": {"monotonic": mono_ns, "epoch": wall_ns},
+                "host_tracer_level": HOST_TRACER_LEVEL,
+            },
+        )
 
     def toggle(self) -> None:
         if self.active:

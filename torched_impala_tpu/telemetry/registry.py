@@ -142,8 +142,10 @@ class Gauge(_Metric):
 
 class EwmaTimer(_Metric):
     """EWMA of observed durations, emitted in milliseconds as
-    `<name>_ms` plus a lifetime `<name>_calls` count. The `span()` context
-    manager records into one of these."""
+    `<name>_ms` plus a lifetime `<name>_calls` count and the lifetime sum
+    `<name>_total_s` (a share of a window is a difference of two sums;
+    an average cannot give it). The `span()` context manager records
+    into one of these."""
 
     kind = "timer"
 
@@ -154,12 +156,14 @@ class EwmaTimer(_Metric):
         self._alpha = alpha
         self._ewma_s: Optional[float] = None
         self._calls = 0
+        self._seconds = 0.0
 
     def observe(self, seconds: float) -> None:
         if not self._registry.enabled:
             return
         with self._lock:
             self._calls += 1
+            self._seconds += seconds
             if self._ewma_s is None:
                 self._ewma_s = seconds
             else:
@@ -182,14 +186,22 @@ class EwmaTimer(_Metric):
         with self._lock:
             return self._calls
 
+    @property
+    def seconds(self) -> float:
+        """Sum of every observed duration."""
+        with self._lock:
+            return self._seconds
+
     def snapshot_into(self, out: Dict[str, float]) -> None:
         with self._lock:
             ewma = self._ewma_s
             calls = self._calls
+            seconds = self._seconds
         out[f"{PREFIX}/{self.name}_ms"] = (
             float("nan") if ewma is None else ewma * 1e3
         )
         out[f"{PREFIX}/{self.name}_calls"] = calls
+        out[f"{PREFIX}/{self.name}_total_s"] = seconds
 
 
 class _SpanContext:

@@ -129,6 +129,16 @@ class FlightRecorder:
         # tid -> thread name, filled lazily on first record per thread
         # (export emits them as Chrome thread_name metadata).
         self._thread_names: Dict[int, str] = {}
+        self.clock_pair = self.sync_clock()
+
+    def sync_clock(self) -> Tuple[int, int]:
+        """Read the two clocks together: records are stamped
+        `time.monotonic_ns()`, a profiler capture counts from the epoch,
+        and this pair relates them (`epoch=True` in the exports).
+        `ProfilerCapture.start` reads it again, so that a capture does
+        not carry the drift since the recorder was made."""
+        self.clock_pair = (time.monotonic_ns(), time.time_ns())
+        return self.clock_pair
 
     # -- recording (the hot path) -----------------------------------------
 
@@ -209,17 +219,29 @@ class FlightRecorder:
     # -- export ------------------------------------------------------------
 
     def to_chrome_events(
-        self, records: Optional[List[tuple]] = None
+        self,
+        records: Optional[List[tuple]] = None,
+        *,
+        since_ns: Optional[int] = None,
+        epoch: bool = False,
     ) -> List[dict]:
         """Chrome-trace event dicts: components map to trace 'processes'
         (one row per pipeline stage in Perfetto), threads nest under
-        them, lineage rides `args`."""
+        them, lineage rides `args`. `since_ns` (a `time.monotonic_ns()`
+        reading) keeps only what ended at or after it; `epoch` writes
+        `ts` on the epoch clock (`clock_pair`) instead of the monotonic
+        one."""
         records = self.tail() if records is None else records
+        mono_ns, wall_ns = self.clock_pair
+        shift_ns = wall_ns - mono_ns if epoch else 0
         pids: Dict[str, int] = {}
         events: List[dict] = []
         thread_names = dict(self._thread_names)
         seen_tids = set()
         for ts_ns, dur_ns, phase, name, tid, lineage in records:
+            if since_ns is not None and ts_ns + dur_ns < since_ns:
+                continue
+            ts_ns += shift_ns
             component = name.split("/", 1)[0]
             pid = pids.setdefault(component, len(pids) + 1)
             ev: Dict[str, Any] = {
@@ -268,19 +290,28 @@ class FlightRecorder:
                 )
         return meta + events
 
-    def export(self, path: str) -> int:
+    def export(
+        self,
+        path: str,
+        *,
+        since_ns: Optional[int] = None,
+        epoch: bool = False,
+        metadata: Optional[dict] = None,
+    ) -> int:
         """Write the retained records as Chrome-trace JSON (`{"traceEvents":
         [...]}`); returns the number of non-metadata events written. Load
         in Perfetto (ui.perfetto.dev → Open trace file) or
-        chrome://tracing."""
-        events = self.to_chrome_events()
+        chrome://tracing. `since_ns` and `epoch` as in `to_chrome_events`;
+        `metadata` is written beside the events."""
+        events = self.to_chrome_events(since_ns=since_ns, epoch=epoch)
         parent = os.path.dirname(path)
         if parent:
             os.makedirs(parent, exist_ok=True)
+        doc = {"traceEvents": events, "displayTimeUnit": "ms"}
+        if metadata:
+            doc["metadata"] = metadata
         with open(path, "w", encoding="utf-8") as f:
-            json.dump(
-                {"traceEvents": events, "displayTimeUnit": "ms"}, f
-            )
+            json.dump(doc, f)
         return sum(1 for e in events if e["ph"] != "M")
 
     def format_tail(self, n: int = 48) -> str:
