@@ -60,7 +60,11 @@ def test_integration_one_push_one_step(use_lstm):
         actor.unroll_and_push()
     learner.start()
     logs = learner.step_once(timeout=30)
+    # One step stays in flight: its version is out a call later, or when
+    # the loop is drained (stop() does).
+    assert learner.param_store.version == 0
     learner.stop()
+    assert learner.param_store.version == T * B
 
     assert np.isfinite(logs["total_loss"])
     assert logs["num_frames"] == T * B

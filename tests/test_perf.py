@@ -262,6 +262,39 @@ def test_in_flight_bracket_is_the_compute_not_the_dispatch():
     assert "dispatch to device done" in render_report({"learner": learner})
 
 
+def test_overlapping_brackets_of_a_pipelined_loop_add_up_to_the_window():
+    """One step in flight: the bracket of step k+1 opens at its dispatch,
+    59 ms before the wait for step k returns. Each step's compute is its
+    own bracket from where the one before ended, so the three brackets
+    (119 + 119 + 63 ms) count as the 183 ms of wall clock they cover."""
+    records = []
+    for k in range(1, 4):
+        t0, tag, prev = 60 * (k - 1), {"step": k}, {"step": k - 1}
+        records += [  # the call for step k
+            _span(t0 + 1, 2, "learner/train_step", tag),
+            _span(t0 + 3, 1, "learner/publish_copy", tag),  # queued
+        ]
+        if k > 1:
+            records += [  # ... settles step k-1 behind that dispatch
+                _span(t0 + 4, 56, "learner/step_wait", prev),
+                _span(t0 - 59, 119, "learner/step_in_flight", prev),
+                _span(t0 + 60, 1, "learner/publish_copy", prev),  # landed
+            ]
+    records += [  # the drain settles step 3
+        _span(183, 1, "learner/step_wait", {"step": 3}),
+        _span(121, 63, "learner/step_in_flight", {"step": 3}),
+    ]
+    learner = analyze_records(records)["learner"]
+    assert learner["compute_source"] == "step_in_flight"
+    assert learner["steps"] == 3
+    assert learner["wall_clock_s"] == pytest.approx(0.183)
+    assert learner["compute_s"] == pytest.approx(0.183)
+    assert learner["compute_frac"] == pytest.approx(1.0)
+    assert learner["gap_total_s"] == 0.0
+    assert learner["coverage_frac"] == pytest.approx(1.0)
+    assert learner["fresh"]["compute_s"] == pytest.approx(0.183)
+
+
 def test_without_a_bracket_the_dispatch_stands_in_and_the_report_says_so():
     # publish_interval 2: only step 2 waited for the device
     records = [

@@ -369,6 +369,13 @@ def test_learner_step_names_exact_unrolls_and_lags(use_ring):
     steps = [r for r in tail if r[3] == "learner/train_step"]
     unrolls = [r for r in tail if r[3] == "actor/unroll"]
     assert len(steps) == 2
+    # Each version under its own frame count, in order, and settled
+    # after its own step's call (the last by stop()'s drain): not under
+    # the counter's value at that time.
+    published = [r for r in tail if r[3] == "learner/publish"]
+    assert [r[5]["version"] for r in published] == [0, T * B, 2 * T * B]
+    for step, publish in zip(steps, published[1:]):
+        assert publish[0] >= step[0] + step[1]
     minted = {r[5]["lid"]: r[5]["param_version"] for r in unrolls}
     frames_per_step = T * B
     for k, rec_step in enumerate(steps, start=1):

@@ -18,9 +18,16 @@ Compute is the host's bracket around the device's work on a step,
 device returns), where the step has one. `learner/train_step` times only
 the DISPATCH of the step: on an asynchronous backend it is a few ms of a
 60 ms step, and tiling the wall clock with it would book the device's
-compute as a `publish` gap. A step without the bracket (nothing was
-published in its period, so the host never waited for it) falls back to
-its `learner/train_step` span; `compute_source` says which was used.
+compute as a `publish` gap. A step without the bracket (it owed the host
+nothing, so the host never waited for it) falls back to its
+`learner/train_step` span; `compute_source` says which was used.
+
+The step loop keeps one step in flight: step k+1 is dispatched before
+the wait for step k returns, so two brackets overlap by design. The
+device runs the steps one after the other, so a step's compute is its own
+bracket from where the bracket before it ended: the overlap is time step
+k+1 sat queued behind step k and is step k's compute, counted once. The
+computes then never add up to more than the wall clock.
 
 Attribution is by interval union-and-subtract, so a feeder span that
 overlaps a train_step (healthy pipelining) only charges the part that
@@ -190,7 +197,12 @@ def analyze_records(
         report["learner"] = {"steps": 0}
         return report
 
-    wall_ns = steps[-1][1] - steps[0][0]
+    # One step in flight: clip each bracket to where the one before ended.
+    done = steps[0][0]
+    for i, (s, e, args) in enumerate(steps):
+        steps[i] = (min(max(s, done), e), e, args)
+        done = max(done, e)
+    wall_ns = done - steps[0][0]
     compute_ns = sum(e - s for s, e, _ in steps)
 
     # Fresh vs replayed compute (IMPACT lineage rides the span args;

@@ -231,6 +231,30 @@ class BatchLineage(NamedTuple):
     ring_slot: int = -1
 
 
+class _InFlight(NamedTuple):
+    """What a dispatched step still owes the host. The step loop settles
+    it one call later, behind the NEXT step's dispatch, so the device
+    never stands idle while the host waits, copies and publishes."""
+
+    step: int  # num_steps after the step
+    version: int  # num_frames after the step: what its parameters publish as
+    dispatch_t0_ns: int  # where its `learner/step_in_flight` span opens
+    probe: list  # one device leaf that is ready when the step (and copy) is
+    snapshot: Any = None  # on-device copy of its parameters, D2H requested
+    ring_slot: int = -1  # donated ring slot to recycle once it completed
+    logs: Optional[dict] = None  # its log scalars, when it crossed log_interval
+    meta: Optional[BatchLineage] = None
+
+
+@jax.jit
+def _publish_snapshot(params):
+    """Step k's parameters in fresh buffers, as one program queued right
+    behind step k. Step k+1 donates the originals, so the copy to the host
+    reads these instead. `jnp.copy`, not an identity: a jitted identity
+    forwards its inputs and copies nothing."""
+    return jax.tree.map(jnp.copy, params)
+
+
 # Sanitizer for flax module names -> health gauge sub-keys
 # (`health/grad_norm_<group>` must satisfy the registry NAME_RE:
 # "Conv_0" -> "conv_0").
@@ -679,27 +703,36 @@ class Learner:
         self._m_batch_wait = reg.timer("learner/batch_wait")
         # The step loop's own time. One period runs from one entry of
         # step_once to the next and is cut, on this one thread and with
-        # shared stamps, into phases that do not overlap and add up to it:
+        # shared stamps, into phases that do not overlap and add up to it.
+        # The call for step k dispatches step k first and settles step
+        # k-1 after (one step in flight), so a period holds phases of both:
         #   batch_wait    the blocking get on the device-batch queue
-        #   train_step    the call that ENQUEUES the compiled step
-        #   bookkeeping   _finish_step but for _publish
-        #   step_wait     in _publish: blocked until the device has
-        #                 finished the step (periods that publish only)
-        #   publish_copy  the rest of _publish: issuing the D2H copies
-        #                 before that wait; after it the copies,
+        #   train_step    the call that ENQUEUES the compiled step k
+        #   bookkeeping   the rest of _finish_step: counters, lineage,
+        #                 step k-1's slot release and logger, post_step
+        #   publish_copy  two pieces: queueing step k's snapshot program
+        #                 and its D2H; after the wait, step k-1's copies,
         #                 host_snapshot, ParamStore.publish
+        #   step_wait     blocked until the device has finished step k-1
+        #                 and its snapshot (steps that owe the host
+        #                 something: a publish, log scalars, a ring slot)
         #   outside_step  from the return to the next entry: the caller
         # `loop_overhead` is the period less batch_wait and step_wait:
         # what the host itself spent while it was waiting for nobody.
-        # Each phase is also a flight-recorder span carrying the step's
-        # number, and `learner/step_in_flight` (span only) brackets the
-        # device's work on the step from the host's side: dispatch start
-        # to the moment step_wait returns.
+        # Each phase is also a flight-recorder span carrying the number
+        # of the step it belongs to, and `learner/step_in_flight` (span
+        # only) brackets the device's work on a step from the host's
+        # side: its dispatch's start to the moment its wait returns, one
+        # call later, so two such spans overlap. `dispatch_lead` is
+        # observed when step k-1 was still on the device as the dispatch
+        # of step k returned: the time from there to step k-1 being
+        # ready, that is how far ahead of the device the loop launched.
         self._m_bookkeeping = reg.timer("learner/bookkeeping")
         self._m_step_wait = reg.timer("learner/step_wait")
         self._m_publish_copy = reg.timer("learner/publish_copy")
         self._m_outside_step = reg.timer("learner/outside_step")
         self._m_loop_overhead = reg.timer("learner/loop_overhead")
+        self._m_dispatch_lead = reg.timer("learner/dispatch_lead")
         # Step-loop thread only: the open period's entry stamp, the
         # seconds it has spent waiting (batch_wait + step_wait), the last
         # return's stamp, and whether a step completed since the entry (a
@@ -708,6 +741,14 @@ class Learner:
         self._period_wait_ns = 0
         self._period_stepped = False
         self._returned_ns: Optional[int] = None
+        # The dispatched step that is not settled yet (None: nothing in
+        # flight), and what settling has cost since the phase timers were
+        # last observed: [step_wait, publish_copy, bookkeeping] ns. The
+        # lock keeps settlements whole and in order when stop() or
+        # set_state() drain from another thread than the step loop's.
+        self._in_flight: Optional[_InFlight] = None
+        self._settle_lock = threading.RLock()
+        self._settled_ns = [0, 0, 0]
         self._m_steps_per_sec = reg.gauge("learner/steps_per_sec")
         self._m_param_lag = reg.gauge("learner/param_lag_frames")
         self._m_enqueue_block = reg.histogram("queue/enqueue_block_ms")
@@ -769,10 +810,6 @@ class Learner:
         # only — reset by _put_batch before each placement).
         self._put_shards = 0  # lint: guarded-by(gil)
         self._put_overlap_ns = 0  # lint: guarded-by(gil)
-        # Donated ring slots awaiting their consuming step's completion:
-        # (slot, probe) pairs, released by _finish_step one step behind
-        # so the release never stalls the pipeline.
-        self._donated_slots: collections.deque = collections.deque()
         reg.gauge("queue/capacity").set(capacity)
         # Live depth, read lazily at snapshot time. Weakref: the global
         # registry must not keep a dead learner's queue (and its queued
@@ -866,7 +903,7 @@ class Learner:
             )
 
         self.param_store = ParamStore()
-        self._publish()
+        self._publish_now()
 
         # Target network (replay/target_store.py): pinned on-device copy
         # of the params the surrogate clips against, refreshed every
@@ -2115,55 +2152,140 @@ class Learner:
             # and exit, mirroring enqueue's contract) and the batcher's
             # pop_ready wait.
             self.traj_ring.close()
+        try:
+            self.drain()
+        except Exception:
+            # A failed step has already raised from step_once; teardown
+            # (run's finally, a supervisor) must not mask that with the
+            # same failure met again on the way out.
+            import logging
+
+            logging.getLogger(__name__).exception(
+                "stop: settling the step in flight failed"
+            )
 
     # ---- stepping ------------------------------------------------------
 
-    def _publish(self) -> tuple:
-        """Copy the parameters to the host and hand them to the actors.
-        Returns the stamps (entry, wait start, device done, end) that cut
-        the call into `learner/step_wait` (the middle) and
-        `learner/publish_copy` (the rest)."""
-        pub_t0 = time.monotonic_ns()
-        # Kick off all leaf D2H copies before materializing any:
-        # np.asarray alone would serialize one synchronous transfer
-        # per leaf.
-        leaves = jax.tree.leaves(self._params)
-        for leaf in leaves:
+    def _publish_now(self) -> None:
+        """Copy the live parameters to the host and hand them to the
+        actors, blocking: construction and `set_state`, where nothing is
+        in flight. The step loop publishes through `_settle`."""
+        t0 = time.monotonic_ns()
+        # All leaf D2H copies requested before any is materialised:
+        # np.asarray alone would serialise one transfer per leaf.
+        for leaf in jax.tree.leaves(self._params):
             if hasattr(leaf, "copy_to_host_async"):
                 leaf.copy_to_host_async()
-        # The moment the device is done with the step: the parameters
-        # are outputs of one program and become ready together.
-        # host_snapshot would block on them an instant later; blocking
-        # here first waits for nothing more and adds no launch, copy or
-        # synchronisation, it only tells the wait from the copy.
-        wait_t0 = time.monotonic_ns()
-        jax.block_until_ready(leaves[:1])  # lint: allow(jit-boundary/host-sync-in-hot-loop)
-        ready = time.monotonic_ns()
-        # host_snapshot, not bare np.asarray: the train step DONATES
-        # the param buffers, so a zero-copy view here would let
-        # actors' params silently morph when XLA reuses the memory
-        # (see types.host_snapshot).
         self.param_store.publish(
             self.num_frames, host_snapshot(self._params)
         )
-        end = time.monotonic_ns()
-        self._m_publish.observe((end - pub_t0) / 1e9)
-        # Publish closes the lineage loop: the version stamped here is
-        # what the next unrolls' lineage records carry as param_version.
+        dur = time.monotonic_ns() - t0
+        self._m_publish.observe(dur / 1e9)
         self._tracer.complete(
-            "learner/publish",
-            pub_t0,
-            end - pub_t0,
-            {"version": self.num_frames},
+            "learner/publish", t0, dur, {"version": self.num_frames}
         )
-        return pub_t0, wait_t0, ready, end
+
+    def drain(self) -> None:
+        """Settle the step in flight now: wait for it, publish its
+        parameters, recycle its ring slot, log it. After it the
+        `ParamStore` holds the newest version and nothing of the loop is
+        left on the device. `stop()`, `set_state()` and the end of `run()`
+        call it; between two calls of `step_once` it is the caller's time
+        and counts in `learner/outside_step` as well."""
+        self._settle(time.monotonic_ns())
+        self._observe_settled(0, 0)
+
+    def _settle(
+        self,
+        t0: int,
+        then: Optional[_InFlight] = None,
+        led_from: Optional[int] = None,
+    ) -> int:
+        """Put `then` in flight and settle the step that was, from stamp
+        `t0` on; returns the stamp where that ended (`t0` where nothing
+        was in flight). What it cost is added to `_settled_ns` by phase,
+        with one span each under the settled step's number. `led_from`:
+        the stamp at which the next step's dispatch returned while this
+        one was still on the device."""
+        with self._settle_lock:
+            done, self._in_flight = self._in_flight, then
+            if done is None:
+                return t0
+            tag = {"step": done.step}
+            jax.block_until_ready(done.probe)  # lint: allow(jit-boundary/host-sync-in-hot-loop)
+            ready = time.monotonic_ns()
+            self._period_wait_ns += ready - t0
+            if led_from is not None:
+                self._m_dispatch_lead.observe((ready - led_from) / 1e9)
+            self._tracer.complete("learner/step_wait", t0, ready - t0, tag)
+            self._tracer.complete(
+                "learner/step_in_flight",
+                done.dispatch_t0_ns,
+                ready - done.dispatch_t0_ns,
+                tag,
+            )
+            landed = ready
+            if done.snapshot is not None:
+                # host_snapshot, not bare np.asarray: published trees
+                # must own their bytes (see types.host_snapshot). The
+                # D2H was requested when the snapshot was queued, so this
+                # mostly finds the bytes on the host already.
+                self.param_store.publish(
+                    done.version, host_snapshot(done.snapshot)
+                )
+                landed = time.monotonic_ns()
+                self._m_publish.observe((landed - t0) / 1e9)
+                self._tracer.complete(
+                    "learner/publish_copy", ready, landed - ready, tag
+                )
+                # Publish closes the lineage loop: the version stamped
+                # here is what the next unrolls' lineage records carry
+                # as param_version.
+                self._tracer.complete(
+                    "learner/publish",
+                    t0,
+                    landed - t0,
+                    {"version": done.version},
+                )
+            if done.ring_slot >= 0:
+                # Donated ring batch: its consuming step has completed,
+                # XLA is done scribbling on the slot's buffers.
+                self.traj_ring.release(done.ring_slot)
+            if done.logs is not None:
+                self._log_step(done)
+            end = time.monotonic_ns()
+            self._tracer.complete(
+                "learner/bookkeeping", landed, end - landed, tag
+            )
+            self._settled_ns[0] += ready - t0
+            self._settled_ns[1] += landed - ready
+            self._settled_ns[2] += end - landed
+            return end
+
+    def _observe_settled(self, copy_ns: int, book_ns: int) -> None:
+        """Observe the phase timers with what `_settle` has cost since
+        the last time, plus the caller's own pieces: once per call of
+        `step_once`, so that a mean per call is a mean per step."""
+        with self._settle_lock:
+            wait_ns, settled_copy, settled_book = self._settled_ns
+            self._settled_ns = [0, 0, 0]
+        if wait_ns:
+            self._m_step_wait.observe(wait_ns / 1e9)
+        if copy_ns + settled_copy:
+            self._m_publish_copy.observe((copy_ns + settled_copy) / 1e9)
+        if book_ns + settled_book:
+            self._m_bookkeeping.observe((book_ns + settled_book) / 1e9)
 
     def step_once(self, timeout: Optional[float] = None) -> Mapping[str, Any]:  # lint: hot-loop
-        """Block for one device batch, take one SGD step, publish params.
+        """Block for one device batch, dispatch one SGD step on it, then
+        settle the step before it: wait for that one, publish its
+        parameters, log it. One step stays in flight, so the device has
+        the next step queued while it runs the current one; `drain()`
+        settles the last.
 
         Raises queue.Empty on timeout. Returned log values are device scalars
         (no forced sync); the configured logger receives host floats every
-        `log_interval` steps.
+        `log_interval` steps, one call after the step they belong to.
         """
         if self.error is not None:
             raise RuntimeError("learner batcher thread died") from self.error
@@ -2171,6 +2293,13 @@ class Learner:
         self._close_period(entered)
         # The step this call is for, on every span of its period.
         step_tag = {"step": self.num_steps + self._config.steps_per_dispatch}
+        wait_from = entered
+        if self._in_flight is not None and self._batch_q.empty():
+            # Nothing to dispatch ahead of the device: settle the step in
+            # flight while waiting for nobody, so that a starved learner
+            # publishes each version as soon as the device has it, not
+            # when the next batch arrives.
+            wait_from = self._settle(entered)
         try:
             arrays, batch_version, meta = self._batch_q.get(
                 timeout=timeout
@@ -2180,12 +2309,12 @@ class Learner:
             # loop): starvation time must not vanish from the diagnostic
             # exactly when starvation is worst.
             step_t0_ns = self._returned_ns = time.monotonic_ns()
-            wait_ns = step_t0_ns - entered
+            wait_ns = step_t0_ns - wait_from
             self._period_wait_ns += wait_ns
             self._wait_accum += wait_ns / 1e9
             self._m_batch_wait.observe(wait_ns / 1e9)
             self._tracer.complete(
-                "learner/batch_wait", entered, wait_ns, step_tag
+                "learner/batch_wait", wait_from, wait_ns, step_tag
             )
         # Mark the step in flight for the batcher's H2D-overlap scoring
         # (_note_h2d); _finish_step records the closed interval.
@@ -2416,14 +2545,26 @@ class Learner:
     def _finish_step(
         self, logs, batch_version, meta, step_t0_ns
     ) -> Mapping[str, Any]:
-        """Post-step bookkeeping shared by the standard and replay
-        paths: counters, trace span, publish/log cadence, target-network
-        refresh and ring staleness watermark."""
+        """After the dispatch, shared by the standard and replay paths:
+        counters, trace span, target-network refresh and ring staleness
+        watermark for this step; its snapshot queued where it crossed
+        `publish_interval`; then the step before it settled (`_settle`:
+        wait, publish, slot release, log)."""
         # The DISPATCH of the XLA step as the host saw it: on an
         # asynchronous backend the call returns once the step is
         # enqueued, and the device's time on it is the
         # `learner/step_in_flight` span (or a device trace).
         dispatched = time.monotonic_ns()
+        # Launched ahead of the device? Asked here, before the host does
+        # anything else, without blocking.
+        before = self._in_flight
+        led_from = (
+            dispatched
+            if before is not None and not before.probe[0].is_ready()
+            else None
+        )
+        # A device leaf of this step, taken before host counters join.
+        probe = jax.tree.leaves(logs)[:1]
         step_dur_ns = dispatched - step_t0_ns
         self._step_intervals.append((step_t0_ns, dispatched))
         self._step_active_since_ns = None
@@ -2464,22 +2605,6 @@ class Learner:
         # the param_lag_frames gauge summarizes by its min-version).
         if meta is None:
             meta = BatchLineage(batch=-1)
-        if meta.ring_slot >= 0:
-            # Donated ring batch: recycle the slot only once its
-            # consuming step completed. Release runs ONE step behind —
-            # block on the previous step's log leaf, which finished
-            # before this step started executing (device steps are
-            # serialized by the params chain) — so recycling never
-            # stalls the just-dispatched step.
-            self._donated_slots.append(
-                (meta.ring_slot, jax.tree.leaves(logs)[:1])
-            )
-            while len(self._donated_slots) > 1:
-                slot, probe = self._donated_slots.popleft()
-                # A completion stall the pipeline couldn't hide debits
-                # the collective's overlap credit (_timed_sync).
-                self._timed_sync(probe)  # lint: allow(jit-boundary/host-sync-in-hot-loop)
-                self.traj_ring.release(slot)
         lags = [self.num_frames - v for v in meta.versions]
         self._tracer.complete(
             "learner/train_step",
@@ -2514,88 +2639,105 @@ class Learner:
         logs["num_steps"] = self.num_steps
         logs["param_lag_frames"] = self.num_frames - batch_version
         step_tag = {"step": self.num_steps}
-        # Bookkeeping is cut in two by _publish: `booked_ns` is the piece
-        # before it, and `resumed` where the second piece starts.
-        booked_ns = 0
-        resumed = dispatched
-        if crossed_interval(
+        publishes = crossed_interval(
             self.num_steps, K, self._config.publish_interval
-        ):
-            pub_t0, wait_t0, ready, resumed = self._publish()
-            booked_ns = pub_t0 - dispatched
-            self._period_wait_ns += ready - wait_t0
-            self._m_step_wait.observe((ready - wait_t0) / 1e9)
-            self._m_publish_copy.observe(
-                (wait_t0 - pub_t0 + resumed - ready) / 1e9
-            )
-            for name, t0, t1 in (
-                ("learner/bookkeeping", dispatched, pub_t0),
-                ("learner/publish_copy", pub_t0, wait_t0),
-                ("learner/step_wait", wait_t0, ready),
-                ("learner/step_in_flight", step_t0_ns, ready),
-                ("learner/publish_copy", ready, resumed),
-            ):
-                self._tracer.complete(name, t0, t1 - t0, step_tag)
-        if (
+        )
+        logs_due = (
             self._logger is not None or self._health is not None
-        ) and crossed_interval(
-            self.num_steps, K, self._config.log_interval
-        ):
-            now = time.monotonic()
-            if self._last_log_t is not None:
-                elapsed = max(now - self._last_log_t, 1e-9)
-                # frames/sec of the learner pipeline, and the fraction of
-                # wall time spent starved waiting for a batch: ~0 means the
-                # TPU is the bottleneck, ~1 means actors/H2D are.
-                logs["frames_per_sec"] = (
-                    self.num_frames - self._last_log_frames
-                ) / elapsed
-                logs["batch_wait_frac"] = min(
-                    self._wait_accum / elapsed, 1.0
-                )
-                self._m_steps_per_sec.set(
-                    (self.num_steps - self._last_log_steps) / elapsed
-                )
-            else:
-                # Keys must exist on the first write too (CSV columns are
-                # fixed by the first row).
-                logs["frames_per_sec"] = float("nan")
-                logs["batch_wait_frac"] = float("nan")
-            self._last_log_t = now
-            self._last_log_frames = self.num_frames
-            self._last_log_steps = self.num_steps
-            self._wait_accum = 0.0
-            # Materializing device scalars blocks on the step's outputs
-            # — the other measurable completion stall (see the
-            # perf/allreduce_* crediting above). Timed via the
-            # calibrated sync so pure conversion overhead doesn't read
-            # as a collective stall.
-            device_leaves = [
-                v for v in logs.values() if isinstance(v, jax.Array)
-            ]
-            if device_leaves and self._allreduce_est_ns:
-                self._timed_sync(device_leaves)  # lint: allow(jit-boundary/host-sync-in-hot-loop)
-            host_logs = {
-                k: float(v) if isinstance(v, (jax.Array, np.ndarray)) else v
-                for k, v in logs.items()
-            }
-            if self._logger is not None:
-                self._logger(host_logs)
-            if self._health is not None:
-                # The health plane rides the SAME materialized floats as
-                # the logger — zero additional device syncs (the ISSUE 19
-                # dispatch-count contract).
-                self._health.observe(host_logs, lineage=meta)
+        ) and crossed_interval(self.num_steps, K, self._config.log_interval)
+        queued = queueing = time.monotonic_ns()
+        snapshot = None
+        if publishes:
+            # Queued behind this step and before the next is ever
+            # dispatched, and its D2H requested at once (all leaves
+            # before any is materialised: np.asarray alone would
+            # serialise one synchronous transfer per leaf), so the bytes
+            # travel as soon as the device has them.
+            snapshot = _publish_snapshot(self._params)
+            leaves = jax.tree.leaves(snapshot)
+            for leaf in leaves:
+                leaf.copy_to_host_async()
+            probe = leaves[:1]
+            queued = time.monotonic_ns()
+            self._tracer.complete(
+                "learner/publish_copy", queueing, queued - queueing, step_tag
+            )
+        self._tracer.complete(
+            "learner/bookkeeping", dispatched, queueing - dispatched, step_tag
+        )
+        # A step that crossed no interval and holds no slot is not waited
+        # for. The one before this is settled now in any case.
+        owed = None
+        if publishes or logs_due or meta.ring_slot >= 0:
+            owed = _InFlight(
+                step=self.num_steps,
+                version=self.num_frames,
+                dispatch_t0_ns=step_t0_ns,
+                probe=probe,
+                snapshot=snapshot,
+                ring_slot=meta.ring_slot,
+                logs=dict(logs) if logs_due else None,
+                meta=meta,
+            )
+        resumed = self._settle(queued, owed, led_from)
         if self.post_step is not None:
             self.post_step(self.num_steps)
         self._period_stepped = True
         self._returned_ns = time.monotonic_ns()
         tail_ns = self._returned_ns - resumed
-        self._m_bookkeeping.observe((booked_ns + tail_ns) / 1e9)
+        self._observe_settled(
+            queued - queueing, queueing - dispatched + tail_ns
+        )
         self._tracer.complete(
             "learner/bookkeeping", resumed, tail_ns, step_tag
         )
         return logs
+
+    def _log_step(self, done: _InFlight) -> None:
+        """Hand a completed step's scalars to the logger and the health
+        monitor as host floats, with the rates since the last log."""
+        logs = done.logs
+        now = time.monotonic()
+        if self._last_log_t is not None:
+            elapsed = max(now - self._last_log_t, 1e-9)
+            # frames/sec of the learner pipeline, and the fraction of
+            # wall time spent starved waiting for a batch: ~0 means the
+            # TPU is the bottleneck, ~1 means actors/H2D are.
+            logs["frames_per_sec"] = (
+                done.version - self._last_log_frames
+            ) / elapsed
+            logs["batch_wait_frac"] = min(self._wait_accum / elapsed, 1.0)
+            self._m_steps_per_sec.set(
+                (done.step - self._last_log_steps) / elapsed
+            )
+        else:
+            # Keys must exist on the first write too (CSV columns are
+            # fixed by the first row).
+            logs["frames_per_sec"] = float("nan")
+            logs["batch_wait_frac"] = float("nan")
+        self._last_log_t = now
+        self._last_log_frames = done.version
+        self._last_log_steps = done.step
+        self._wait_accum = 0.0
+        # The step has completed (_settle waited for it, and that wait is
+        # the device's compute, not a stall): what materialising the
+        # scalars still waits for is debited against the collective's
+        # overlap credit, timed via the calibrated sync so that pure
+        # conversion overhead does not read as a stall.
+        device_leaves = [v for v in logs.values() if isinstance(v, jax.Array)]
+        if device_leaves and self._allreduce_est_ns:
+            self._timed_sync(device_leaves)  # lint: allow(jit-boundary/host-sync-in-hot-loop)
+        host_logs = {
+            k: float(v) if isinstance(v, (jax.Array, np.ndarray)) else v
+            for k, v in logs.items()
+        }
+        if self._logger is not None:
+            self._logger(host_logs)
+        if self._health is not None:
+            # The health plane rides the SAME materialized floats as
+            # the logger — zero additional device syncs (the ISSUE 19
+            # dispatch-count contract).
+            self._health.observe(host_logs, lineage=done.meta)
 
     def attach_health(self, monitor) -> None:
         """Attach a `telemetry.health.HealthMonitor` (ISSUE 19): its
@@ -2658,6 +2800,9 @@ class Learner:
                 except queue.Empty:
                     if watchdog is not None:
                         watchdog()
+            # The last step's version reaches the actors, and a failure
+            # of it raises here, before the loop reports a clean end.
+            self.drain()
         except BaseException as e:
             # Anomaly postmortem on the way down (ISSUE 19): bundle the
             # flight-recorder tail, health snapshots, and the last
@@ -2727,6 +2872,10 @@ class Learner:
             validate_restored_shapes,
         )
 
+        # The step in flight belongs to the state that is being replaced:
+        # settle it first, so that its version cannot land on the
+        # restored one.
+        self.drain()
         params = state["params"]
         # Fail actionably (naming the known r5 padding change) instead of
         # with a raw tree/shape mismatch deeper in device_put/XLA.
@@ -2791,7 +2940,7 @@ class Learner:
             from torched_impala_tpu.utils.checkpoint import unpack_rng
 
             self._rng = unpack_rng(state["rng"])
-        self._publish()
+        self._publish_now()
         if self.traj_ring is not None:
             # A restore landing on a live ring (survivor-driven restart
             # after a kill_host chaos fault) must not feed slots a dead
