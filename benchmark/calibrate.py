@@ -10,13 +10,12 @@ In one process, at the cell's own size, on the chip:
                  number's lower reading.
   control        the reference put in the program's place with each part of
                  the network, weights and activations, in the precision
-                 below the one the configuration states for it: the
-                 bfloat16 torso in 8-bit floats (e4m3), the float32 core
-                 and heads in bfloat16. (The program's own lower-precision
-                 path, `train_dtype`, does not trace with the fused LSTM:
-                 PERF.md.)
-  control_core   only the core and heads lowered (bfloat16), the torso in
-                 the reference's float32: the step that tempts most.
+                 below the one the configuration states for it (bfloat16 in
+                 8-bit floats, e4m3; float32 in bfloat16). (The program's
+                 own lower-precision path, `train_dtype`, does not trace
+                 with the fused LSTM: PERF.md.)
+  control_...    the other entries of the network file's `CONTROLS`: only
+                 some parts lowered, the rest in the reference's float32.
   half           the fault 'half of the batch left out', planted in the
                  reference put in the program's place: the reference on the
                  first half of each batch's rows against the reference on
@@ -48,7 +47,7 @@ def program_record(prep) -> dict:
     from benchmark import driver, program
 
     learner, _ = program.build_learner(
-        prep.config, prep.chips, prep.weights, prep.popart
+        prep.net, prep.config, prep.chips, prep.weights, prep.popart
     )
     learner.start()
     try:
@@ -89,18 +88,21 @@ def main(argv=None, probe=None, root: str = ROOT) -> int:
         gc.collect()
         batches = driver.check_batches(prep)
         ref = check.reference_record(prep, batches)
-        row["sound"] = check.compare(sound, ref, decay)
+        row["sound"] = check.compare(sound, ref, decay, prep.net.leaf_groups)
         row["losses"] = {"program": sound["losses"], "reference": ref["losses"]}
         row["grad_norm_unclipped"] = ref["grad_norm_unclipped"]
 
         def in_place(prep=prep, **planted) -> dict:
             rec = check.reference_record(prep, batches, **planted)
-            return check.compare(check.as_program_record(rec, decay), ref, decay)
+            return check.compare(
+                check.as_program_record(rec, decay), ref, decay,
+                prep.net.leaf_groups,
+            )
 
         if i < args.control_seeds:
-            for which in check.CONTROLS:
+            for which in prep.net.CONTROLS:
                 row[which] = in_place(
-                    dtypes=check.control_dtypes(prep.config, which)
+                    dtypes=check.control_dtypes(prep.net, prep.config, which)
                 )
         if i < args.fault_seeds:
             row["half"] = in_place(

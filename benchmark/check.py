@@ -160,16 +160,17 @@ def popart_gap(program: dict, reference: dict) -> float:
     return worst
 
 
-def compare(program: dict, reference: dict, rmsprop_decay: float) -> dict:
-    """The numbers, from two records in the program's leaf names.
+def compare(
+    program: dict, reference: dict, rmsprop_decay: float, leaf_groups
+) -> dict:
+    """The numbers, from two records in the program's leaf names;
+    `leaf_groups` is the network file's.
 
     program:   losses [3], params0, params1 and nu1 (parameters and second
                moments after step 1), params3, popart0 and popart1 (PopArt's
                statistics at the start and after step 1; None with one task).
     reference: the same with grads1 (clipped) for nu1, and loss_scales."""
     import jax
-
-    from benchmark.program import leaf_groups
 
     numbers, where = {}, {}
     for i, (lp, lr, scale) in enumerate(
@@ -249,20 +250,15 @@ PRECISION_BELOW = {
     "bfloat16": "float8_e4m3fn",
     "float16": "float8_e4m3fn",
 }
-# What a control stores (torso, core with the heads) one precision below
-# what the configuration states for it; the other part is the reference's
-# float32. `control` is the contract's: each part one below. `control_core`
-# is the step that tempts most (a bfloat16 LSTM kernel or train step beside
-# the torso as it is); no number separates it from a sound run, whose core
-# is already fed by a bfloat16 torso (PERF.md section 2).
-CONTROLS = {"control": (True, True), "control_core": (False, True)}
 
 
-def control_dtypes(config: dict, which: str) -> tuple:
-    stated = (config["model"]["torso_dtype"], config["model"]["train_dtype"])
+def control_dtypes(net, config: dict, which: str) -> tuple:
+    """What a control stores each part of the network in: one precision
+    below what the configuration states for it (`net.stated_dtypes`) where
+    `net.CONTROLS[which]` says so, the reference's float32 elsewhere."""
     return tuple(
         PRECISION_BELOW[d] if lower else "float32"
-        for d, lower in zip(stated, CONTROLS[which])
+        for d, lower in zip(net.stated_dtypes(config), net.CONTROLS[which])
     )
 
 
@@ -270,14 +266,17 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
     """Three reference steps from `prep`'s weights and PopArt statistics on
     the stacked host `batches`; the record in the program's leaf names.
     `rows` (a slice) plants the fault 'part of the batch left out' into the
-    reference; `dtypes` (see `control_dtypes`) makes it a control: what the
-    torso, and the core with the heads, are stored in."""
+    reference; `dtypes` (see `control_dtypes`) makes it a control: what
+    each part of the network is stored in."""
     import jax.numpy as jnp
 
     from benchmark import program, reference as ref
 
-    config = prep.config
-    dtypes = tuple(jnp.dtype(d) for d in dtypes or ("float32", "float32"))
+    config, net = prep.config, prep.net
+    to_program = net.to_program_params
+    if dtypes is None:
+        dtypes = ("float32",) * len(net.stated_dtypes(config))
+    dtypes = tuple(jnp.dtype(d) for d in dtypes)
     hp = ref.hyper_params(config)
     params = prep.weights
     nu, popart = ref.init_state(params, prep.popart)
@@ -285,9 +284,10 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
     record = {
         "losses": [],
         "loss_scales": [],
-        "params0": program.host(program.to_program_params(params)),
+        "params0": program.host(to_program(params)),
         "popart0": prep.popart,
     }
+    network = (net.forward, net.sizes(config))
     for k, b in enumerate(batches):
         batch = ref.Batch(
             b["obs"], b["first"], b["actions"], b["behaviour_logits"],
@@ -296,23 +296,19 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
         if rows is not None:
             batch = batch.rows(rows.start, rows.stop)
         out = ref.learner_step(
-            params, nu, popart, batch, k, hp, block, dtypes=dtypes
+            network, params, nu, popart, batch, k, hp, block, dtypes=dtypes
         )
         params, nu, popart = out.params, out.nu, out.popart
         record["losses"].append(out.loss)
         record["loss_scales"].append(out.loss_scale)
         if k == 0:
-            record["grads1"] = program.host(
-                program.to_program_params(out.grads)
-            )
+            record["grads1"] = program.host(to_program(out.grads))
             record["grad_norm_unclipped"] = out.grad_norm_unclipped
-            record["params1"] = program.host(
-                program.to_program_params(params)
-            )
+            record["params1"] = program.host(to_program(params))
             record["popart1"] = popart and {
                 k: np.asarray(v) for k, v in popart.items()
             }
-    record["params3"] = program.host(program.to_program_params(params))
+    record["params3"] = program.host(to_program(params))
     return record
 
 

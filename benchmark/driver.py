@@ -15,11 +15,12 @@ from __future__ import annotations
 
 import contextlib
 import gc
+import importlib.util
 import json
 import os
 import threading
 import time
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 from benchmark import program, reference, stats, traffic
 
@@ -27,17 +28,25 @@ WARMUP_STEPS = 8  # after the check's three: queues and both stack buffers full
 STEP_TIMEOUT_S = 120.0
 CHECK_STEPS = 3
 RESERVED_VS_TEMP = 0.02  # the reserved scratch is the step's temporaries to 2%
+DEFAULT_NETWORK = "impala_resnet_lstm"  # of a configuration that names none
+# What a network file holds (benchmark/README.md, "A network").
+NETWORK_API = (
+    "REQUIRED_MODEL_KEYS", "sizes", "init_params", "forward", "draw_state",
+    "to_program_params", "leaf_groups", "stated", "stated_dtypes", "CONTROLS",
+    "step_flops", "OPS_AND_BYTES",
+)
 
 
 class Spec:
     """`BENCHMARK.json` at `root` and the files it names. Whatever belongs
-    to one configuration, one traffic mix or one per-layer metric is a file
-    of its own, found by name under any of `paths`."""
+    to one configuration, one network, one traffic mix or one per-layer
+    metric is a file of its own, found by name under any of `paths`."""
 
     def __init__(self, root: str):
         self.root = root
         with open(os.path.join(root, "BENCHMARK.json")) as f:
             self.doc = json.load(f)
+        self._networks: dict = {}
 
     def cell(self, name: str) -> dict:
         for w in self.doc["workloads"]:
@@ -53,16 +62,47 @@ class Spec:
                     return json.load(f)
         raise KeyError(f"no configuration {name!r} in BENCHMARK.json")
 
-    def find(self, kind: str, name: str) -> dict:
-        """`<path>/<kind>/<name>.json` under the first of `paths` that has it."""
+    def _file(self, kind: str, name: str) -> str:
+        """`<path>/<kind>/<name>` under the first of `paths` that has it."""
         for path in self.doc["paths"]:
-            file = os.path.join(self.root, path, kind, name + ".json")
+            file = os.path.join(self.root, path, kind, name)
             if os.path.exists(file):
-                with open(file) as f:
-                    return json.load(f)
-        raise FileNotFoundError(
-            f"no {kind}/{name}.json under {self.doc['paths']}"
-        )
+                return file
+        raise FileNotFoundError(f"no {kind}/{name} under {self.doc['paths']}")
+
+    def find(self, kind: str, name: str) -> dict:
+        """The data file `<kind>/<name>.json`."""
+        with open(self._file(kind, name + ".json")) as f:
+            return json.load(f)
+
+    def network(self, config: dict):
+        """The module `networks/<name>.py` that the configuration names
+        under `network`, loaded by its path, once."""
+        name = config.get("network", DEFAULT_NETWORK)
+        if name not in self._networks:
+            try:
+                file = self._file("networks", name + ".py")
+            except FileNotFoundError as e:
+                raise program.ConfigMismatch(
+                    f"{config.get('name')}: network {name!r}: {e}"
+                ) from None
+            module_spec = importlib.util.spec_from_file_location(name, file)
+            module = importlib.util.module_from_spec(module_spec)
+            module_spec.loader.exec_module(module)
+            missing = [k for k in NETWORK_API if not hasattr(module, k)]
+            if missing:
+                raise program.ConfigMismatch(f"network {name!r} lacks {missing}")
+            self._networks[name] = module
+        module = self._networks[name]
+        lacking = [
+            k for k in module.REQUIRED_MODEL_KEYS if k not in config["model"]
+        ]
+        if lacking:
+            raise program.ConfigMismatch(
+                f"{config.get('name')}: network {name!r} needs {lacking} "
+                "in the configuration's `model` group"
+            )
+        return module
 
     def metrics(self, section: str, cell: str) -> list:
         """Entries of `end_to_end` or `per_layer` that this cell reports."""
@@ -75,6 +115,7 @@ class Spec:
 
 class Prepared(NamedTuple):
     config: dict
+    net: Any  # the configuration's network file, a module
     mix: dict
     chips: int
     weights: dict  # the reference's tree, on the device
@@ -86,18 +127,19 @@ class Prepared(NamedTuple):
 
 def prepare(spec: Spec, cell: dict, seed: int) -> Prepared:
     config = spec.config(cell["config"])
+    net = spec.network(config)
     mix = spec.find("traffic", cell["traffic"])
     seed = abs(int(seed))
-    pool = traffic.make_pool(seed, config, mix)
-    weights = reference.init_params(seed, reference.Shapes.from_config(config))
+    pool = traffic.make_pool(seed, config, mix, net.draw_state)
     return Prepared(
         config=config,
+        net=net,
         mix=mix,
         chips=int(cell["chips"]),
-        weights=weights,
+        weights=net.init_params(seed, config),
         popart=reference.init_popart(seed, config),
         pool=pool,
-        trajs=[program.trajectory(u) for u in pool],
+        trajs=program.trajectories(config, pool),
         orders=traffic.feeder_orders(seed, mix, len(pool)),
     )
 
@@ -117,7 +159,7 @@ def first_steps(learner, prep: Prepared) -> dict:
     b = int(prep.config["batch_size"])
     record = {
         "losses": [],
-        "params0": program.host(program.to_program_params(prep.weights)),
+        "params0": program.host(prep.net.to_program_params(prep.weights)),
         "popart0": prep.popart,
     }
     for s in range(CHECK_STEPS):
@@ -227,7 +269,7 @@ def measure(
 
     parts = {"inputs_made": time.monotonic() - t_start}
     learner, registry = program.build_learner(
-        prep.config, prep.chips, prep.weights, prep.popart
+        prep.net, prep.config, prep.chips, prep.weights, prep.popart
     )
     parts["learner_built"] = time.monotonic() - t_start
     learner.start()

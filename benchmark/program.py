@@ -22,9 +22,9 @@ class ConfigMismatch(ValueError):
 
 def experiment_config(config: dict):
     """The preset named by the configuration's file, with B and T from the
-    file. Every size and hyper-parameter the file states is checked against
-    what the program will run, so that the FLOP count and the reference are
-    of the real model."""
+    file. Every hyper-parameter the file states is checked against what the
+    program will run (the network's sizes: `_check_net`), so that the FLOP
+    count and the reference are of the real model."""
     from torched_impala_tpu import configs
 
     if config["preset"] not in configs.REGISTRY:
@@ -40,10 +40,7 @@ def experiment_config(config: dict):
         "obs_dtype": (m["obs_dtype"], exp.obs_dtype),
         "num_actions": (m["num_actions"], exp.num_actions),
         "num_tasks": (m["num_tasks"], exp.num_tasks),
-        "torso": (m["torso"], exp.model),
-        "torso_dtype": (m["torso_dtype"], exp.compute_dtype),
         "train_dtype": (m["train_dtype"], exp.train_dtype),
-        "use_lstm": (m["use_lstm"], exp.use_lstm),
         "discount": (loss["discount"], exp.discount),
         "vf_coef": (loss["vf_coef"], exp.vf_coef),
         "entropy_coef": (loss["entropy_coef"], exp.entropy_coef),
@@ -55,8 +52,6 @@ def experiment_config(config: dict):
         "max_grad_norm": (opt["max_grad_norm"], exp.max_grad_norm),
         "total_env_frames": (opt["total_env_frames"], exp.total_env_frames),
     }
-    if m["use_lstm"]:
-        stated["lstm_size"] = (m["lstm_size"], exp.lstm_size)
     if m["num_tasks"] > 1:
         stated["popart_step_size"] = (
             config["popart"]["step_size"],
@@ -70,19 +65,11 @@ def experiment_config(config: dict):
     return exp
 
 
-def _check_net(config: dict, agent, learner_config) -> None:
-    m, loss = config["model"], config["loss"]
-    torso, lc = agent.net.torso, learner_config.loss
+def _check_net(net, config: dict, exp, agent, learner_config) -> None:
+    """The network file's own pairs of (file, program), and the loss's."""
+    loss, lc = config["loss"], learner_config.loss
     stated = {
-        "channel_sections": (
-            tuple(m["channel_sections"]),
-            tuple(torso.channel_sections),
-        ),
-        "blocks_per_section": (
-            m["blocks_per_section"],
-            torso.blocks_per_section,
-        ),
-        "fc_size": (m["fc_size"], torso.hidden_size),
+        **net.stated(config, exp, agent.net),
         "clip_rho_threshold": (
             loss["clip_rho_threshold"],
             lc.clip_rho_threshold,
@@ -137,9 +124,34 @@ def make_registry():
     return SummingRegistry()
 
 
-def build_learner(config: dict, chips: int, weights: Any, popart=None):
-    """The preset's learner with the benchmark's own weights, and PopArt
-    statistics where the configuration has them, in it."""
+def _check_tree(net, config: dict, ours, theirs) -> None:
+    """The network file's tree in the program's leaf names has to be the
+    program's own, leaf for leaf and shape for shape."""
+    import jax
+
+    def shapes(tree):
+        return {
+            jax.tree_util.keystr(path): tuple(leaf.shape)
+            for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
+        }
+
+    ours, theirs = shapes(ours), shapes(theirs)
+    wrong = {
+        k: (ours.get(k), theirs.get(k))
+        for k in sorted(ours.keys() | theirs.keys())
+        if ours.get(k) != theirs.get(k)
+    }
+    if wrong:
+        raise ConfigMismatch(
+            f"{config['name']}: network {net.__name__!r} against the program's "
+            f"parameters, (file's, program's) shape by leaf: {wrong}"
+        )
+
+
+def build_learner(net, config: dict, chips: int, weights: Any, popart=None):
+    """The preset's learner with the benchmark's own weights (the tree of
+    the network file `net`), and PopArt statistics where the configuration
+    has them, in it."""
     import jax
 
     from torched_impala_tpu import configs
@@ -153,7 +165,7 @@ def build_learner(config: dict, chips: int, weights: Any, popart=None):
         mesh = make_mesh(num_data=chips)
     agent = configs.make_agent(exp, mesh=mesh)
     learner_config = configs.make_learner_config(exp)
-    _check_net(config, agent, learner_config)
+    _check_net(net, config, exp, agent, learner_config)
     registry = make_registry()
     learner = Learner(
         agent=agent,
@@ -169,7 +181,9 @@ def build_learner(config: dict, chips: int, weights: Any, popart=None):
     # hand it these very buffers.
     import jax.numpy as jnp
 
-    state["params"] = jax.tree.map(jnp.copy, to_program_params(weights))
+    params = net.to_program_params(weights)
+    _check_tree(net, config, params, state["params"])
+    state["params"] = jax.tree.map(jnp.copy, params)
     if (popart is None) != (learner_config.popart is None):
         raise ConfigMismatch(f"{config['name']}: PopArt in one of file, program")
     if popart is not None:
@@ -178,79 +192,30 @@ def build_learner(config: dict, chips: int, weights: Any, popart=None):
     return learner, registry
 
 
-def to_program_params(ref: dict) -> dict:
-    """The reference's parameter tree in the program's leaf names (flax
-    auto-names of `AtariDeepTorso`, `PallasLSTMCell`, the two heads)."""
-    torso: dict = {}
-    block = 0
-    for i, sec in enumerate(ref["sections"]):
-        torso[f"Conv_{i}"] = {
-            "kernel": sec["conv"]["w"],
-            "bias": sec["conv"]["b"],
-        }
-        for blk in sec["blocks"]:
-            torso[f"ResidualBlock_{block}"] = {
-                "Conv_0": {
-                    "kernel": blk["conv1"]["w"],
-                    "bias": blk["conv1"]["b"],
-                },
-                "Conv_1": {
-                    "kernel": blk["conv2"]["w"],
-                    "bias": blk["conv2"]["b"],
-                },
-            }
-            block += 1
-    torso["Dense_0"] = {"kernel": ref["fc"]["w"], "bias": ref["fc"]["b"]}
-    out = {
-        "torso": torso,
-        "policy_head": {
-            "kernel": ref["policy"]["w"],
-            "bias": ref["policy"]["b"],
-        },
-        "value_head": {
-            "kernel": ref["value"]["w"],
-            "bias": ref["value"]["b"],
-        },
-    }
-    if "lstm" in ref:
-        hid = ref["lstm"]["wh"].shape[0]
-        lstm = {}
-        for j, gate in enumerate("ifgo"):
-            cols = slice(j * hid, (j + 1) * hid)
-            lstm[f"i{gate}"] = {"kernel": ref["lstm"]["wi"][:, cols]}
-            lstm[f"h{gate}"] = {
-                "kernel": ref["lstm"]["wh"][:, cols],
-                "bias": ref["lstm"]["b"][cols],
-            }
-        out["lstm"] = lstm
-    return {"params": out}
+def trajectories(config: dict, pool: list) -> list:
+    """The generated unrolls as the program's `Trajectory`s, each one's
+    state (a flat tuple of arrays) in the type the program's network
+    carries."""
+    import jax
 
-
-def leaf_groups(leaf_name: str) -> tuple:
-    """The parts of the model a parameter leaf belongs to. As far as the
-    configuration states precisions apart: the `torso` (its `torso_dtype`)
-    or the `core` (recurrent core and heads, float32); within the core, the
-    `lstm` (its gradient comes back through the whole unroll) or the
-    `heads` (theirs does not)."""
-    if "['torso']" in leaf_name:
-        return ("torso",)
-    return ("core", "lstm" if "['lstm']" in leaf_name else "heads")
-
-
-def trajectory(unroll: dict):
-    """One generated unroll as the program's `Trajectory`."""
+    from torched_impala_tpu import configs
     from torched_impala_tpu.runtime.types import Trajectory
 
-    return Trajectory(
-        obs=unroll["obs"],
-        first=unroll["first"],
-        actions=unroll["actions"],
-        behaviour_logits=unroll["behaviour_logits"],
-        rewards=unroll["rewards"],
-        cont=unroll["cont"],
-        agent_state=unroll["state"],
-        task=int(unroll["task"]),
-    )
+    agent = configs.make_agent(experiment_config(config))
+    carried = jax.tree.structure(agent.initial_state(1))
+    return [
+        Trajectory(
+            obs=u["obs"],
+            first=u["first"],
+            actions=u["actions"],
+            behaviour_logits=u["behaviour_logits"],
+            rewards=u["rewards"],
+            cont=u["cont"],
+            agent_state=jax.tree.unflatten(carried, u["state"]),
+            task=int(u["task"]),
+        )
+        for u in pool
+    ]
 
 
 def queue_closed_error():

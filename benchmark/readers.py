@@ -9,15 +9,14 @@ a share of a roofline or of a peak that it could not read.
 
 The context (`Context`) holds what one traced run produced: the reduced
 trace, the timers' totals over the traced window, the steps completed in
-it, the configuration and the peaks of the device.
+it, the configuration with its network file, and the peaks of the device.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Optional
+from typing import Any, NamedTuple, Optional
 
 from benchmark import flops, trace as tr
-from benchmark.reference import Shapes
 
 
 class Context(NamedTuple):
@@ -29,6 +28,7 @@ class Context(NamedTuple):
     chips: int
     peaks: dict  # {"bf16_flops_per_s", "hbm_bytes_per_s", ...}
     window: dict = {}  # stats.window_metrics of the traced window
+    net: Any = None  # the configuration's network file: its counts
 
     @property
     def device_window(self):
@@ -82,17 +82,14 @@ def device_time(ctx: Context, patterns: list):
 
 def roofline(ctx: Context, patterns: list, within: list, ops_and_bytes: str):
     """A kernel's share of its roofline, in %: the least time the chip could
-    take (the larger of FLOPs over the peak and bytes over the peak
-    bandwidth, from `flops.OPS_AND_BYTES[ops_and_bytes]`) over the device
-    time measured. What is timed: the events matching `patterns` or, with
+    take (`flops.least_seconds` of the FLOPs and bytes that the network
+    file's `OPS_AND_BYTES[ops_and_bytes]` counts) over the device time
+    measured. What is timed: the events matching `patterns` or, with
     `within`, the shortest event matching `within` around each of them (the
     loop that a kernel's calls make up), each counted as one call of the
     function."""
-    n_flops, n_bytes = flops.OPS_AND_BYTES[ops_and_bytes](ctx.config, ctx.chips)
-    least = max(
-        n_flops / ctx.peaks["bf16_flops_per_s"],
-        n_bytes / ctx.peaks["hbm_bytes_per_s"],
-    )
+    n_flops, n_bytes = ctx.net.OPS_AND_BYTES[ops_and_bytes](ctx.config, ctx.chips)
+    least = flops.least_seconds(n_flops, n_bytes, ctx.peaks)
     calls, seconds = 0, 0.0
     for events in _per_device(ctx, "ops"):
         hits = tr.matching(events, patterns)
@@ -141,16 +138,11 @@ def idle_share(ctx: Context):
 
 
 def mfu(ctx: Context):
-    """Model FLOPs of the steps completed in the traced window, over the
-    window and the chips' bf16 peak, in %."""
+    """Model FLOPs (the network file's `step_flops`) of the steps completed
+    in the traced window, over the window and the chips' bf16 peak, in %."""
     if ctx.steps < 1 or ctx.host_window_s <= 0.0:
         return None
-    per_step = flops.step_flops(
-        Shapes.from_config(ctx.config),
-        ctx.config["unroll_length"],
-        ctx.config["batch_size"],
-    )
-    rate = per_step * ctx.steps / ctx.host_window_s
+    rate = ctx.net.step_flops(ctx.config) * ctx.steps / ctx.host_window_s
     return 100.0 * rate / (ctx.chips * ctx.peaks["bf16_flops_per_s"])
 
 

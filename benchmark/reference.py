@@ -1,25 +1,26 @@
-"""Plain reference of one IMPALA learner step, kept with the benchmark.
+"""Plain reference of one IMPALA learner step, kept with the benchmark:
+the part that every configuration shares.
 
 Straightforward `jax.numpy` in float32 under
-`jax.default_matmul_precision("highest")`: the IMPALA deep ResNet torso
-(arXiv:1802.01561 fig. 3, large architecture), an LSTM core with episode
-resets inside the unroll, policy and value heads, V-trace targets, the
-IMPALA loss, PopArt (arXiv:1809.04474) where the configuration has more
-than one task, the gradient by `jax.grad`, global-norm clipping and
-RMSProp under a linear learning-rate anneal.
+`jax.default_matmul_precision("highest")`: V-trace targets, the IMPALA
+loss, PopArt (arXiv:1809.04474) where the configuration has more than one
+task, the gradient by `jax.grad`, global-norm clipping and RMSProp under a
+linear learning-rate anneal. The network itself (its weights from the seed
+and its forward pass over an unroll) is the configuration's network file
+under `networks/`, handed in as `net`; its tree carries the value head
+under `value` (`w` `[F, K]`, `b` `[K]`), which PopArt rescales.
 
 It imports nothing of the program and takes nothing the program made: the
-weights come from `init_params(seed, ...)` below and are handed TO the
-program (`benchmark/program.py`). Every hyper-parameter comes from the
-configuration's own file under `benchmark/configs/`.
+weights come from the network file's `init_params(seed, config)` and are
+handed TO the program (`benchmark/program.py`). Every hyper-parameter comes
+from the configuration's own file under `configs/`.
 
 The batch is processed in blocks of rows so that a full-size step fits a
 chip beside nothing else: the loss sums over rows, so gradients add; PopArt
 needs the whole batch's value-target moments first, hence its forward-only
 first pass.
 
-Departures from the publications, stated: no language-instruction LSTM in
-the DMLab-30 model (the program has none); RMSProp epsilon 1e-7 inside the
+Departure from the publications, stated: RMSProp epsilon 1e-7 inside the
 square root (the program's optax settings), where the paper uses 0.1.
 """
 
@@ -35,86 +36,16 @@ import numpy as np
 F32 = jnp.float32
 
 
-class Shapes(NamedTuple):
-    """The sizes of one configuration, read from its file."""
-
-    obs_shape: tuple
-    num_actions: int
-    num_values: int
-    channel_sections: tuple
-    blocks_per_section: int
-    fc_size: int
-    lstm_size: int  # 0: no recurrent core
-
-    @classmethod
-    def from_config(cls, config: dict) -> "Shapes":
-        m = config["model"]
-        return cls(
-            obs_shape=tuple(m["obs_shape"]),
-            num_actions=int(m["num_actions"]),
-            num_values=int(m["num_tasks"]),
-            channel_sections=tuple(m["channel_sections"]),
-            blocks_per_section=int(m["blocks_per_section"]),
-            fc_size=int(m["fc_size"]),
-            lstm_size=int(m["lstm_size"]) if m["use_lstm"] else 0,
-        )
-
-
-def pooled(n: int) -> int:
-    """Output extent of the 3x3 / stride-2 SAME max-pool."""
-    return -(-n // 2)
-
-
-def flat_features(s: Shapes) -> int:
-    h, w, _ = s.obs_shape
-    for _ in s.channel_sections:
-        h, w = pooled(h), pooled(w)
-    return h * w * s.channel_sections[-1]
-
-
-# ---- weights from the seed ---------------------------------------------
-
-
-def _param_shapes(s: Shapes) -> dict:
-    def conv(cin, cout):
-        return {"w": (3, 3, cin, cout), "b": (cout,)}
-
-    sections, cin = [], s.obs_shape[-1]
-    for ch in s.channel_sections:
-        sections.append(
-            {
-                "conv": conv(cin, ch),
-                "blocks": [
-                    {"conv1": conv(ch, ch), "conv2": conv(ch, ch)}
-                    for _ in range(s.blocks_per_section)
-                ],
-            }
-        )
-        cin = ch
-    core = s.lstm_size or s.fc_size
-    tree = {
-        "sections": sections,
-        "fc": {"w": (flat_features(s), s.fc_size), "b": (s.fc_size,)},
-        "policy": {"w": (core, s.num_actions), "b": (s.num_actions,)},
-        "value": {"w": (core, s.num_values), "b": (s.num_values,)},
-    }
-    if s.lstm_size:
-        h = s.lstm_size
-        tree["lstm"] = {
-            "wi": (s.fc_size, 4 * h),
-            "wh": (h, 4 * h),
-            "b": (4 * h,),
-        }
-    return tree
+# ---- what every network's file uses -------------------------------------
 
 
 def _is_shape(x) -> bool:
     return isinstance(x, tuple) and all(isinstance(d, int) for d in x)
 
 
-@functools.partial(jax.jit, static_argnums=(1,))
-def _init(key, s: Shapes):
-    shapes = _param_shapes(s)
+def draw_leaves(key, shapes):
+    """A tree of weights for a tree of shapes (traced inside the network
+    file's one jitted call)."""
     leaves, treedef = jax.tree.flatten(shapes, is_leaf=_is_shape)
     keys = jax.random.split(key, len(leaves))
 
@@ -132,28 +63,10 @@ def _init(key, s: Shapes):
     )
 
 
-def init_params(seed: int, s: Shapes) -> dict:
-    """The reference's weights, made on the default device in one jitted
-    call. `seed` may exceed 32 bits: it is folded into two words."""
-    key = jax.random.fold_in(
+def seed_key(seed: int):
+    """`seed` may exceed 32 bits: it is folded into two words."""
+    return jax.random.fold_in(
         jax.random.key(seed & 0x7FFFFFFF), (seed >> 31) & 0x7FFFFFFF
-    )
-    return _init(key, s)
-
-
-# ---- forward pass -------------------------------------------------------
-
-
-def _conv(x, p):
-    y = jax.lax.conv_general_dilated(
-        x, p["w"], (1, 1), "SAME", dimension_numbers=("NHWC", "HWIO", "NHWC")
-    )
-    return y + p["b"]
-
-
-def _max_pool(x):
-    return jax.lax.reduce_window(
-        x, -jnp.inf, jax.lax.max, (1, 3, 3, 1), (1, 2, 2, 1), "SAME"
     )
 
 
@@ -166,68 +79,6 @@ def rounded(x, dtype):
         top = float(jnp.finfo(dtype).max)  # saturate: e4m3 has no infinity
         return jnp.clip(x, -top, top).astype(dtype).astype(jnp.bfloat16)
     return x.astype(dtype)
-
-
-def torso(params, obs, dtype=F32):
-    """`[N, H, W, C]` pixels (uint8 scaled by 1/255) -> `[N, fc]`; weights
-    and every layer's output stored in `dtype` (float32: the reference)."""
-    q = functools.partial(rounded, dtype=dtype)
-    params = jax.tree.map(q, params)
-    x = obs.astype(F32)
-    if obs.dtype == jnp.uint8:
-        x = x / 255.0
-    x = q(x)
-    for sec in params["sections"]:
-        x = q(_max_pool(_conv(x, sec["conv"])))
-        for blk in sec["blocks"]:
-            y = q(_conv(jax.nn.relu(x), blk["conv1"]))
-            y = q(_conv(jax.nn.relu(y), blk["conv2"]))
-            x = q(x + y)
-    x = jax.nn.relu(x).reshape(x.shape[0], -1)
-    return q(jax.nn.relu(x @ params["fc"]["w"] + params["fc"]["b"]))
-
-
-def lstm_unroll(p, feats, first, c0, h0):
-    """LSTM over `[T, B, F]` with the carry zeroed where `first` is set,
-    BEFORE the cell sees that step. Gates along 4H are (i, f, g, o)."""
-    hid = c0.shape[-1]
-
-    def step(carry, xs):
-        c, h = carry
-        x, fst = xs
-        keep = 1.0 - fst.astype(c.dtype)[:, None]
-        c, h = c * keep, h * keep
-        gates = (h @ p["wh"] + p["b"]) + x @ p["wi"]
-        i = jax.nn.sigmoid(gates[:, :hid])
-        f = jax.nn.sigmoid(gates[:, hid : 2 * hid])
-        g = jnp.tanh(gates[:, 2 * hid : 3 * hid])
-        o = jax.nn.sigmoid(gates[:, 3 * hid :])
-        c = f * c + i * g
-        h = o * jnp.tanh(c)
-        return (c, h), h
-
-    _, out = jax.lax.scan(step, (c0, h0), (feats, first))
-    return out
-
-
-def forward(params, obs, first, state, dtypes=(F32, F32)):
-    """Unroll over `[T+1, B, ...]`: (policy logits `[T+1, B, A]`, values
-    `[T+1, B, K]`). `state` is `(c, h)` at obs[0], or `()` with no core.
-    `dtypes`: what the torso, and the core with the heads, are stored in."""
-    t, b = obs.shape[:2]
-    torso_params = {k: params[k] for k in ("sections", "fc")}
-    feats = torso(torso_params, obs.reshape(t * b, *obs.shape[2:]), dtypes[0])
-    feats = feats.reshape(t, b, -1).astype(dtypes[1])
-    params = jax.tree.map(
-        lambda a: a.astype(dtypes[1]),
-        {k: v for k, v in params.items() if k not in torso_params},
-    )
-    if "lstm" in params:
-        c0, h0 = (s.astype(feats.dtype) for s in state)
-        feats = lstm_unroll(params["lstm"], feats, first, c0, h0)
-    logits = feats @ params["policy"]["w"] + params["policy"]["b"]
-    values = feats @ params["value"]["w"] + params["value"]["b"]
-    return logits.astype(F32), values.astype(F32)
 
 
 # ---- V-trace, loss, PopArt ---------------------------------------------
@@ -278,7 +129,7 @@ class Batch(NamedTuple):
     rewards: Any  # [T, B]
     cont: Any  # [T, B]
     tasks: Any  # [B] int32
-    state: Any  # (c [B, H], h [B, H]) or ()
+    state: Any  # the network's recurrent state: a tuple of `[B, ...]` arrays
 
     def rows(self, lo: int, hi: int) -> "Batch":
         return Batch(
@@ -288,13 +139,15 @@ class Batch(NamedTuple):
         )
 
 
-def _targets(params, pa_old, batch: Batch, hp, popart, dtypes=(F32, F32)):
+def _targets(net, params, pa_old, batch: Batch, hp, popart, dtypes):
     """Forward pass, then V-trace on constants: (logits[:-1], values that
-    carry gradient `[T+1, B]`, vs, advantages). `dtypes` other than
-    float32 make the control: weights and activations of the network
-    rounded to them, the loss, V-trace and PopArt still in float32."""
+    carry gradient `[T+1, B]`, vs, advantages). `net` is the network file's
+    `(forward, sizes)`. `dtypes` other than float32 make the control:
+    weights and activations of the network rounded to them, the loss,
+    V-trace and PopArt still in float32."""
+    forward, sizes = net
     logits, values = forward(
-        params, batch.obs, batch.first, batch.state, dtypes
+        sizes, params, batch.obs, batch.first, batch.state, dtypes
     )
     if popart:
         values = jnp.take_along_axis(
@@ -321,9 +174,11 @@ def _targets(params, pa_old, batch: Batch, hp, popart, dtypes=(F32, F32)):
     return logits[:-1], values, vs, adv
 
 
-def _block_loss(params, pa_old, pa_new, batch: Batch, hp, popart, dtypes):
+def _block_loss(params, net, pa_old, pa_new, batch: Batch, hp, popart, dtypes):
     """Summed loss of a block of rows (total, parts)."""
-    logits, values, vs, adv = _targets(params, pa_old, batch, hp, popart, dtypes)
+    logits, values, vs, adv = _targets(
+        net, params, pa_old, batch, hp, popart, dtypes
+    )
     vs, adv = jax.lax.stop_gradient((vs, adv))
     if popart:
         # Baseline on normalised values and targets, both under the
@@ -346,19 +201,19 @@ def _block_loss(params, pa_old, pa_new, batch: Batch, hp, popart, dtypes):
     return total, {"pg": pg, "baseline": bl, "neg_entropy": ent}
 
 
-@functools.partial(jax.jit, static_argnames=("popart", "dtypes"))
-def _block_grad(params, pa_old, pa_new, batch, hp, popart, dtypes):
+@functools.partial(jax.jit, static_argnames=("net", "popart", "dtypes"))
+def _block_grad(params, net, pa_old, pa_new, batch, hp, popart, dtypes):
     (total, parts), grads = jax.value_and_grad(_block_loss, has_aux=True)(
-        params, pa_old, pa_new, batch, hp, popart, dtypes
+        params, net, pa_old, pa_new, batch, hp, popart, dtypes
     )
     return total, parts, grads
 
 
-@functools.partial(jax.jit, static_argnames=("num_values", "dtypes"))
-def _block_moments(params, pa_old, batch, hp, num_values, dtypes):
+@functools.partial(jax.jit, static_argnames=("net", "num_values", "dtypes"))
+def _block_moments(params, net, pa_old, batch, hp, num_values, dtypes):
     """Per-task count, sum and sum of squares of a block's V-trace
     targets (PopArt's first pass; additive over blocks)."""
-    _, _, vs, _ = _targets(params, pa_old, batch, hp, True, dtypes)
+    _, _, vs, _ = _targets(net, params, pa_old, batch, hp, True, dtypes)
     zero = jnp.zeros((num_values,), F32)
     cnt = zero.at[batch.tasks].add(jnp.full(batch.tasks.shape, vs.shape[0], F32))
     tot = zero.at[batch.tasks].add(jnp.sum(vs, 0))
@@ -420,6 +275,7 @@ class StepOut(NamedTuple):
 
 
 def learner_step(
+    net,
     params,
     nu,
     popart: Optional[dict],
@@ -427,9 +283,10 @@ def learner_step(
     k: int,
     hp: dict,
     block_rows: int,
-    dtypes=(F32, F32),
+    dtypes: tuple,
 ) -> StepOut:
-    """One reference step on host arrays `batch`, in blocks of rows."""
+    """One reference step on host arrays `batch`, in blocks of rows, of the
+    network `net`: its file's `(forward, sizes(config))`, both hashable."""
     n = batch.tasks.shape[0]
     if n % block_rows:
         raise ValueError(f"{n} rows do not divide into blocks of {block_rows}")
@@ -444,7 +301,7 @@ def learner_step(
             mom = None
             for blk in blocks:
                 m = _block_moments(
-                    params, popart, blk, hp,
+                    params, net, popart, blk, hp,
                     num_values=popart["mu"].shape[0], dtypes=dtypes,
                 )
                 mom = m if mom is None else jax.tree.map(jnp.add, mom, m)
@@ -452,7 +309,8 @@ def learner_step(
         loss, parts, grads = 0.0, None, None
         for blk in blocks:
             total, prt, g = _block_grad(
-                params, popart, pa_new, blk, hp, popart=use_pa, dtypes=dtypes
+                params, net, popart, pa_new, blk, hp,
+                popart=use_pa, dtypes=dtypes,
             )
             loss = loss + total
             parts = prt if parts is None else jax.tree.map(jnp.add, parts, prt)

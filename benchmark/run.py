@@ -111,6 +111,7 @@ def main(argv=None, probe=probe_devices, root: str = ROOT) -> int:
             chips=prep.chips,
             peaks=peaks[device["kind"]],
             window=got.window,
+            net=prep.net,
         )
         shutil.rmtree(trace_dir, ignore_errors=True)
         for m in spec.metrics("per_layer", cell["name"]):
@@ -143,7 +144,8 @@ def main(argv=None, probe=probe_devices, root: str = ROOT) -> int:
     t_ref = time.monotonic()
     ref_record = check.reference_record(prep, driver.check_batches(prep))
     compared = check.compare(
-        got.program_record, ref_record, prep.config["optimizer"]["rmsprop_decay"]
+        got.program_record, ref_record,
+        prep.config["optimizer"]["rmsprop_decay"], prep.net.leaf_groups,
     )
     result["reference_s"] = time.monotonic() - t_ref
     result["all_numbers"] = compared["numbers"]

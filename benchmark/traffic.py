@@ -7,14 +7,18 @@ benchmark's `paths`. Its parameters:
            as the learner's bounded queue takes the last: a closed loop at
            saturation, the only kind of load the learner's feed has.
   p_first  per-step probability that an observation starts an episode (the
-           LSTM carry is reset there; the step before it ends one).
-  tasks    "single" (task 0) or "uniform" over the configuration's tasks.
+           recurrent state is reset there; the step before it ends one).
+  tasks    "single" (task 0), "uniform" over the configuration's tasks, or
+           "zipf": task ids with probability proportional to 1/rank, the
+           tasks' ranks a permutation fixed by the seed.
 
 The same in every mix: a pool of `POOL_BATCHES` batches of distinct unrolls
 (the output check's three steps see 3*B rows that all differ, and no batch
 repeats the one before it), behaviour logits N(0,1) (so V-trace's clipping
-is active), rewards N(0,1), and each unroll's initial LSTM state drawn with
-spread `STATE_STD`.
+is active), rewards N(0,1), and each unroll's recurrent state at its first
+observation as the configuration's network file draws it (`draw_state`, the
+generator's last draw): a flat tuple of arrays, each with a leading axis of
+one row, which B unrolls concatenate on, as the program's own stacking does.
 
 Every seed gives the same sizes and the same amount of work; only the
 values and the order differ.
@@ -25,23 +29,24 @@ from __future__ import annotations
 import numpy as np
 
 REQUIRED = ("feeders", "p_first", "tasks")
+TASKS = ("single", "uniform", "zipf")
 POOL_BATCHES = 3
-STATE_STD = 0.5
 
 
 def validate(mix: dict) -> None:
     missing = [k for k in REQUIRED if k not in mix]
     if missing:
         raise ValueError(f"traffic mix lacks {missing}")
-    if mix["tasks"] not in ("single", "uniform"):
-        raise ValueError(f"tasks must be single or uniform: {mix['tasks']!r}")
+    if mix["tasks"] not in TASKS:
+        raise ValueError(f"tasks must be one of {TASKS}: {mix['tasks']!r}")
     if mix["feeders"] < 1 or not 0.0 <= mix["p_first"] <= 1.0:
         raise ValueError("feeders >= 1 and p_first a probability")
 
 
-def make_pool(seed: int, config: dict, mix: dict) -> list:
+def make_pool(seed: int, config: dict, mix: dict, draw_state) -> list:
     """`POOL_BATCHES * B` unrolls as dicts of numpy arrays, time-major,
-    T+1 observations each. Bulk draws, sliced into views per unroll."""
+    T+1 observations each. Bulk draws, sliced into views per unroll.
+    `draw_state(rng, n, config)` is the network file's."""
     validate(mix)
     m = config["model"]
     t, b = int(config["unroll_length"]), int(config["batch_size"])
@@ -57,16 +62,15 @@ def make_pool(seed: int, config: dict, mix: dict) -> list:
     rewards = rng.standard_normal((n, t), dtype=np.float32)
     # An episode that starts at t+1 ended at t.
     cont = 1.0 - first[:, 1:].astype(np.float32)
+    k = int(m["num_tasks"])
     if mix["tasks"] == "uniform":
-        tasks = rng.integers(0, int(m["num_tasks"]), size=n, dtype=np.int32)
+        tasks = rng.integers(0, k, size=n, dtype=np.int32)
+    elif mix["tasks"] == "zipf":
+        weight = 1.0 / (1.0 + rng.permutation(k))  # 1/rank of each task
+        tasks = rng.choice(k, size=n, p=weight / weight.sum()).astype(np.int32)
     else:
         tasks = np.zeros(n, np.int32)
-    if m["use_lstm"]:
-        h = int(m["lstm_size"])
-        c0 = rng.standard_normal((n, 1, h), dtype=np.float32) * STATE_STD
-        h0 = np.tanh(
-            rng.standard_normal((n, 1, h), dtype=np.float32) * STATE_STD
-        )
+    state = draw_state(rng, n, config)
     pool = []
     for i in range(n):
         pool.append(
@@ -78,7 +82,7 @@ def make_pool(seed: int, config: dict, mix: dict) -> list:
                 "rewards": rewards[i],
                 "cont": cont[i],
                 "task": tasks[i],
-                "state": (c0[i], h0[i]) if m["use_lstm"] else (),
+                "state": tuple(s[i] for s in state),
             }
         )
     return pool

@@ -12,12 +12,35 @@ from contextlib import redirect_stderr, redirect_stdout
 import pytest
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-DATA_DIRS = ("configs", "traffic", "metrics", "limits")
+DATA_DIRS = ("configs", "networks", "traffic", "metrics", "limits")
 
 
 def fake_probe(chips):
     """Says what a v5e host would; nothing is measured under that name."""
     return {"platform": "tpu", "kind": "TPU v5 lite", "count": chips}
+
+
+@pytest.fixture
+def network_of():
+    """`network_of(config)`: the network file of the repo's own benchmark
+    that `config` names, as the harness loads it."""
+    from benchmark import driver
+
+    return driver.Spec(ROOT).network
+
+
+@pytest.fixture
+def tiny_config():
+    """`tiny_config(**model)`: a configuration of `impala_resnet_lstm` at
+    sizes a test states."""
+    base = {
+        "obs_shape": [8, 8, 1], "num_actions": 2, "num_tasks": 1,
+        "torso": "deep_resnet", "torso_dtype": "bfloat16",
+        "train_dtype": "float32", "channel_sections": [4],
+        "blocks_per_section": 1, "fc_size": 8, "use_lstm": True,
+        "lstm_size": 8,
+    }
+    return lambda **model: {"name": "tiny", "model": dict(base, **model)}
 
 
 class TinyCheckout:

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import flops, readers, reference, stats, trace
+from benchmark import flops, readers, stats, trace
 from benchmark.trace import Event
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -75,6 +75,8 @@ def test_step_window_and_idle_gaps():
 
 
 def _context(ops, modules, **kw):
+    from benchmark import driver
+
     cfg = json.load(open(os.path.join(ROOT, "benchmark/configs/breakout_deep_lstm.json")))
     base = dict(
         trace=trace.Trace({"/device:TPU:0": ops}, {"/device:TPU:0": modules}),
@@ -84,6 +86,7 @@ def _context(ops, modules, **kw):
         config=cfg,
         chips=1,
         peaks={"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+        net=driver.Spec(ROOT).network(cfg),
     )
     base.update(kw)
     return readers.Context(**base)
@@ -118,8 +121,9 @@ def test_readers_on_a_hand_made_trace():
     assert readers.device_time(ctx, ["train_step"]) == pytest.approx(800.0)
     assert readers.device_time(ctx, ["nothing"]) is None
     assert readers.exposed_time(ctx, ["all-reduce"]) is None
-    n_flops, n_bytes = flops.lstm_unroll_forward(ctx.config, 1)
+    n_flops, n_bytes = ctx.net.lstm_unroll_forward(ctx.config, 1)
     least = max(n_flops / 197e12, n_bytes / 819e9)
+    assert flops.least_seconds(n_flops, n_bytes, ctx.peaks) == least
     # four unrolls, each timed by its own (the shorter) loop: 0.15 s
     assert readers.roofline(ctx, **LSTM_METRIC) == pytest.approx(100 * least / 0.15)
     # without `within` the kernel's own events are timed, each one a call
@@ -129,7 +133,7 @@ def test_readers_on_a_hand_made_trace():
     empty = _context([], [])
     assert readers.idle_share(empty) is None
     assert readers.roofline(empty, **LSTM_METRIC) is None
-    per_step = flops.step_flops(reference.Shapes.from_config(ctx.config), 20, 256)
+    per_step = ctx.net.step_flops(ctx.config)
     assert readers.mfu(ctx) == pytest.approx(100 * per_step * 3 / 3.0 / 197e12)
     shipped = json.load(open(os.path.join(ROOT, "benchmark/metrics/kernels.lstm_roofline.json")))
     assert shipped["params"] == LSTM_METRIC
@@ -174,62 +178,66 @@ def test_load_reads_a_recorded_capture(tmp_path):
 # ---- operations and bytes ----------------------------------------------
 
 
-def test_lstm_unroll_ops_and_bytes_by_hand():
+def test_lstm_unroll_ops_and_bytes_by_hand(network_of):
     cfg = json.load(open(os.path.join(ROOT, "benchmark/configs/breakout_deep_lstm.json")))
     # 21 steps of [256,512] x [512,1024]
-    n_flops, n_bytes = flops.lstm_unroll_forward(cfg, 1)
+    count = network_of(cfg).OPS_AND_BYTES["lstm_unroll_forward"]
+    n_flops, n_bytes = count(cfg, 1)
     assert n_flops == 21 * 2 * 256 * 512 * 1024 == 5_637_144_576
     words = 513 * 1024 + 21 * 256 * 512
     assert n_bytes == 4 * words == 13_111_296
     # compute-bound on a v5e: 28.6 us of FLOPs against 16.0 us of bytes
     assert n_flops / 197e12 > n_bytes / 819e9
     # four chips: a quarter of the rows each, the weights whole
-    quarter = flops.lstm_unroll_forward(cfg, 4)
+    quarter = count(cfg, 4)
     assert quarter[0] == n_flops / 4
     assert quarter[1] == 4 * (513 * 1024 + 21 * 64 * 512)
 
 
-def test_forward_macs_by_hand_for_breakout():
+def test_forward_macs_by_hand_for_breakout(network_of):
     cfg = json.load(open(os.path.join(ROOT, "benchmark/configs/breakout_deep_lstm.json")))
-    macs = flops.forward_macs_per_obs(reference.Shapes.from_config(cfg))
+    net = network_of(cfg)
+    macs = net.forward_macs_per_obs(net.sizes(cfg))
     # taps on the zero padding are not counted: (3h-2)(3w-2) of 9hw
     assert macs["section0.conv"] == 250 * 250 * 4 * 16
     assert macs["section0.blocks"] == 4 * 124 * 124 * 16 * 16
     assert macs["section2.blocks"] == 4 * 31 * 31 * 32 * 32
-    assert flops.taps_3x3_same(1, 1) == 1 and flops.taps_3x3_same(2, 2) == 16
+    assert net.taps_3x3_same(1, 1) == 1 and net.taps_3x3_same(2, 2) == 16
     assert macs["fc"] == 11 * 11 * 32 * 256 == 3872 * 256
     assert macs["lstm"] == 512 * 1024
     assert macs["heads"] == 256 * 5
 
 
-TINY = reference.Shapes(
-    obs_shape=(16, 20, 3), num_actions=5, num_values=3,
-    channel_sections=(8, 16), blocks_per_section=1, fc_size=32, lstm_size=0,
-)
-
-
-def test_flop_counter_against_xla_on_a_scan_free_model():
+def test_flop_counter_against_xla_on_a_scan_free_model(network_of, tiny_config):
     """XLA's `cost_analysis` counts a scan's body once, so the check is on
     the part without one: torso and heads, forward and backward. It also
     counts the elementwise work, hence the 10% of room above."""
-    params = reference.init_params(7, TINY)
+    TINY = tiny_config(
+        obs_shape=[16, 20, 3], num_actions=5, num_tasks=3,
+        channel_sections=[8, 16], blocks_per_section=1, fc_size=32,
+        use_lstm=False, lstm_size=0,
+    )
+    net = network_of(TINY)
+    sizes = net.sizes(TINY)
+    params = net.init_params(7, TINY)
     n = 6
     obs = jax.random.randint(
-        jax.random.key(1), (1, n, *TINY.obs_shape), 0, 256
+        jax.random.key(1), (1, n, *sizes.obs_shape), 0, 256
     ).astype(jnp.uint8)
 
     def loss(p, x):
-        logits, values = reference.forward(p, x, None, ())
+        logits, values = net.forward(sizes, p, x, None, ())
         return jnp.sum(jnp.square(logits)) + jnp.sum(jnp.square(values))
 
     cost = jax.jit(jax.grad(loss)).lower(params, obs).compile().cost_analysis()
     cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     # one observation per unroll, all of them trained on: T+1 = T = 1 here
-    macs = flops.forward_macs_per_obs(TINY)
+    macs = net.forward_macs_per_obs(sizes)
     fwd = 2.0 * sum(macs.values())
     counted = n * (fwd + 2.0 * fwd - 2.0 * macs["section0.conv"])
     assert counted <= cost["flops"] <= 1.10 * counted
-    assert flops.step_flops(TINY, 1, n) == pytest.approx(n * (2 * fwd + 2 * fwd - 2 * macs["section0.conv"]))
+    step = dict(TINY, unroll_length=1, batch_size=n)
+    assert net.step_flops(step) == pytest.approx(n * (2 * fwd + 2 * fwd - 2 * macs["section0.conv"]))
 
 
 # ---- the window's rate and tail ----------------------------------------

@@ -145,11 +145,14 @@ def test_the_control_is_not_correct(checkout, cell_name):
     decay = prep.config["optimizer"]["rmsprop_decay"]
 
     def verdict_of(record):
-        numbers = check.compare(check.as_program_record(record, decay), want, decay)
+        numbers = check.compare(
+            check.as_program_record(record, decay), want, decay,
+            prep.net.leaf_groups,
+        )
         return check.verdict(numbers["numbers"], limits)
 
     for which, comes_out in (("control", False), ("control_core", True)):
-        dtypes = check.control_dtypes(prep.config, which)
+        dtypes = check.control_dtypes(prep.net, prep.config, which)
         correct, table = verdict_of(
             check.reference_record(prep, batches, dtypes=dtypes)
         )

@@ -17,6 +17,10 @@ def _records(checkout, cell_name, unroll, seed, monkeypatch):
     for w in checkout.doc["workloads"]:
         rel = f"benchmark/traffic/{w['traffic']}.json"
         checkout.write(rel, dict(checkout.read(rel), p_first=0.3))
+    for c in checkout.doc["configs"]:  # the file says what the program runs
+        cfg = checkout.read(c["file"])
+        cfg["model"]["torso_dtype"] = "float32"
+        checkout.write(c["file"], cfg)
     as_stated = program.experiment_config
     monkeypatch.setattr(
         program,
@@ -27,7 +31,7 @@ def _records(checkout, cell_name, unroll, seed, monkeypatch):
     prep = driver.prepare(spec, spec.cell(cell_name), seed)
     assert any(u["first"][1:].any() for u in prep.pool[:12]), "no reset inside"
     learner, _ = program.build_learner(
-        prep.config, prep.chips, prep.weights, prep.popart
+        prep.net, prep.config, prep.chips, prep.weights, prep.popart
     )
     learner.start()
     try:
@@ -45,7 +49,7 @@ def _records(checkout, cell_name, unroll, seed, monkeypatch):
 )
 def test_reference_agrees_with_the_program(checkout, cell_name, unroll, monkeypatch):
     prep, got, want = _records(checkout, cell_name, unroll, 11, monkeypatch)
-    numbers = check.compare(got, want, 0.99)["numbers"]
+    numbers = check.compare(got, want, 0.99, prep.net.leaf_groups)["numbers"]
     # float32 both sides: rounding, and the odd ReLU or max-pool tie
     assert numbers["loss_gap_step1"] < 2e-5
     assert numbers["grad_norm_gap"] < 5e-3
@@ -94,15 +98,15 @@ def test_vtrace_against_a_loop_written_by_hand():
     )
 
 
-def test_lstm_reset_zeroes_the_carry_before_the_step():
-    s = reference.Shapes((8, 8, 1), 2, 1, (4,), 1, 8, 8)
-    p = reference.init_params(3, s)["lstm"]
+def test_lstm_reset_zeroes_the_carry_before_the_step(network_of, tiny_config):
+    net = network_of(tiny_config())
+    p = net.init_params(3, tiny_config())["lstm"]
     feats = np.ones((3, 2, 8), np.float32)
     c0 = h0 = np.full((2, 8), 0.7, np.float32)
     first = np.array([[False, True], [False, False], [True, False]])
-    out = np.asarray(reference.lstm_unroll(p, feats, first, c0, h0))
+    out = np.asarray(net.lstm_unroll(p, feats, first, c0, h0))
     fresh = np.asarray(
-        reference.lstm_unroll(p, feats[:1], first[:1] | True, c0, h0)
+        net.lstm_unroll(p, feats[:1], first[:1] | True, c0, h0)
     )
     # row 1 starts an episode at t=0: its carry-in does not matter
     np.testing.assert_allclose(out[0, 1], fresh[0, 1], rtol=1e-6)
@@ -111,13 +115,14 @@ def test_lstm_reset_zeroes_the_carry_before_the_step():
     np.testing.assert_allclose(out[2, 0], fresh[0, 0], rtol=1e-6)
 
 
-def test_weights_are_a_function_of_the_seed_alone():
-    s = reference.Shapes((8, 8, 1), 2, 1, (4,), 1, 8, 8)
-    a, b = reference.init_params(2**31 + 5, s), reference.init_params(2**31 + 5, s)
-    c = reference.init_params(5, s)
+def test_weights_are_a_function_of_the_seed_alone(network_of, tiny_config):
+    cfg = tiny_config()
+    net = network_of(cfg)
+    a, b = net.init_params(2**31 + 5, cfg), net.init_params(2**31 + 5, cfg)
+    c = net.init_params(5, cfg)
     assert np.array_equal(a["fc"]["w"], b["fc"]["w"])
     assert not np.array_equal(a["fc"]["w"], c["fc"]["w"])
-    tree = program.to_program_params(a)["params"]
+    tree = net.to_program_params(a)["params"]
     assert sorted(tree) == ["lstm", "policy_head", "torso", "value_head"]
     np.testing.assert_array_equal(
         tree["lstm"]["hg"]["kernel"], np.asarray(a["lstm"]["wh"])[:, 16:24]
@@ -129,9 +134,10 @@ def test_the_pool_is_the_same_work_for_every_seed(checkout):
     cell = spec.cell("dmlab30_t100_b64_feed_sat")
     config = dict(spec.config(cell["config"]), batch_size=2, unroll_length=3)
     mix = spec.find("traffic", cell["traffic"])
-    a = traffic.make_pool(2**31 + 7, config, mix)
-    b = traffic.make_pool(2**31 + 7, config, mix)
-    c = traffic.make_pool(8, config, mix)
+    draw = spec.network(config).draw_state
+    a = traffic.make_pool(2**31 + 7, config, mix, draw)
+    b = traffic.make_pool(2**31 + 7, config, mix, draw)
+    c = traffic.make_pool(8, config, mix, draw)
     assert len(a) == len(c) == traffic.POOL_BATCHES * 2
     assert np.array_equal(a[0]["obs"], b[0]["obs"])
     assert not np.array_equal(a[0]["obs"], c[0]["obs"])
@@ -143,5 +149,5 @@ def test_the_pool_is_the_same_work_for_every_seed(checkout):
     orders = traffic.feeder_orders(9, mix, len(a))
     assert len(orders) == mix["feeders"]
     assert all(sorted(o) == list(range(len(a))) for o in orders)
-    with pytest.raises(ValueError):
-        traffic.make_pool(1, config, dict(mix, tasks="zipf"))
+    with pytest.raises(ValueError, match="tasks must be one of"):
+        traffic.make_pool(1, config, dict(mix, tasks="by_turns"), draw)

@@ -192,6 +192,9 @@ def test_every_entry_of_benchmark_json_has_its_file(checkout):
     for cell in spec.doc["workloads"]:
         config = spec.config(cell["config"])
         assert config["chips"] == cell["chips"]
+        assert spec.network(config).__name__ == config.get(
+            "network", driver.DEFAULT_NETWORK
+        )
         spec.find("traffic", cell["traffic"])
         assert spec.find("limits", cell["name"])
         for m in spec.metrics("per_layer", cell["name"]):
