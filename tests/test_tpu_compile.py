@@ -15,7 +15,9 @@ A compile that passes is not a chip run. `chip_smoke.py` is the chip run.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +25,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from torched_impala_tpu.models.torsos import AtariDeepTorso
 from torched_impala_tpu.ops import lstm_pallas, vtrace_pallas
 from torched_impala_tpu.ops.attention_pallas import windowed_attention
 from torched_impala_tpu.ops.conv_pallas import fused_residual_block
@@ -172,6 +175,67 @@ def test_conv_block(one_chip):
         kernel, bias, kernel, bias,
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize(
+    "n,obs",
+    [(21 * 256, (84, 84, 4)), (101 * 64, (72, 96, 3))],
+    ids=["breakout_cell", "dmlab_cell"],
+)
+def test_deep_torso_pool_gradient(one_chip, n, obs):
+    """The bf16 deep torso's gradient at the benchmark cells' (T+1)*B
+    images (a compile takes as long at 128, and there both versions fit
+    without temporaries worth comparing): each pool is two
+    Mosaic calls (forward with the winner index, backward from it), no
+    `select-and-scatter` is left, the `[H, W, C, N]` views around the
+    kernels are bitcasts — no `copy` or `transpose` of anything the size
+    of an activation anywhere in the module, but for the uint8
+    observations' own re-layout (the learner's AUTO input layouts are
+    what removes that one) — and the step needs fewer
+    temporaries than with XLA's pool compiled beside it (the convolution
+    outputs are no longer kept for the backward)."""
+
+    def compiled(pool_kernel):
+        torso = AtariDeepTorso(dtype=jnp.bfloat16, pool_kernel=pool_kernel)
+        params = jax.eval_shape(
+            torso.init, jax.random.key(0), jnp.zeros((1, *obs), jnp.uint8)
+        )
+
+        def loss(p, x):
+            return jnp.sum(torso.apply(p, x).astype(F32))
+
+        return (
+            jax.jit(jax.grad(loss))
+            .lower(
+                jax.tree.map(
+                    lambda a: _shape(one_chip, a.shape, a.dtype), params
+                ),
+                _shape(one_chip, (n, *obs), jnp.uint8),
+            )
+            .compile()
+        )
+
+    kernel, xla = compiled(True), compiled(False)
+    text = kernel.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 6
+    assert "max_pool_forward" in text and "max_pool_backward" in text
+    assert "select-and-scatter(" not in text
+    assert xla.as_text().count("select-and-scatter(") == 3
+    # The smallest activation a pool touches: the last section's output.
+    smallest = n * 32 * math.prod(-(-s // 8) for s in obs[:2])
+    moved = [
+        line.strip()[:120]
+        for line in text.splitlines()
+        for m in [re.search(r"= \w+\[([\d,]+)\]\S* (copy|transpose)\(", line)]
+        if m
+        and (dims := tuple(map(int, m.group(1).split(",")))) != (n, *obs)
+        and math.prod(dims) >= smallest
+    ]
+    assert not moved, moved
+    assert (
+        kernel.memory_analysis().temp_size_in_bytes
+        < xla.memory_analysis().temp_size_in_bytes
+    )
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

@@ -10,6 +10,14 @@ TPU notes: convs/matmuls run on the MXU; `dtype` selects the compute dtype
 (bfloat16 halves HBM traffic and doubles MXU throughput) while parameters
 stay float32. Pixel observations arrive uint8 `[..., H, W, C]` and are
 scaled inside the torso so the host→device transfer stays 1 byte/pixel.
+
+Three TPU-shaped rewrites live here, none of which changes a parameter
+tree: the first pixel convolution's kernel-side 1/255 fold and its
+space-to-depth form (`_FirstPixelConv`), and the deep torso's max-pool,
+whose backward routes gradients from a one-byte winner index saved by
+the forward instead of XLA's `select-and-scatter` over the kept
+convolution output (`ops/maxpool_pallas.py`; not differentiated it is
+`nn.max_pool` as before).
 """
 
 from __future__ import annotations
@@ -19,6 +27,8 @@ from typing import Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+
+from torched_impala_tpu.ops.maxpool_pallas import max_pool
 
 
 class _FirstPixelConv(nn.Module):
@@ -281,6 +291,10 @@ class AtariDeepTorso(nn.Module):
     # the unfused path — opt-in because the win is TPU memory-bandwidth
     # bound and CPU interpret mode is strictly slower.
     fused_blocks: bool = False
+    # The pool's own backward (ops/maxpool_pallas.py). Not a choice of
+    # the user's: `resolve_kernels` clears it for a learner on a mesh of
+    # several TPU devices, where a Mosaic kernel cannot be partitioned.
+    pool_kernel: bool = True
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -295,9 +309,7 @@ class AtariDeepTorso(nn.Module):
                 x = nn.Conv(
                     channels, (3, 3), dtype=self.dtype, name=f"Conv_{i}"
                 )(x)
-            x = nn.max_pool(
-                x, window_shape=(3, 3), strides=(2, 2), padding="SAME"
-            )
+            x = max_pool(x, kernel=self.pool_kernel)
             for _ in range(self.blocks_per_section):
                 x = ResidualBlock(
                     channels, dtype=self.dtype, fused=self.fused_blocks

@@ -302,15 +302,16 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
 
     Returns `(agent, loss, resolved)`: `loss.vtrace_implementation` is
     never 'auto' afterwards, and `resolved` names what was chosen
-    (`vtrace`, `lstm`, `fused_conv`, `attention`, `devices`, `why`).
+    (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`, `devices`,
+    `why`).
 
     On one device the choice is per platform: the Pallas kernels on a
     TPU, the scan elsewhere (the model's own kernels pick compiled vs
     interpreted at lowering time, ops/pallas_util.py). On a mesh of more
     than one TPU device every kernel resolves to its XLA implementation
     — the scan V-trace, the flax LSTM cell, unfused residual blocks,
-    einsum attention — because Mosaic kernels cannot be auto-partitioned
-    and nothing here wraps them per shard yet. That is decided HERE, by
+    XLA's max-pool, einsum attention — because Mosaic kernels cannot be
+    auto-partitioned and nothing here wraps them per shard yet. That is decided HERE, by
     construction, and logged once; it is never recovered from a failed
     lowering. The param tree is identical across implementations
     (tests/test_pallas_lstm.py), so actors may keep the fused net."""
@@ -334,6 +335,8 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
         torso = net.torso
         if getattr(torso, "fused_blocks", False):
             torso = torso.clone(fused_blocks=False)
+        if getattr(torso, "pool_kernel", False):
+            torso = torso.clone(pool_kernel=False)
         net = net.clone(
             torso=torso,
             lstm_impl="flax",
@@ -349,6 +352,9 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
         "vtrace": impl,
         "lstm": net.lstm_impl if kind == "lstm" else None,
         "fused_conv": bool(getattr(net.torso, "fused_blocks", False)),
+        "max_pool": {True: "kernel", False: "xla"}.get(
+            getattr(net.torso, "pool_kernel", None)
+        ),
         "attention": (
             dict(net.transformer).get("dense_kernel")
             if kind == "transformer"
@@ -362,10 +368,10 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
 
         logging.getLogger(__name__).warning(
             "learner kernels -> XLA (%s): vtrace=%s lstm=%s "
-            "fused_conv=%s attention=%s",
+            "fused_conv=%s max_pool=%s attention=%s",
             why,
             *(resolved[k] for k in (
-                "vtrace", "lstm", "fused_conv", "attention"
+                "vtrace", "lstm", "fused_conv", "max_pool", "attention"
             )),
         )
     return (
