@@ -576,7 +576,6 @@ def phase_train(seed: int) -> dict:
             auto_requested
             and learner._auto_jit is None
             and learner._mesh is None
-            and cfg.data_device is None
         ),
     }
     kids = seen["children"]

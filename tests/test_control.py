@@ -5,8 +5,9 @@ decision accounting + flight-recorder audit trail, the standard
 train/serving knob sets, and the --control CLI roundtrip.
 
 Everything here drives ``ControlLoop.tick(now=...)`` with a synthetic
-clock — no threads, no sleeps — matching the doctor self-check's
-deterministic style.
+clock — no control thread, no sleeps — matching the doctor self-check's
+deterministic style; the standing-scenarios test at the end puts a real
+env pool and a threaded server under the same clock.
 """
 
 import pytest
@@ -706,6 +707,118 @@ class TestBuildServingControl:
         wait0 = server.max_wait_s
         assert loop.tick(now=0.0) >= 1
         assert server.max_wait_s < wait0
+
+
+# ---- the two standing scenarios, end to end ---------------------------
+
+
+def _scripted_env(seed, env_index=None):
+    from torched_impala_tpu.envs.fake import ScriptedEnv
+
+    return ScriptedEnv(episode_len=5)
+
+
+class TestStandingScenarios:
+    def test_stragglers_shrink_waves_and_bursts_shrink_the_window(self):
+        """Scenario 1: a 4x2 async env pool with ready_fraction="auto"
+        is shown one stall of 25 ms in every ten worker steps (scripted:
+        the submit stamp is backdated, nothing sleeps); its knob moves
+        off the 0.5 default and ends under it. Scenario 2: bursts of 4
+        clients against a server whose coalescing window is 10 ms and
+        whose wave holds 16, so every burst waits the whole window; the
+        serving controller, ticked on a synthetic clock between bursts
+        against a 2 ms SLO on the request wait, takes decisions and ends
+        with a window under the configured one."""
+        import time
+
+        import jax
+        import numpy as np
+
+        from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
+        from torched_impala_tpu.runtime.env_pool import ProcessEnvPool
+        from torched_impala_tpu.runtime.param_store import ParamStore
+        from torched_impala_tpu.serving import (
+            InProcessClient,
+            PolicyServer,
+            VersionRegistry,
+        )
+
+        reg = Registry()
+        pool = ProcessEnvPool(
+            env_factory=_scripted_env,
+            num_workers=4,
+            envs_per_worker=2,
+            obs_shape=(4,),
+            obs_dtype=np.float32,
+            mode="async",
+            ready_fraction="auto",
+            telemetry=reg,
+        )
+        try:
+            assert pool.ready_fraction == 0.5
+            for i in range(32 + 320):
+                stall = i >= 32 and i % 10 == 0
+                w = i % 4
+                pool._submit_t[w] = time.monotonic() - (
+                    0.025 if stall else 1e-3
+                )
+                pool._observe_step(w)
+            assert pool.AUTO_FRACTION_MIN <= pool.ready_fraction < 0.5
+            assert reg.gauge(
+                "control/knob_pool_ready_fraction"
+            ).value == pytest.approx(pool.ready_fraction)
+        finally:
+            pool.close()
+
+        burst, cap, wait0_s, slo_ms, rounds = 4, 16, 0.010, 2.0, 12
+        obs_dim = 8
+        agent = Agent(
+            ImpalaNet(num_actions=4, torso=MLPTorso(hidden_sizes=(64,)))
+        )
+        params = agent.init_params(
+            jax.random.key(0), np.zeros((obs_dim,), np.float32)
+        )
+        sreg = Registry()
+        store = ParamStore()
+        store.publish(0, params)
+        server = PolicyServer(
+            agent=agent,
+            registry=VersionRegistry.serving_latest(store, telemetry=sreg),
+            example_obs=np.zeros((obs_dim,), np.float32),
+            max_clients=cap,
+            max_batch=cap,
+            max_wait_s=wait0_s,
+            telemetry=sreg,
+        ).start()
+        loop = build_serving_control(
+            server=server,
+            slo_ms=slo_ms,
+            telemetry=sreg,
+            tracer=FlightRecorder(capacity=256),
+        )
+        try:
+            clients = [
+                InProcessClient(server, greedy=True) for _ in range(burst)
+            ]
+            obs = np.random.default_rng(0).normal(
+                size=(burst, obs_dim)
+            ).astype(np.float32)
+            for r in range(rounds + 1):
+                cells = [
+                    c.act_async(obs[i], r == 0)
+                    for i, c in enumerate(clients)
+                ]
+                for cell in cells:
+                    cell.result(timeout=120.0)
+                # The clock strides past the policy's cooldown, so every
+                # burst's evidence can move the knobs.
+                loop.tick(now=10.0 * (r + 1))
+            for c in clients:
+                c.close()
+        finally:
+            server.close()
+        assert sreg.counter("control/decision_total").value > 0
+        assert server.max_wait_s < wait0_s
 
 
 # ---- CLI / config roundtrip ------------------------------------------

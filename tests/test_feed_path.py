@@ -30,9 +30,13 @@ def _agent(num_values=1):
     )
 
 
-def _run_ring(donate, K=1, n=4, T=3, B=4, E=2, mesh=None, **cfg_kwargs):
+def _run_ring(
+    donate, K=1, n=4, T=3, B=4, E=2, mesh=None, inspect=None, **cfg_kwargs
+):
     """Train `n` learner steps through the trajectory ring and return
-    (final params, telemetry registry, per-step losses)."""
+    (final params, telemetry registry, per-step losses). `inspect`, when
+    given, is shown the first placed batch (the 8-tuple of device
+    arrays) before the step takes it."""
     reg = Registry()
     num_values = (
         cfg_kwargs["popart"].num_values
@@ -70,9 +74,13 @@ def _run_ring(donate, K=1, n=4, T=3, B=4, E=2, mesh=None, **cfg_kwargs):
     learner.start()
     losses = []
     try:
-        for _ in range(n):
+        for i in range(n):
             for _ in range(K * B // E):
                 actor.unroll_and_push()
+            if inspect is not None and i == 0:
+                item = learner._batch_q.get(timeout=60)
+                inspect(item[0])
+                learner._batch_q.put(item)
             logs = learner.step_once(timeout=60)
             assert np.isfinite(logs["total_loss"])
             losses.append(float(logs["total_loss"]))
@@ -97,13 +105,20 @@ class TestDonatedRing:
         assert reg_don.counter("learner/ring_stage_bytes").value == 0
         assert reg_don.counter("learner/donated_batches").value == 4
 
-    def test_superbatch_donated_parity(self):
-        """K=2 superbatch slots feed the fused dispatch directly;
-        donation must not change the training trajectory."""
-        p_copy, _, _ = _run_ring(donate=False, K=2, n=3)
-        p_don, reg, _ = _run_ring(donate=True, K=2, n=3)
+    @pytest.mark.parametrize("K", [2, 9])
+    def test_superbatch_donated_parity(self, K):
+        """K superbatch slots feed the fused dispatch directly (K=9 is
+        one past the K=8 ceiling the fused dispatch once had); donation
+        must not change the training trajectory, stages nothing where
+        the copy path stages every superbatch, and every batch fed is
+        counted as donated with its H2D time credited."""
+        p_copy, reg_copy, _ = _run_ring(donate=False, K=K, n=3)
+        p_don, reg, _ = _run_ring(donate=True, K=K, n=3)
         jax.tree.map(np.testing.assert_array_equal, p_copy, p_don)
+        assert reg_copy.counter("learner/ring_stage_bytes").value > 0
         assert reg.counter("learner/ring_stage_bytes").value == 0
+        assert reg.counter("learner/donated_batches").value == 3
+        assert reg.counter("perf/h2d_ns_total").value > 0
 
     def test_h2d_overlap_telemetry_populated(self):
         _, reg, _ = _run_ring(donate=True)
@@ -161,6 +176,40 @@ class TestDonatedRing:
         assert len(losses) == 3
         assert reg.counter("learner/ring_stage_bytes").value == 0
         assert reg.counter("learner/donated_batches").value == 3
+
+    def test_mesh_ring_places_every_shard_on_its_own_device(self):
+        """All 8 virtual devices as one data mesh, donated ring, B=8:
+        nothing is staged host-side, every batch fed is donated, and the
+        batch the step is handed has one row on each device."""
+        from torched_impala_tpu.parallel import make_mesh
+
+        devices = jax.devices("cpu")
+        assert len(devices) == 8  # tests/conftest.py
+        T, B = 3, 8
+
+        def one_row_per_device(arrays):
+            obs = arrays[0]  # [T+1, B, ...]
+            assert obs.shape == (T + 1, B, 4)
+            shards = obs.addressable_shards
+            assert {s.device for s in shards} == set(devices)
+            assert all(s.data.shape == (T + 1, 1, 4) for s in shards)
+            assert sorted(s.index[1].start for s in shards) == list(
+                range(B)
+            )
+
+        _, reg, losses = _run_ring(
+            donate=True,
+            n=3,
+            T=T,
+            B=B,
+            E=4,
+            mesh=make_mesh(num_data=8, devices=devices),
+            inspect=one_row_per_device,
+        )
+        assert len(losses) == 3
+        assert reg.counter("learner/ring_stage_bytes").value == 0
+        assert reg.counter("learner/donated_batches").value == 3
+        assert reg.counter("perf/h2d_ns_total").value > 0
 
     def test_mesh_donation_reuses_slot_backing_stores(self):
         """Donation aliasing under pjit: a sharded batch assembled by

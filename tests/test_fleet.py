@@ -614,6 +614,65 @@ class TestLoadgen:
             fleet.close()
 
 
+    @pytest.mark.parametrize("replicas", [1, 2])
+    def test_killed_replica_under_load_single_fails_fleet_absorbs(
+        self, agent, params, replicas
+    ):
+        """The same open-loop stream (16 clients, int8 behind the parity
+        gate) with one server killed mid-wave at its 10th wave: a single
+        server has nowhere to fail over and its later requests fail; a
+        2-replica fleet marks exactly one replica dead, answers the
+        orphaned requests by the one retry and fails none."""
+        obs_pool = obs_batch(64, seed=3)
+        ok, mismatches = greedy_action_parity(
+            agent, params, obs_pool[:16], dtype="int8"
+        )
+        assert ok and mismatches == 0
+        clients = 16
+        fleet, _ = make_fleet(
+            agent,
+            params,
+            replicas=replicas,
+            start=True,
+            dtype="int8",
+            max_clients=clients + 2,
+            max_batch=8,
+            max_wait_s=1e-3,
+        )
+        injector = ChaosInjector(
+            ChaosPlan([Fault(kind="kill_server_mid_wave", at=10)]),
+            telemetry=Registry(),
+        )
+        injector.install(fleets=[fleet])
+        try:
+            report = run_load(
+                fleet=fleet,
+                shape=TrafficShape(
+                    kind="poisson", rate_rps=300.0, duration_s=1.0
+                ),
+                slo_ms=50.0,
+                example_obs=np.zeros((OBS_DIM,), np.float32),
+                obs_pool=obs_pool,
+                clients=clients,
+                seed=3,
+            )
+            states = sorted(fleet.states().values())
+        finally:
+            fleet.close()
+        assert len(injector.fired) == 1
+        assert report.offered == (
+            report.ok + report.expired + report.disconnected + report.failed
+        )
+        if replicas == 1:
+            assert states == [DEAD]
+            assert report.failed > 0
+        else:
+            assert states == [ACTIVE, DEAD]
+            assert report.failed == 0
+            assert report.retried >= 1
+            assert report.ok == report.offered
+
+
 # ---- ParamStore publish listeners (the rollout feed) -------------------
 
 

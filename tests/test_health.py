@@ -494,3 +494,82 @@ class TestShadowMismatchRate:
             s.name: s for s in health_slo_specs()
         }["shadow_mismatch"]
         assert spec.key == "serving/shadow_mismatch_rate"
+
+
+def test_one_learner_step_emits_health_series_and_same_parameters():
+    """A learner on fake Pong frames (shallow torso, T=5, B=8) with
+    `health_diagnostics` on and a monitor attached: one settled step
+    leaves at least 10 `health/*` series in the registry, all from the
+    step's own logs, and the parameters after that step are bit for bit
+    those of the same step with the diagnostics off, which emits no
+    `health_*` log at all."""
+    import optax
+
+    from torched_impala_tpu import configs
+    from torched_impala_tpu.models import Agent, AtariShallowTorso, ImpalaNet
+    from torched_impala_tpu.runtime import Learner, LearnerConfig, VectorActor
+
+    T, B, E = 5, 8, 4
+    cfg = configs.ExperimentConfig(
+        name="health_step",
+        env_family="atari",
+        env_id="PongNoFrameskip-v4",
+        obs_shape=(84, 84, 4),
+        obs_dtype="uint8",
+        num_actions=6,
+    )
+    factory = configs.make_env_factory(cfg, fake=True)
+    agent = Agent(ImpalaNet(num_actions=6, torso=AtariShallowTorso()))
+
+    def one_step(diagnostics):
+        reg = Registry()
+        learner = Learner(
+            agent=agent,
+            optimizer=optax.rmsprop(6e-4, decay=0.99, eps=1e-7),
+            config=LearnerConfig(
+                batch_size=B,
+                unroll_length=T,
+                log_interval=1,
+                loss=ImpalaLossConfig(health_diagnostics=diagnostics),
+            ),
+            example_obs=configs.example_obs(cfg),
+            rng=jax.random.key(0),
+            telemetry=reg,
+        )
+        learner.attach_health(HealthMonitor(registry=reg))
+        actor = VectorActor(
+            actor_id=0,
+            envs=[factory(1000 + j, j) for j in range(E)],
+            agent=agent,
+            param_store=learner.param_store,
+            enqueue=learner.enqueue,
+            unroll_length=T,
+            seed=7,
+            telemetry=reg,
+        )
+        learner.start()
+        try:
+            for _ in range(B // E):
+                actor.unroll_and_push()
+            logs = learner.step_once(timeout=120)
+            learner.drain()
+        finally:
+            learner.stop()
+        params = jax.tree.map(lambda x: np.array(x, copy=True), learner.params)
+        series = sorted(
+            k for k in reg.snapshot() if k.startswith("telemetry/health/")
+        )
+        return params, logs, series
+
+    p_on, logs_on, series_on = one_step(True)
+    p_off, logs_off, series_off = one_step(False)
+    in_step = sorted(k for k in logs_on if k.startswith("health_"))
+    assert len(in_step) >= 10, in_step
+    assert len(series_on) >= 10, series_on
+    assert {
+        "telemetry/health/" + k[len("health_"):] for k in in_step
+    } <= set(series_on)
+    assert not any(k.startswith("health_") for k in logs_off)
+    # Host-derived only (the grad spike ratio), nothing from the step.
+    assert len(series_off) < len(series_on)
+    jax.tree.map(np.testing.assert_array_equal, p_on, p_off)

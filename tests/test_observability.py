@@ -358,6 +358,61 @@ class TestMetricsExporter:
         finally:
             exp.stop()
 
+    def test_worker_snapshot_crosses_lane_and_scrape_whole(self):
+        """A worker-sized payload (64 series, heartbeats, a 256-record
+        trace tail) is read back from the lane byte for byte; laid under
+        four workers' labels beside the 64 local series, one HTTP scrape
+        renders every one of the 128 metrics exactly once."""
+        local = {
+            f"telemetry/script/series_{i:02d}": float(i) for i in range(64)
+        }
+        rec = FlightRecorder(capacity=512)
+        t_ns = time.monotonic_ns()
+        for i in range(256):
+            rec.complete("pool/worker_step", t_ns + i, 1000, {"lid": "a0u0"})
+        payload = {
+            "label": "proc0w0",
+            "snapshot": dict(local),
+            "heartbeats": {"worker": 12.5},
+            "trace": rec.tail(256),
+            "thread_names": {},
+        }
+        sent = json.dumps(payload).encode()
+        assert len(sent) > 1_000
+        lane = SnapshotLane(1)
+        try:
+            w = SnapshotWriter(lane.descriptor(), 0)
+            try:
+                assert w.publish(payload)
+                got = lane.read(0)
+            finally:
+                w.close()
+        finally:
+            lane.close()
+        assert got.pop("pid") == os.getpid()  # the header's stamp
+        assert json.dumps(got).encode() == sent
+
+        snap = dict(local)
+        for wk in range(4):
+            for i in range(16):
+                snap[f"telemetry/proc0w{wk}/pool/series_{i:02d}"] = float(i)
+        exp = MetricsExporter(
+            lambda: dict(snap), port=0, registry=Registry()
+        ).start()
+        try:
+            url = f"http://127.0.0.1:{exp.port}/metrics"
+            with urllib.request.urlopen(url, timeout=10) as resp:
+                body = resp.read().decode()
+        finally:
+            exp.stop()
+        samples = [
+            line.split()[0]
+            for line in body.splitlines()
+            if line and not line.startswith("#")
+        ]
+        assert sorted(samples) == sorted(metric_name(k) for k in snap)
+        assert len(set(samples)) == len(samples) == 128
+
     def test_file_fallback_ticks_engine_and_publishes(self, tmp_path):
         """--metrics-file mode: the background tick advances the alert
         engine on a steady cadence AND atomically rewrites the file —

@@ -80,10 +80,10 @@ def test_pallas_compiled_on_tpu_matches_scan():
     """Compiled (Mosaic, interpret=False) kernel parity on a real chip.
 
     The test-suite conftest forces the CPU backend, so under `pytest tests/`
-    this always skips; it runs when invoked with a TPU backend — e.g. by
-    `python bench.py` via run_vtrace_kernel_compare, or
+    this always skips; it runs when invoked with a TPU backend — e.g.
     `python -m pytest tests/test_pallas_vtrace.py -k compiled -p no:cacheprovider`
-    with a tpu-forcing conftest override (VERDICT r1 item 5).
+    with a tpu-forcing conftest override (`chip_smoke.py`'s kernels
+    phase holds the same parity on the chip).
     """
     import jax
 

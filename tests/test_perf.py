@@ -1,6 +1,6 @@
 """Performance observatory units (ISSUE 10): cost model + fallback,
 overlap-analyzer interval arithmetic on synthetic flight-recorder
-traces, report rendering/writing, and the perfgate exit-code contract.
+traces, and report rendering/writing.
 
 Synthetic traces use the recorder's own record shape — the
 `(ts_ns, dur_ns, phase, name, tid, args)` 6-tuples of
@@ -442,134 +442,3 @@ def test_write_report_non_json_suffix(tmp_path):
     txt = write_report({"schema": 1, "span_counts": {}}, path)
     assert txt == path + ".txt"
     assert os.path.exists(path) and os.path.exists(txt)
-
-
-# ---- perfgate ------------------------------------------------------------
-
-
-def _gate(tmp_path):
-    from tools import perfgate
-
-    return perfgate, str(tmp_path / "BENCH_HISTORY.jsonl")
-
-
-def _seed(perfgate, path, values, metric="fps", direction="higher"):
-    for v in values:
-        perfgate.append_history(
-            "headline",
-            metric,
-            v,
-            path=path,
-            direction=direction,
-            sha="test",
-            fingerprint="testbox|x86_64|cpu1",
-        )
-
-
-def test_perfgate_missing_and_empty_history_exit_2(tmp_path):
-    perfgate, path = _gate(tmp_path)
-    assert perfgate.main(["--history", path]) == 2
-    open(path, "w").close()
-    assert perfgate.main(["--history", path]) == 2
-    assert perfgate.main(["--history", path, "--drop", "1.5"]) == 2
-
-
-def test_perfgate_fresh_history_exits_0(tmp_path):
-    perfgate, path = _gate(tmp_path)
-    _seed(perfgate, path, [100.0])
-    assert perfgate.main(["--history", path]) == 0
-
-
-def test_perfgate_catches_20pct_drop(tmp_path):
-    perfgate, path = _gate(tmp_path)
-    _seed(perfgate, path, [100.0, 101.0, 99.0, 100.0, 80.0])
-    assert perfgate.main(["--history", path]) == 1
-    findings = perfgate.check_records(perfgate.load_history(path))
-    assert len(findings) == 1 and "below the trailing median" in findings[0]
-
-
-def test_perfgate_needs_min_prior_before_relative_check(tmp_path):
-    perfgate, path = _gate(tmp_path)
-    # Two priors only: the relative check must stay disarmed.
-    _seed(perfgate, path, [100.0, 100.0, 50.0])
-    assert perfgate.main(["--history", path]) == 0
-    assert perfgate.main(["--history", path, "--min-prior", "2"]) == 1
-
-
-def test_perfgate_lower_is_better_direction(tmp_path):
-    perfgate, path = _gate(tmp_path)
-    _seed(
-        perfgate,
-        path,
-        [10.0, 10.0, 10.0, 10.0, 13.0],
-        metric="stack_ms",
-        direction="lower",
-    )
-    assert perfgate.main(["--history", path]) == 1
-    _seed(perfgate, path, [9.0], metric="stack_ms", direction="lower")
-    # Newest is healthy again; only the newest record per group gates.
-    assert perfgate.main(["--history", path]) == 0
-
-
-def test_perfgate_budget_scoped_by_fingerprint(tmp_path):
-    from tools import perfgate
-
-    path = str(tmp_path / "h.jsonl")
-    budgets = {"fps": {"min": 90.0, "fingerprint_contains": "tpu"}}
-    perfgate.append_history(
-        "headline", "fps", 50.0, path=path, sha="t", fingerprint="cpubox"
-    )
-    records = perfgate.load_history(path)
-    # CPU fingerprint: the TPU floor must not apply.
-    assert perfgate.check_records(records, budgets=budgets) == []
-    perfgate.append_history(
-        "headline", "fps", 50.0, path=path, sha="t", fingerprint="v5e|tpu"
-    )
-    findings = perfgate.check_records(
-        perfgate.load_history(path), budgets=budgets
-    )
-    assert len(findings) == 1 and "pinned budget min" in findings[0]
-
-
-def test_perfgate_groups_are_per_machine(tmp_path):
-    from tools import perfgate
-
-    path = str(tmp_path / "h.jsonl")
-    # 4 fast records on box A, then one slow record on box B: no
-    # cross-machine comparison may fire.
-    for v in (100.0, 100.0, 100.0, 100.0):
-        perfgate.append_history(
-            "headline", "fps", v, path=path, sha="t", fingerprint="boxA"
-        )
-    perfgate.append_history(
-        "headline", "fps", 10.0, path=path, sha="t", fingerprint="boxB"
-    )
-    assert perfgate.check_records(perfgate.load_history(path)) == []
-
-
-def test_perfgate_skips_malformed_lines(tmp_path):
-    from tools import perfgate
-
-    path = str(tmp_path / "h.jsonl")
-    perfgate.append_history(
-        "headline", "fps", 100.0, path=path, sha="t", fingerprint="box"
-    )
-    with open(path, "a") as f:
-        f.write('{"truncated": \n')
-        f.write("not json at all\n")
-        f.write('{"metric": "fps", "value": "NaN-ish-string"}\n')
-    records = perfgate.load_history(path)
-    assert len(records) == 1
-    assert perfgate.main(["--history", path]) == 0
-
-
-def test_perfgate_env_var_override(tmp_path, monkeypatch):
-    from tools import perfgate
-
-    path = str(tmp_path / "env.jsonl")
-    monkeypatch.setenv("BENCH_HISTORY_PATH", path)
-    rec = perfgate.append_history(
-        "headline", "fps", 42.0, sha="t", fingerprint="box"
-    )
-    assert rec["value"] == 42.0
-    assert os.path.exists(path)

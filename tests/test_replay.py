@@ -534,3 +534,83 @@ class TestStructuralParity:
         snap = reg.snapshot()
         assert snap["telemetry/replay/reuse_delivered"] == 3
         assert snap["telemetry/replay/target_updates"] >= 2
+
+    def test_replay_on_and_off_over_the_same_env_frames(self):
+        """The same fresh unroll stream (T=4, E=4, B=4, 3 batches) into
+        a learner without replay and one with max_reuse=2: equal env
+        frames, and every update the replay arm takes beyond the plain
+        arm's is a counted re-delivery through a live target."""
+        import queue as queue_mod
+
+        T, E, B, n = 4, 4, 4, 3
+        agent = Agent(
+            ImpalaNet(num_actions=2, torso=MLPTorso(hidden_sizes=(32,)))
+        )
+
+        def run(replay):
+            reg = Registry()
+            learner = Learner(
+                agent=agent,
+                optimizer=optax.sgd(1e-2),
+                config=LearnerConfig(
+                    batch_size=B,
+                    unroll_length=T,
+                    publish_interval=1,
+                    traj_ring=True,
+                    replay=replay,
+                    auto_layouts=False,
+                ),
+                example_obs=np.zeros((4,), np.float32),
+                rng=jax.random.key(0),
+                telemetry=reg,
+            )
+            actor = VectorActor(
+                actor_id=0,
+                envs=[ScriptedEnv(episode_len=5) for _ in range(E)],
+                agent=agent,
+                param_store=learner.param_store,
+                enqueue=learner.enqueue,
+                unroll_length=T,
+                seed=7,
+                telemetry=reg,
+                traj_ring=learner.traj_ring,
+            )
+            learner.start()
+            updates = 0
+            try:
+                # Pushes interleaved with steps, so neither the ring nor
+                # the device queue backs up into a blocked actor; then
+                # the replay tail is drained until nothing is delivered.
+                for _ in range(n):
+                    for _ in range(B // E):
+                        actor.unroll_and_push()
+                    learner.step_once(timeout=60)
+                    updates += 1
+                while True:
+                    try:
+                        learner.step_once(timeout=3.0)
+                    except queue_mod.Empty:
+                        break
+                    updates += 1
+            finally:
+                learner.stop()
+            snap = reg.snapshot()
+            return {
+                "updates": updates,
+                "env_frames": actor.num_unrolls * T,
+                "reuse_delivered": int(
+                    snap.get("telemetry/replay/reuse_delivered", 0)
+                ),
+                "target_updates": int(
+                    snap.get("telemetry/replay/target_updates", 0)
+                ),
+            }
+
+        off = run(None)
+        on = run(ReplayConfig(max_reuse=2, target_update_interval=4))
+        assert on["env_frames"] == off["env_frames"] == n * B * T
+        assert off["updates"] == n
+        assert off["reuse_delivered"] == 0 and off["target_updates"] == 0
+        assert on["reuse_delivered"] >= 2
+        assert on["updates"] == off["updates"] + on["reuse_delivered"]
+        assert on["target_updates"] >= 1

@@ -803,6 +803,105 @@ class TestTrainResilienceIntegration:
         # Clean finish wrote the final manifest at the target step.
         assert ck2.all_steps()[-1] == 8
 
+    def test_scripted_faults_then_resume_is_deterministic(self, tmp_path):
+        """One env worker SIGKILLed, one actor thread crashed and the
+        learner crashed at step 4 of 8, process actors, a checkpoint
+        every 2 steps: every fault fires as scripted and the first two
+        are absorbed before the learner's crash ends the run with a
+        save on disk; two resumes of the newest manifest assemble
+        bit-identical first batches; the resumed run ends on the target
+        step."""
+        from torched_impala_tpu import configs
+        from torched_impala_tpu.runtime import VectorActor
+        from torched_impala_tpu.runtime.loop import train
+
+        target, crash_at, interval = 8, 4, 2
+        cfg, common = self._common()
+        fp = config_fingerprint(cfg)
+        injector = ChaosInjector(
+            ChaosPlan.from_dicts(
+                [
+                    {"kind": "kill_env_worker", "at": 4, "target": 0},
+                    {"kind": "raise_in_actor", "at": 3},
+                    {"kind": "crash_learner", "at": crash_at},
+                ]
+            )
+        )
+        ck = AsyncCheckpointer(
+            str(tmp_path), keep=3, interval_steps=interval, config_hash=fp
+        )
+        with pytest.raises(ChaosError):
+            train(
+                total_steps=target,
+                async_checkpointer=ck,
+                chaos=injector,
+                actor_mode="process",
+                envs_per_actor=2,
+                config_hash=fp,
+                **common,
+            )
+        ck.wait()
+        saved = ck.all_steps()
+        ck.close()
+        assert sorted(f.kind for f in injector.fired) == [
+            "crash_learner", "kill_env_worker", "raise_in_actor",
+        ]
+        assert saved and saved[-1] <= crash_at
+
+        lcfg = common["learner_config"]
+        factory = common["env_factory"]
+
+        def first_batch_after_resume():
+            learner = Learner(
+                agent=common["agent"],
+                optimizer=configs.make_optimizer(cfg),
+                config=lcfg,
+                example_obs=common["example_obs"],
+                rng=jax.random.key(0),
+                telemetry=Registry(),
+            )
+            manifest, state = restore_latest(
+                str(tmp_path), learner.get_state(), config_hash=fp
+            )
+            learner.set_state(state)
+            actor = VectorActor(
+                actor_id=0,
+                envs=[factory(1000 + j, j) for j in range(2)],
+                agent=common["agent"],
+                param_store=learner.param_store,
+                enqueue=learner.enqueue,
+                unroll_length=lcfg.unroll_length,
+                seed=7,
+                telemetry=Registry(),
+            )
+            learner.start()
+            try:
+                actor.unroll_and_push()
+                arrays, _, _ = learner._batch_q.get(timeout=120)
+                return manifest.step, jax.tree.map(
+                    lambda x: np.array(x, copy=True), arrays
+                )
+            finally:
+                learner.stop()
+
+        step_a, batch_a = first_batch_after_resume()
+        step_b, batch_b = first_batch_after_resume()
+        assert step_a == step_b == saved[-1]
+        jax.tree.map(np.testing.assert_array_equal, batch_a, batch_b)
+
+        ck2 = AsyncCheckpointer(
+            str(tmp_path), keep=3, interval_steps=interval, config_hash=fp
+        )
+        result = train(
+            total_steps=target,
+            async_checkpointer=ck2,
+            resume="auto",
+            config_hash=fp,
+            **common,
+        )
+        ck2.close()
+        assert result.learner.num_steps == target
+
     def test_resume_refuses_config_mismatch(self, tmp_path):
         from torched_impala_tpu.runtime.loop import train
 
@@ -910,7 +1009,6 @@ def test_lint_flags_unprefixed_resilience_names(tmp_path):
 
     pkg = tmp_path / "torched_impala_tpu"
     pkg.mkdir()
-    (tmp_path / "bench.py").write_text("")
     (pkg / "bad.py").write_text(
         'reg.counter("resilience/orphan_series")\n'
         'reg.counter("resilience/checkpoint_bytes")\n'  # prefixed: clean

@@ -604,6 +604,72 @@ class TestServingEdgeCases:
             server.close()
 
 
+    def test_64_clients_coalesce_under_shadow_with_bf16_parity(
+        self, agent, params
+    ):
+        """64 clients, one request each a round for 5 rounds, against a
+        threaded server that may put all of them into one wave: the
+        requests are served in fewer waves than there are requests, the
+        actions are the direct greedy ones, every sampled wave is scored
+        by a shadow label holding the same parameters without one
+        mismatch, and the bfloat16 cast passes the greedy-action gate on
+        the same 64 observations."""
+        import time
+
+        C, rounds = 64, 5
+        telemetry = Registry()
+        store = ParamStore()
+        store.publish(0, params)
+        registry = VersionRegistry(store, telemetry=Registry())
+        registry.pin("live", 0)
+        registry.pin("shadow", 0)
+        registry.set_routing(
+            {"live": 1.0}, shadow="shadow", shadow_fraction=1.0
+        )
+        server = PolicyServer(
+            agent=agent,
+            registry=registry,
+            example_obs=np.zeros((OBS_DIM,), np.float32),
+            max_clients=C,
+            max_batch=C,
+            max_wait_s=5e-3,
+            telemetry=telemetry,
+        ).start()
+        obs = obs_batch(C, seed=4)
+        try:
+            clients = [InProcessClient(server, greedy=True) for _ in range(C)]
+            for r in range(rounds):
+                cells = [
+                    c.act_async(obs[i], r == 0)
+                    for i, c in enumerate(clients)
+                ]
+                got = [cell.result(timeout=120.0).action for cell in cells]
+                if r == 0:
+                    assert np.array_equal(
+                        np.asarray(got), direct_greedy(agent, params, obs)
+                    )
+            for c in clients:
+                c.close()
+            deadline = time.monotonic() + 30.0
+            while time.monotonic() < deadline:
+                if telemetry.snapshot()["telemetry/serving/shadow_total"]:
+                    break
+                time.sleep(0.01)
+        finally:
+            server.close()
+        snap = telemetry.snapshot()
+        assert snap["telemetry/serving/request_total"] == C * rounds
+        assert (
+            rounds
+            <= snap["telemetry/serving/wave_total"]
+            < snap["telemetry/serving/request_total"]
+        )
+        assert snap["telemetry/serving/shadow_total"] > 0
+        assert snap["telemetry/serving/shadow_mismatch"] == 0
+        ok, mismatches = greedy_action_parity(agent, params, obs)
+        assert ok and mismatches == 0
+
+
 # ---- shm request ring ---------------------------------------------------
 
 

@@ -148,6 +148,40 @@ def test_export_valid_chrome_trace(tmp_path):
     )
 
 
+@pytest.mark.parametrize(
+    "n_records, capacity", [(3 * 500, 4096), (4 * 5000, 16384)]
+)
+def test_export_holds_every_record_up_to_the_ring_size(
+    tmp_path, n_records, capacity
+):
+    """A scripted run of instants, pre-timed spans and span contexts,
+    with and without lineage: the export is a valid Chrome trace of
+    exactly the records written while they fit the ring, and of the
+    ring's size once they do not (the newest are kept)."""
+    rec = FlightRecorder(capacity=capacity)
+    lineage = {"lid": "a0u0", "worker": 3}
+    t_ns = time.monotonic_ns()
+    for i in range(n_records):
+        kind = i % 4
+        if kind == 0:
+            rec.instant("script/evt", lineage)
+        elif kind == 1:
+            rec.complete("script/span", t_ns + i, 1000, lineage)
+        elif kind == 2:
+            with rec.span("script/ctx", lineage):
+                pass
+        else:
+            rec.instant("script/bare")
+    path = str(tmp_path / "trace.json")
+    n = rec.export(path)
+    assert n == min(n_records, rec.capacity)
+    obj = json.load(open(path))
+    assert validate_chrome_trace(obj) == []
+    events = [e for e in obj["traceEvents"] if e["ph"] != "M"]
+    assert len(events) == n
+    assert {e["ph"] for e in events} == {"i", "X"}
+
+
 def test_validate_chrome_trace_catches_violations():
     assert validate_chrome_trace([]) != []
     assert validate_chrome_trace({"foo": 1}) != []

@@ -185,18 +185,34 @@ class TestRingPipeline:
     """Ring vs queue path parity through the REAL VectorActor + Learner
     batcher on deterministic envs."""
 
-    def _drain(self, use_ring, use_lstm=False, T=5, E=2, B=4, n=3):
-        agent = _agent(use_lstm=use_lstm)
+    def _drain(
+        self,
+        use_ring,
+        use_lstm=False,
+        T=5,
+        E=2,
+        B=4,
+        n=3,
+        agent=None,
+        envs=None,
+        example_obs=None,
+        telemetry=None,
+    ):
+        agent = agent if agent is not None else _agent(use_lstm=use_lstm)
+        if envs is None:
+            envs = [ScriptedEnv(episode_len=4) for _ in range(E)]
+        if example_obs is None:
+            example_obs = np.zeros((4,), np.float32)
         learner = Learner(
             agent=agent,
             optimizer=optax.sgd(1e-2),
             config=LearnerConfig(
                 batch_size=B, unroll_length=T, traj_ring=use_ring
             ),
-            example_obs=np.zeros((4,), np.float32),
+            example_obs=example_obs,
             rng=jax.random.key(0),
+            telemetry=telemetry,
         )
-        envs = [ScriptedEnv(episode_len=4) for _ in range(E)]
         actor = VectorActor(
             actor_id=0,
             envs=envs,
@@ -205,6 +221,7 @@ class TestRingPipeline:
             enqueue=learner.enqueue,
             unroll_length=T,
             seed=3,
+            telemetry=telemetry,
             traj_ring=learner.traj_ring,
         )
         learner.start()
@@ -213,7 +230,7 @@ class TestRingPipeline:
             for _ in range(n):
                 for _ in range(B // E):
                     actor.unroll_and_push()
-                arrays, version, _meta = learner._batch_q.get(timeout=60)
+                arrays, version, _meta = learner._batch_q.get(timeout=120)
                 batches.append(
                     (
                         jax.tree.map(
@@ -237,6 +254,64 @@ class TestRingPipeline:
         # Unroll accounting unchanged: E per cycle, counted without
         # Trajectory objects.
         assert actor.num_unrolls == 3 * 4
+
+    def test_ring_copies_nothing_at_stack_stage_on_pixel_unrolls(self):
+        """Fake Pong unrolls (84x84x4 uint8, T=4, E=4, B=4, 3 batches)
+        through both paths: the batches are bit-identical, the queue
+        path copies every unroll at stack time
+        (`learner/host_stack_bytes`) and the ring path copies nothing
+        there; what the ring stages before the transfer on a backend
+        whose device_put may alias host memory (this CPU) never exceeds
+        what the queue path copied."""
+        from torched_impala_tpu import configs
+        from torched_impala_tpu.models import AtariShallowTorso
+        from torched_impala_tpu.telemetry import Registry
+
+        T, E, B, n = 4, 4, 4, 3
+        cfg = configs.ExperimentConfig(
+            name="ring_pixels",
+            env_family="atari",
+            env_id="PongNoFrameskip-v4",
+            obs_shape=(84, 84, 4),
+            obs_dtype="uint8",
+            num_actions=6,
+        )
+        factory = configs.make_env_factory(cfg, fake=True)
+        agent = Agent(ImpalaNet(num_actions=6, torso=AtariShallowTorso()))
+
+        def drain(use_ring):
+            reg = Registry()
+            batches, _ = self._drain(
+                use_ring,
+                T=T,
+                E=E,
+                B=B,
+                n=n,
+                agent=agent,
+                envs=[factory(1000 + j, j) for j in range(E)],
+                example_obs=configs.example_obs(cfg),
+                telemetry=reg,
+            )
+            per_unroll = {
+                name: reg.counter(f"learner/{name}").value / (n * B)
+                for name in ("host_stack_bytes", "ring_stage_bytes")
+            }
+            return batches, per_unroll
+
+        queue_b, queue_bytes = drain(False)
+        ring_b, ring_bytes = drain(True)
+        assert len(queue_b) == len(ring_b) == n
+        for (bq, vq), (br, vr) in zip(queue_b, ring_b):
+            assert vq == vr
+            jax.tree.map(np.testing.assert_array_equal, bq, br)
+        # One unroll holds (T+1) * 84*84*4 bytes of frames alone.
+        assert queue_bytes["host_stack_bytes"] > (T + 1) * 84 * 84 * 4
+        assert ring_bytes["host_stack_bytes"] == 0
+        assert queue_bytes["ring_stage_bytes"] == 0
+        assert (
+            ring_bytes["ring_stage_bytes"]
+            <= queue_bytes["host_stack_bytes"]
+        )
 
     def test_ring_slots_recycle_across_many_batches(self):
         # More batches than slots: every slot is recycled at least once
@@ -342,14 +417,3 @@ class TestRingPipeline:
         )
         assert meshed.traj_ring is not None
         assert len(meshed._batch_shardings) == 8
-        # data_device stays a genuinely unsupported combo.
-        with pytest.raises(ValueError, match="data_device"):
-            Learner(
-                config=LearnerConfig(
-                    batch_size=2,
-                    unroll_length=3,
-                    traj_ring=True,
-                    data_device="cpu",
-                ),
-                **common,
-            )

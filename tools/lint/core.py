@@ -56,10 +56,10 @@ DEFAULT_BASELINE = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "baseline.txt"
 )
 
-# Scanned by default: the package plus the benchmark driver. Tools and
-# tests are excluded (fixtures under tests/lint_fixtures/ carry seeded
-# violations by design).
-DEFAULT_ROOTS = ("torched_impala_tpu", "bench.py")
+# Scanned by default: the package. Tools and tests are excluded
+# (fixtures under tests/lint_fixtures/ carry seeded violations by
+# design).
+DEFAULT_ROOTS = ("torched_impala_tpu",)
 
 
 @dataclasses.dataclass(frozen=True)

@@ -55,7 +55,7 @@ def module_name(rel: str) -> str:
 
     'torched_impala_tpu/parallel/mesh.py' -> 'torched_impala_tpu.parallel.mesh'
     'torched_impala_tpu/ops/__init__.py'  -> 'torched_impala_tpu.ops'
-    'bench.py'                            -> 'bench'
+    'chip_smoke.py'                       -> 'chip_smoke'
     """
     mod = rel[:-3] if rel.endswith(".py") else rel
     mod = mod.replace("/", ".")

@@ -325,8 +325,8 @@ def check(files: Sequence[SourceFile]) -> List[Finding]:
 
 
 def legacy_check(root: str) -> List[str]:
-    """The pre-framework surface: scan `root` (torched_impala_tpu/**
-    + bench.py) and return human-readable strings — one per finding,
+    """The pre-framework surface: scan `root` (torched_impala_tpu/**)
+    and return human-readable strings — one per finding,
     ``path:line: message`` — exactly like tools/check_metric_names.py
     always did. The CLI shim and pre-existing tests call this."""
     from tools.lint.core import (

@@ -160,8 +160,8 @@ class ExperimentConfig:
     # catch up on the next wave (runtime/env_pool.py). Lockstep stays the
     # default and the test baseline; async is opt-in per preset.
     # `pool_ready_fraction` also accepts "auto": the pool retunes the
-    # fraction from an EWMA of its own straggler flags (the measured
-    # rate->fraction line from bench.py's env_pool section).
+    # fraction from an EWMA of its own straggler flags (the
+    # rate->fraction line of env_pool.AUTO_FRACTION_*).
     pool_mode: str = "lockstep"
     pool_ready_fraction: float | str = 0.5
     # Zero-copy trajectory ring (runtime/traj_ring.py): actors write
@@ -780,8 +780,8 @@ PROCGEN = ExperimentConfig(
     compute_dtype="bfloat16",
     actor_mode="process",
     # The largest fleet is where one straggler gates 512 envs in lockstep:
-    # ready-set batching over the first 75% of workers (bench.py env_pool
-    # section: >=1.3x under 10% straggler injection, ~parity without).
+    # ready-set batching over the first 75% of workers (the gain is
+    # not measured on the chip's host: no cell drives an env pool).
     pool_mode="async",
     num_actors=512,
     unroll_length=20,

@@ -68,8 +68,8 @@ class _Binding:
 class ControlLoop:
     """Ticks the bound policies at a fixed interval on a daemon thread.
 
-    ``tick`` is also public and side-effect-complete so tests, doctor,
-    and bench drive the loop deterministically without threads or
+    ``tick`` is also public and side-effect-complete so tests and
+    doctor drive the loop deterministically without threads or
     sleeps (pass an explicit ``now`` for a synthetic clock).
     """
 

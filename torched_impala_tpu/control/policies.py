@@ -11,8 +11,8 @@ Three rule shapes cover every knob the runtime exposes today:
   recompiles stops the climb from hammering a wall.
 - :class:`TargetMapPolicy` — a direct measured-line feedback law:
   ``value = base - slope * signal``. The env_pool EWMA auto
-  ready-fraction tuner is the first instance (the slope is the
-  rate->fraction line fit to bench.py's env_pool measurements).
+  ready-fraction tuner is the first instance (the slope is
+  env_pool.AUTO_FRACTION_SLOPE).
 - :class:`SloPolicy` — budgeted-headroom bang-bang with a hysteresis
   band: shrink the knob while the SLO is violated, relax it back while
   there is ample headroom, hold in between. Serves the serving-tier

@@ -715,19 +715,8 @@ def _check_perf() -> tuple[str, str]:
     """Performance-observatory self-check (docs/OBSERVABILITY.md): the
     cost model must report nonzero FLOPs for a tiny jitted matmul —
     from the backend's cost_analysis where available, else the static
-    estimator — and export the perf/* gauges; the overlap analyzer must
-    attribute a synthetic two-step trace; and perfgate must catch a
-    seeded 20% throughput regression while passing the healthy prefix
-    of the same history."""
-    import os
-    import sys
-    import tempfile
-
-    repo = os.path.dirname(
-        os.path.dirname(os.path.abspath(__file__))
-    )
-    if repo not in sys.path:
-        sys.path.insert(0, repo)
+    estimator — and export the perf/* gauges; and the overlap analyzer
+    must attribute a synthetic two-step trace."""
     try:
         import jax
         import jax.numpy as jnp
@@ -790,47 +779,9 @@ def _check_perf() -> tuple[str, str]:
             )
         if learner["replayed"]["steps"] != 1:
             return "FAIL", "replayed step not attributed separately"
-
-        # perfgate: healthy history passes, a seeded 20% drop fails.
-        from tools.perfgate import (
-            append_history,
-            check_records,
-            load_history,
-        )
-
-        with tempfile.TemporaryDirectory(prefix="doctor_perf_") as td:
-            hist = os.path.join(td, "history.jsonl")
-            for v in (100.0, 101.0, 99.0, 100.0):
-                append_history(
-                    "doctor",
-                    "probe_fps",
-                    v,
-                    path=hist,
-                    sha="doctor",
-                    fingerprint="doctor-host",
-                )
-            healthy = check_records(load_history(hist))
-            if healthy:
-                return "FAIL", (
-                    f"perfgate flagged a healthy history: {healthy[0]}"
-                )
-            append_history(
-                "doctor",
-                "probe_fps",
-                80.0,  # 20% below the trailing median of 100
-                path=hist,
-                sha="doctor",
-                fingerprint="doctor-host",
-            )
-            seeded = check_records(load_history(hist))
-            if not seeded:
-                return "FAIL", (
-                    "perfgate missed a seeded 20% throughput regression"
-                )
         return "ok", (
             f"flops={root.flops:.0f} ({root.source}); analyzer "
-            "attributes feed gap + replayed step; perfgate passes "
-            "healthy history, catches seeded -20%"
+            "attributes feed gap + replayed step"
         )
     except Exception:
         return "FAIL", (

@@ -182,8 +182,8 @@ class VectorSignalEnv:
     but the observation is a float32 one-hot vector, so an MLP torso
     learns it in a handful of SGD steps. This is the cheapest env with a
     genuine learning signal, which makes it the return-target probe for
-    CPU-budget recovery scenarios (bench.py multihost kill_host chaos:
-    prove the resumed run still LEARNS, not merely that it steps).
+    CPU-budget recovery scenarios (runtime/distributed.py's `signal`
+    env: prove a resumed run still LEARNS, not merely that it steps).
     """
 
     def __init__(self, num_actions: int = 2, episode_len: int = 8, seed: int = 0):
@@ -275,9 +275,8 @@ class StragglerEnv:
     Every step sleeps `base_delay_s` (emulator-cost stand-in), plus
     `straggler_delay_s` with probability `straggler_prob` — the long-tail
     stall (GC pause, auto-reset, slow emulator frame) that lockstep env
-    pools serialize onto every wave. The env-pool bench
-    (bench.py run_bench_env_pool) uses this to compare lockstep vs async
-    ready-set scheduling under 0% / 10% straggler injection.
+    pools serialize onto every wave. tests/test_env_pool.py uses it to
+    hold a worker inside a step long enough to kill it there.
     """
 
     def __init__(

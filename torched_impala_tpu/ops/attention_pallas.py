@@ -486,7 +486,7 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
 
 def _visibility(seg_q, seg_ctx, T: int, S: int, W: int):
     """The einsum path's mask (models/transformer.py dense path), exposed
-    for the tests' and bench's reference implementations."""
+    for the tests' reference implementation."""
     t = jnp.arange(T, dtype=jnp.int32)
     s = jnp.arange(S, dtype=jnp.int32)
     pos_ok = (s[None, :] < W) | (s[None, :] - W <= t[:, None])  # [T, S]

@@ -9,12 +9,11 @@ launch: build each child's environment (`child_env`), start the
 processes, babysit them (`launch`), and parse their structured result
 lines (`parse_results`).
 
-Used by tests/test_multihost.py (tier-1 2-process parity), bench.py's
-`multihost` section (weak scaling), doctor's `multihost` row, and the
-`kill_host` chaos scenario — the launcher is also the survivor-side
-failure detector: when one host dies (e.g. SIGKILL mid-collective), the
-surviving processes are blocked inside the broken collective forever, so
-`launch` kills them after a grace period and reports the wreck; callers
+Used by tests/test_multihost.py (tier-1 2-process parity), doctor's
+`multihost` row, and the `kill_host` chaos scenario — the launcher is
+also the survivor-side failure detector: when one host dies (e.g.
+SIGKILL mid-collective), the surviving processes are blocked inside the
+broken collective forever, so `launch` kills them after a grace period and reports the wreck; callers
 restart the whole cluster from the newest checkpoint, which is exactly
 the real-pod failure model (docs/MULTIHOST.md).
 

@@ -1,10 +1,11 @@
 """Performance observatory: cost model (FLOPs/bytes per jitted root,
-live `perf/mfu` / `perf/membw_util` / `perf/flops_per_step` gauges),
-flight-recorder overlap analyzer (`report.py`), and — on the tooling
-side — `tools/perfgate.py`, the BENCH_HISTORY.jsonl regression gate.
+live `perf/mfu` / `perf/membw_util` / `perf/flops_per_step` gauges)
+and the flight-recorder overlap analyzer (`report.py`). What a run
+measured on the chip is `benchmark/`'s and PERF.md's to say, not this
+package's: its gauges are a running job's estimates.
 
 See docs/OBSERVABILITY.md "Performance observatory" for the gauge
-table, report anatomy, and the perfgate workflow.
+table and the report's anatomy.
 """
 
 from torched_impala_tpu.perf.costmodel import (
@@ -12,7 +13,6 @@ from torched_impala_tpu.perf.costmodel import (
     CostModel,
     DevicePeaks,
     RootCost,
-    device_peaks,
     extract_compiled_cost,
     param_count,
     static_flops_estimate,
@@ -35,7 +35,6 @@ __all__ = [
     "CostModel",
     "DevicePeaks",
     "RootCost",
-    "device_peaks",
     "extract_compiled_cost",
     "param_count",
     "static_flops_estimate",

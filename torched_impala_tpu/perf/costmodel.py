@@ -1,8 +1,8 @@
 """Cost model: FLOPs / bytes-accessed per jitted root, live MFU gauges.
 
-The ROADMAP's MFU push (Pong 0.19-0.25, deep ResNet+LSTM ~0.11, B1024
-0.28) has so far been measured only by bench.py's offline arithmetic.
-This module makes the same numbers a LIVE observable: every jitted root
+The benchmark computes `train_step.mfu` offline, from shapes and a device
+trace (`benchmark/flops.py`, `benchmark/readers.py`). This module is the
+LIVE estimate a running job exports: every jitted root
 (train_step, replay step, serving wave, fused K-step) registers its
 compiled cost here, and the learner's step cadence turns them into
 `perf/mfu`, `perf/membw_util`, and `perf/flops_per_step` gauges through
@@ -12,7 +12,7 @@ Two sources, in preference order:
 
 - ``cost_analysis`` — XLA's algebraic per-program count, read off a
   compiled executable (``jax.jit(f).lower(...).compile()`` or an AOT
-  handle). Caveat inherited from bench.py: XLA counts every
+  handle). Caveat (PERF.md section 6, PR 23): XLA counts every
   `lax.scan`/`while` BODY once, not x trip count, so grad-accum
   programs under-count by ~accum (pass ``steps_per_call``/``flops_scale``
   to correct) while a fused-K body IS one full SGD step already.
@@ -24,9 +24,8 @@ Two sources, in preference order:
 
 Peaks come from ONE table keyed by `device_kind` (DEVICE_PEAKS below,
 with its source). A device that is not in the table has no peaks: the
-utilisation gauges then stay unset (`CostModel.for_device`) or the
-lookup raises (`device_peaks`, for code that reports a utilisation) —
-no device is a v5e by default.
+utilisation gauges then stay unset (`CostModel.for_device`) — no device
+is a v5e by default.
 """
 
 from __future__ import annotations
@@ -69,18 +68,6 @@ DEVICE_PEAKS: Dict[str, DevicePeaks] = {
 # gloo TCP; 4 GB/s is the measured order of magnitude. Not a device
 # peak: it only feeds the all-reduce OVERLAP estimate of a CPU mesh.
 LOOPBACK_BYTES_PER_S = 4e9
-
-
-def device_peaks(device_kind: str) -> DevicePeaks:
-    """Peaks for `device_kind`; a device not in the table is an error."""
-    try:
-        return DEVICE_PEAKS[device_kind]
-    except KeyError:
-        raise ValueError(
-            f"no published peaks for device_kind {device_kind!r}; "
-            f"DEVICE_PEAKS knows {sorted(DEVICE_PEAKS)} — add the "
-            "device with its source instead of assuming another's"
-        ) from None
 
 
 def allreduce_ns(nbytes: float, n_shards: int, bytes_per_s: float) -> int:

@@ -11,7 +11,7 @@ Three pillars (docs/RESILIENCE.md):
 - `chaos` — declarative fault plans (SIGKILL env workers, crash actors,
   wedge the trajectory queue, delay shm lanes, corrupt checkpoints,
   crash the learner) injected through runtime hooks; exercised by
-  tests/test_resilience.py and the `bench.py` chaos section.
+  tests/test_resilience.py.
 """
 
 from torched_impala_tpu.resilience.checkpointer import AsyncCheckpointer

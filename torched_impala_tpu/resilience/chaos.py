@@ -5,8 +5,7 @@ the resume path all CLAIM to handle failure; this module exercises those
 claims on demand instead of waiting for production to. A `ChaosPlan` is a
 list of `Fault`s — each names a KIND, an injection SITE counter value
 (`at` = the Nth event observed at that site), and an optional target —
-parsed from JSON (`--chaos-plan plan.json`) or built in code (tests,
-`bench.py chaos`).
+parsed from JSON (`--chaos-plan plan.json`) or built in code (tests).
 
 Fault kinds and the hook site each rides:
 
@@ -130,8 +129,8 @@ class Fault:
 
 @dataclasses.dataclass(frozen=True)
 class ChaosPlan:
-    """An ordered fault list; the declarative artifact tests and the
-    bench assert against."""
+    """An ordered fault list; the declarative artifact tests assert
+    against."""
 
     faults: tuple
 

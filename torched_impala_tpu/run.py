@@ -64,7 +64,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "straight into preallocated learner batch slots "
                         "(no per-env Trajectory arrays, no np.stack); "
                         "needs vectorized actors whose env counts divide "
-                        "batch-size; composes with --dp-devices meshes "
+                        "batch-size; composes with --dp meshes "
                         "(runtime/traj_ring.py)")
     p.add_argument("--max-reuse", type=int, default=None,
                    help="replay: deliver each committed unroll up to N "
@@ -156,8 +156,7 @@ def parse_args(argv=None) -> argparse.Namespace:
                         "each, gloo collectives, loopback coordinator) "
                         "and run it as an N-host cluster — the "
                         "parallel/simhost.py harness behind the tier-1 "
-                        "multi-host tests and the bench multihost "
-                        "section (docs/MULTIHOST.md)")
+                        "multi-host tests (docs/MULTIHOST.md)")
     # Environments.
     p.add_argument("--env-id", default=None,
                    help="override the preset's env id (e.g. a different "

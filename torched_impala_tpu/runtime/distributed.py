@@ -25,8 +25,7 @@ environment triple (`multihost.bootstrap`). Single-process invocation
 process-count-agnostic property the tier-1 parity test pins.
 
 Used by: tests/test_multihost.py (2-process vs 1-process loss-trajectory
-parity), bench.py `multihost` (weak scaling + allreduce overlap),
-doctor's multihost row, and the kill_host chaos bench scenario.
+parity, the kill_host recovery scenario) and doctor's multihost row.
 """
 
 from __future__ import annotations
@@ -67,7 +66,6 @@ class DistSpec:
     # (VectorSignalEnv, genuine learning signal for return targets).
     env: str = "fake"
     episode_len: int = 8
-    env_delay_s: float = 0.0  # StragglerEnv pacing (weak-scaling bench)
     # Optimizer.
     optimizer: str = "sgd"
     learning_rate: float = 1e-2
@@ -148,17 +146,6 @@ class SpecEnvFactory:
         )
 
 
-def make_env_factory(spec: DistSpec):
-    from torched_impala_tpu.envs import StragglerFactory
-
-    base = SpecEnvFactory(
-        spec.env, spec.obs_dim, spec.num_actions, spec.episode_len
-    )
-    if spec.env_delay_s > 0.0:
-        return StragglerFactory(base, base_delay_s=spec.env_delay_s)
-    return base
-
-
 def example_obs(spec: DistSpec):
     import numpy as np
 
@@ -171,8 +158,8 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
 
     Returns the structured payload that the worker main prints as a
     SIMHOST_RESULT line: per-step losses, steps/frames, publish version,
-    telemetry picks (allreduce/H2D overlap, per-host labels), episode
-    returns — everything the cluster-side callers assert on.
+    per-host telemetry labels, episode returns — what the cluster-side
+    callers assert on.
     """
     import dataclasses as _dc
 
@@ -184,7 +171,6 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
     from torched_impala_tpu.parallel import multihost
     from torched_impala_tpu.runtime.learner import LearnerConfig
     from torched_impala_tpu.runtime.loop import train
-    from torched_impala_tpu.telemetry import get_registry
 
     topo = multihost.topology()
     mesh = multihost.global_mesh(
@@ -232,20 +218,13 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
 
     from torched_impala_tpu.telemetry import get_aggregator
 
-    import time
-
     losses: List[float] = []
     versions: List[int] = []
     proc_labels: set = set()
-    log_times: List[float] = []
 
     def logger(logs):
         if "total_loss" in logs:
             losses.append(float(logs["total_loss"]))
-            # Per-log-call wall clock: the steady-state frames/s window
-            # below starts at the FIRST call (after jit compile) so the
-            # weak-scaling quotient compares stepping, not compilation.
-            log_times.append(time.monotonic())
         if "param_version" in logs:
             versions.append(int(logs["param_version"]))
         # Sample the fan-in lanes while the pool is alive: aggregated
@@ -258,10 +237,11 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
                 if parts[1].startswith("proc"):
                     proc_labels.add(parts[1])
 
-    t_train = time.monotonic()
     result = train(
         agent=agent,
-        env_factory=make_env_factory(spec),
+        env_factory=SpecEnvFactory(
+            spec.env, spec.obs_dim, spec.num_actions, spec.episode_len
+        ),
         example_obs=example_obs(spec),
         num_actors=spec.num_actors,
         learner_config=lcfg,
@@ -278,12 +258,10 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
         envs_per_actor=spec.envs_per_actor,
         actor_mode=spec.actor_mode,
     )
-    train_s = time.monotonic() - t_train
     if async_ck is not None:
         async_ck.wait()
         async_ck.close()
 
-    snap = get_registry().snapshot()
     returns = [r for _, r, _ in result.episode_returns]
     payload: Dict[str, Any] = {
         "host": topo.process_index,
@@ -292,28 +270,6 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
         "global_devices": topo.global_device_count,
         "steps": int(result.learner.num_steps),
         "num_frames": int(result.num_frames),
-        # Train-loop wall time only (bootstrap/compile excluded by
-        # neither — this is end-to-end inside train(); the weak-scaling
-        # bench compares like against like, so shared overheads cancel).
-        "train_s": round(train_s, 4),
-        "frames_per_s": (
-            round(result.num_frames / train_s, 2) if train_s > 0 else 0.0
-        ),
-        # Global frames/s over the steady window (first log call ->
-        # last), excluding the compile-laden first step. None until at
-        # least two log calls landed.
-        "steady_frames_per_s": (
-            round(
-                (len(log_times) - 1)
-                * spec.log_every
-                * spec.batch_size
-                * spec.unroll_length
-                / (log_times[-1] - log_times[0]),
-                2,
-            )
-            if len(log_times) >= 2 and log_times[-1] > log_times[0]
-            else None
-        ),
         "losses": [round(x, 10) for x in losses],
         "publish_version": int(result.learner.param_store.version),
         "local_batch_size": int(result.learner._local_batch_size),
@@ -321,11 +277,6 @@ def run_host(spec: DistSpec) -> Dict[str, Any]:
             float(np.mean(returns[-20:])) if returns else None
         ),
         "episodes": len(returns),
-        "allreduce_overlap_frac": snap.get(
-            "telemetry/perf/allreduce_overlap_frac"
-        ),
-        "allreduce_ns_total": snap.get("telemetry/perf/allreduce_ns_total"),
-        "h2d_overlap_frac": snap.get("telemetry/perf/h2d_overlap_frac"),
         "proc_labels": sorted(proc_labels),
     }
     return payload

@@ -317,13 +317,15 @@ class ProcessEnvPool:
             raise ValueError(
                 f"unknown pool mode {mode!r}; expected 'lockstep' or 'async'"
             )
-        # "auto": EWMA straggler-rate tuner (ROADMAP remaining idea). The
-        # bench.py env_pool measurements say the best fraction tracks the
-        # straggler rate — 0.25 won at 10% injected stragglers (1.81x
-        # lockstep) while every fraction ties without stragglers (the
-        # grace window coalesces full batches) — so the tuner maps an
-        # EWMA of the pool's own straggler flags onto that measured line
-        # and retunes every AUTO_FRACTION_INTERVAL observed steps.
+        # "auto": EWMA straggler-rate tuner. The best fraction tracks
+        # the straggler rate — small waves under stragglers, full waves
+        # without (the grace window coalesces full batches) — so the
+        # tuner maps an EWMA of the pool's own straggler flags onto the
+        # AUTO_FRACTION_* line below and retunes every
+        # AUTO_FRACTION_INTERVAL observed steps. The line came from CPU
+        # sandbox runs with injected delays; no benchmark cell drives an
+        # env pool yet (PERF.md section 7, row 2), so it is not measured
+        # on the chip's host.
         self._auto_fraction = ready_fraction == "auto"
         if self._auto_fraction:
             ready_fraction = 0.5  # the historical default, until evidence
@@ -391,7 +393,7 @@ class ProcessEnvPool:
         # straggler-flag EWMA (this pool was the prototype the framework
         # generalizes — see torched_impala_tpu/control/). The pool ticks
         # its policy itself from _observe_step: the tuner must work in
-        # bench/eval harnesses that never start a ControlLoop thread.
+        # eval harnesses and tests that never start a ControlLoop thread.
         if self._auto_fraction:
             from torched_impala_tpu.control import (
                 FnSignal,
@@ -560,11 +562,12 @@ class ProcessEnvPool:
 
     # ready_fraction="auto" tuner parameters: straggler-flag EWMA
     # weight, retune period (observed steps), and the rate->fraction
-    # line fit to the bench.py env_pool measurements — rate 0 maps to
-    # 1.0 (full coalesced waves; parity without stragglers at every
-    # fraction) and rate 0.1 maps to the 0.25 floor (the measured 1.81x
-    # winner at 10% injected stragglers). SLOPE/MIN parameterize the
-    # control-plane TargetMapPolicy/KnobSpec built in __init__.
+    # line — rate 0 maps to 1.0 (full coalesced waves) and rate 0.1
+    # maps to the 0.25 floor (the best fraction at 10% injected
+    # stragglers in CPU sandbox runs; not measured on the chip's host).
+    # SLOPE/MIN parameterize the control-plane TargetMapPolicy/KnobSpec
+    # built in __init__; tests/test_env_pool.py::TestAutoReadyFraction
+    # and tests/test_control.py hold the tuner's behaviour.
     AUTO_FRACTION_ALPHA = 1.0 / 32.0
     AUTO_FRACTION_INTERVAL = 32
     AUTO_FRACTION_SLOPE = 7.5

@@ -140,10 +140,10 @@ class LearnerConfig:
     # step. The r5 headline trace showed a 0.50 ms/step pure-layout copy
     # of the uint8 obs batch (copy.3, 9% of the device step) that this
     # moves into the double-buffered H2D transfer — off the serial
-    # critical path; measured on-chip: 658k -> 698k frames/s (+6%).
+    # critical path (both benchmark cells run with it on; its own gain
+    # has not been measured on the chip, ROADMAP S8).
     # Single-device (mesh=None) path only; ignored under a mesh (pjit
-    # sharding x layout interplay) and with data_device (cross-backend
-    # formats don't transfer). The step itself is AOT-compiled on the
+    # sharding x layout interplay). The step itself is AOT-compiled on the
     # first batch; numerics are identical (layouts don't change math).
     auto_layouts: bool = True
     # Zero-copy trajectory ring (runtime/traj_ring.py): vectorized
@@ -185,14 +185,6 @@ class LearnerConfig:
     # pre-existing step. run.py gates bf16 behind a greedy-action
     # parity probe and falls back to f32 when the probe fails.
     train_dtype: str = "float32"
-    # Backend NAME ("cpu") the batcher device_puts assembled batches to,
-    # instead of the default device. A measurement/staging knob (bench's
-    # feeder section uses it to time the ingest path against the local
-    # CPU backend: a drain into the accelerator measures the H2D route,
-    # not host work). Training with data_device different from the
-    # compute device is NOT supported (the train step would pull every
-    # batch cross-backend); None = default device.
-    data_device: Optional[str] = None
     # IMPACT-style replay (replay/ subsystem, docs/REPLAY.md): retain
     # ring slots for up to max_reuse deliveries and train replayed
     # batches with the clipped target-network surrogate
@@ -518,20 +510,6 @@ class Learner:
         self._optimizer = optimizer
         self._logger = logger
         self._mesh = mesh
-        # Resolve the batcher's device_put target ONCE: a typo'd backend
-        # name fails here, loudly, instead of per-batch inside the
-        # batcher thread (surfaced only via self.error).
-        if config.data_device is not None and mesh is not None:
-            raise ValueError(
-                "LearnerConfig.data_device is a measurement/staging knob "
-                "and cannot combine with a mesh: the pjit'd step expects "
-                "mesh-sharded batches, not arrays on another backend"
-            )
-        self._data_device = (
-            jax.local_devices(backend=config.data_device)[0]
-            if config.data_device is not None
-            else None
-        )
         # Full-bf16 step (ISSUE 16): the loss closures cast the f32
         # master params to this dtype; None = the exact f32 path.
         precision.validate_compute_dtype("train_step", config.train_dtype)
@@ -764,8 +742,10 @@ class Learner:
         self._m_fused_fallbacks = reg.counter("perf/fused_fallbacks")
         # Zero-copy feed path (donate_batch): how much of the H2D
         # dispatch wall-time landed inside a train step's compute window
-        # (the overlapped-H2D design point). ns counters so bench can
-        # snapshot window deltas; the gauge is the cumulative fraction.
+        # (the overlapped-H2D design point). ns counters so a reader can
+        # take window deltas; the gauge is the cumulative fraction. Read
+        # by tests/test_feed_path.py and doctor; by nothing on a chip
+        # (ROADMAP D6).
         # `learner/donated_batches` counts batches fed without a staging
         # copy (the donation gauge OBSERVABILITY.md documents).
         self._m_h2d_total_ns = reg.counter("perf/h2d_ns_total")
@@ -865,11 +845,6 @@ class Learner:
         # adds two more so retained slots don't starve the free list.
         self.traj_ring: Optional[TrajectoryRing] = None
         if config.traj_ring:
-            if config.data_device is not None:
-                raise ValueError(
-                    "traj_ring cannot combine with data_device (the "
-                    "measurement knob keeps the queue path)"
-                )
             if config.steps_per_dispatch > 1 and self._replay is not None:
                 raise ValueError(
                     "traj_ring superbatch (steps_per_dispatch > 1) does "
@@ -949,11 +924,6 @@ class Learner:
         fused = config.steps_per_dispatch > 1
         step_impl = self._train_multi_impl if fused else self._train_step_impl
         if config.donate_batch:
-            if config.data_device is not None:
-                raise ValueError(
-                    "donate_batch cannot combine with data_device (the "
-                    "measurement knob keeps the copy path)"
-                )
             if self._replay is not None:
                 raise ValueError(
                     "donate_batch does not compose with replay: a "
@@ -1008,11 +978,7 @@ class Learner:
                 self._replay_step = jax.jit(
                     self._train_step_replay_impl, donate_argnums=(0, 1, 2)
                 )
-            if (
-                config.auto_layouts
-                and config.data_device is None
-                and self._replay is None
-            ):
+            if config.auto_layouts and self._replay is None:
                 from jax.experimental.layout import Format, Layout
 
                 auto = Format(Layout.AUTO)
@@ -1581,10 +1547,7 @@ class Learner:
                 # multihost mesh, devices.flat[0] can belong to another
                 # process, and reading such an array back raises (killed
                 # the batcher thread in the 2-process test).
-                if self._data_device is not None:
-                    # Probe the same device the batcher targets.
-                    target = self._data_device
-                elif self._mesh is None:
+                if self._mesh is None:
                     target = None
                 else:
                     local = set(jax.local_devices())
@@ -1743,7 +1706,7 @@ class Learner:
     def _count_stack_bytes(self, batch: Trajectory) -> None:
         """Account the bytes `stack_trajectories` just copied — the
         per-batch host copy cost the trajectory ring eliminates
-        (bench.py traj_ring section reads this counter)."""
+        (tests/test_traj_ring.py reads this counter on both paths)."""
         self._m_host_stack_bytes.inc(
             tree_nbytes(
                 (
@@ -1816,10 +1779,8 @@ class Learner:
 
     def _put_batch(self, arrays):
         """H2D placement of one assembled batch 8-tuple, honoring
-        data_device / AUTO-layout formats / the mesh — shared by the
-        queue and trajectory-ring batcher loops."""
-        if self._data_device is not None:
-            return jax.device_put(arrays, self._data_device)
+        AUTO-layout formats / the mesh — shared by the queue and
+        trajectory-ring batcher loops."""
         if self._mesh is None:
             # Locals, not repeated attribute reads: step_once's
             # layout-mismatch fallback nulls these from the main

@@ -83,8 +83,8 @@ def tree_nbytes(tree: Any) -> int:
 
     The copy-bytes accounting unit behind `telemetry/learner/
     host_stack_bytes` (how many bytes the batcher's stacking path copies
-    per batch — the number the zero-copy trajectory ring drives to 0)
-    and bench.py's `traj_ring` section."""
+    per batch — the number the zero-copy trajectory ring drives to 0;
+    tests/test_traj_ring.py holds both sides)."""
     return sum(
         leaf.nbytes
         for leaf in jax.tree.leaves(tree)

@@ -25,7 +25,7 @@ class InProcessClient:
 
     `act()` is the synchronous surface (submit + wait); `act_async()`
     returns the result cell for callers that pipeline their own waits
-    (the bench's concurrent-client driver). Use as a context manager or
+    (a concurrent-client driver). Use as a context manager or
     call `close()` so the slot frees for the next client.
     """
 

@@ -17,8 +17,8 @@ per seed.
 
 The verdict is a `LoadReport`: p50/p99 latency, achieved vs offered
 rate, and GOODPUT — completed requests per second that landed within
-the SLO. Goodput-at-SLO is the fleet's headline number (bench.py
-`loadgen` section → BENCH_HISTORY.jsonl → tools/perfgate.py budgets):
+the SLO. Goodput-at-SLO is the fleet's headline number (no benchmark
+cell drives serving yet, PERF.md section 7, row 4):
 past the saturation knee, raw throughput keeps climbing while goodput
 collapses, which is exactly the regression a latency gate must catch.
 """
@@ -28,7 +28,7 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -179,7 +179,6 @@ def run_load(
     slow_frac: float = 0.0,
     slow_hold_ms: float = 20.0,
     timeout_s: float = 30.0,
-    on_arrival: Optional[Callable[[int], None]] = None,
 ) -> LoadReport:
     """Drive `fleet` with `shape` arrivals from `clients` worker threads
     and return the `LoadReport`.
@@ -188,9 +187,7 @@ def run_load(
     scheduled arrival, sleeps until its instant, and issues a blocking
     request — so the OFFERED process is `shape` regardless of how slow
     the fleet answers (until all workers are stuck in flight, which the
-    report exposes as offered-vs-achieved divergence plus fat tails).
-    `on_arrival(i)` runs as arrival `i` is claimed (bench chaos uses it
-    to trigger mid-run faults at a deterministic arrival)."""
+    report exposes as offered-vs-achieved divergence plus fat tails)."""
     if slo_ms <= 0:
         raise ValueError(f"slo_ms must be > 0, got {slo_ms}")
     if clients < 1:
@@ -227,8 +224,6 @@ def run_load(
                     if i >= n:
                         return
                     next_idx[0] += 1
-                if on_arrival is not None:
-                    on_arrival(i)
                 t_sched = start + float(arrivals[i])
                 delay = t_sched - time.monotonic()
                 if delay > 0:

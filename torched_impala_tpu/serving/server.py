@@ -36,7 +36,7 @@ Core mechanics (docs/SERVING.md has the diagrams):
   quantizes them per-channel (serving/quant.py) and dequantizes inside
   the jitted wave — the actor-side speed/memory levers. Policy: both
   must pass the f32 greedy-action parity gate (`greedy_action_parity`,
-  run by doctor/tests/bench/run.py) before a fleet trusts them.
+  run by doctor/tests/run.py) before a fleet trusts them.
 
 Every request carries a lineage ID (`c<slot>r<seq>`) recorded on the
 `serving/request` span; waves record `serving/wave` with the exact
@@ -686,8 +686,7 @@ class PolicyServer:
         the first wave at the new version. Draining rollouts
         (fleet.rollout) call this while the replica is still out of
         rotation: with a second replica carrying traffic the warm is
-        free, with one replica it is downtime — the availability gap
-        bench.py's loadgen section measures. No-op for float32."""
+        free, with one replica it is downtime. No-op for float32."""
         if self._dtype == "float32":
             return
         params = self._registry.store.get_version(version)

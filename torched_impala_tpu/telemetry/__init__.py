@@ -17,7 +17,6 @@ from torched_impala_tpu.telemetry.registry import (
     Histogram,
     Registry,
     get_registry,
-    set_enabled,
 )
 from torched_impala_tpu.telemetry.watchdog import (
     StallWatchdog,
@@ -37,7 +36,6 @@ from torched_impala_tpu.telemetry.tracing import (
     get_recorder,
     install_sigusr2,
     mint_lineage_id,
-    set_trace_enabled,
     validate_chrome_trace,
 )
 from torched_impala_tpu.telemetry.aggregate import (
@@ -80,7 +78,6 @@ __all__ = [
     "Histogram",
     "Registry",
     "get_registry",
-    "set_enabled",
     "StallWatchdog",
     "dump_thread_stacks",
     "install_thread_excepthook",
@@ -92,7 +89,6 @@ __all__ = [
     "get_recorder",
     "install_sigusr2",
     "mint_lineage_id",
-    "set_trace_enabled",
     "validate_chrome_trace",
     "LABEL_RE",
     "SnapshotLane",

@@ -9,8 +9,8 @@ scalar keys (`telemetry/<component>/<name>`) that ride the existing
 `Logger.write(dict)` surface, so every logger backend (print/csv/jsonl/tb)
 gets the signals for free.
 
-Hot-path cost discipline (bench.py `telemetry` section pins < 2% on
-env-pool steps/s):
+Hot-path cost discipline (what it costs on the chip: PERF.md section 6,
+PR 24, registry and recorder on against off):
 - one metric object per call site, resolved ONCE at component
   construction — the hot path never does a dict lookup or name parse;
 - each metric has its own small lock (a counter increment never contends
@@ -428,10 +428,3 @@ _GLOBAL = Registry()
 def get_registry() -> Registry:
     """The process-global registry every pipeline stage records into."""
     return _GLOBAL
-
-
-def set_enabled(enabled: bool) -> None:
-    """Enable/disable the global registry's hot-path recording (records
-    become one attribute load + branch). Snapshot still works; existing
-    values freeze."""
-    _GLOBAL.enabled = enabled

@@ -1,5 +1,5 @@
 """One rule for JAX's persistent compilation cache, for every entry point
-(`run.main`, `bench.main`, `chip_smoke.py`, `tests/conftest.py`).
+(`run.main`, `chip_smoke.py`, `benchmark/program.py`, `tests/conftest.py`).
 
 If `JAX_COMPILATION_CACHE_DIR` is set, JAX reads it itself and this code
 sets no directory. Otherwise the cache lives at `<checkout>/.jax_cache`
