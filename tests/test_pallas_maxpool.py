@@ -387,6 +387,8 @@ def test_learner_step_has_no_select_and_scatter_and_mesh_compiles():
     cfg, learner = _tiny_breakout_learner()
     try:
         assert learner.kernels["max_pool"] == "kernel"
+        # a step lowered for the CPU takes no packed weight gradient
+        assert learner.kernels["packed_convs"] == []
         text = (
             _traced_step(cfg, learner)
             .lower(lowering_platforms=("tpu",))

@@ -15,6 +15,7 @@ A compile that passes is not a chip run. `chip_smoke.py` is the chip run.
 
 from __future__ import annotations
 
+import collections
 import math
 import os
 import re
@@ -177,12 +178,18 @@ def test_conv_block(one_chip):
     assert "tpu_custom_call" in text
 
 
+# Per cell: the packed weight gradients the first two (DMLab: all three)
+# sections' blocks take, as `(p + 2, C, p * C)` of the packed product's
+# `f32[3, p+2, C, p*C]` result -> how many.
 @pytest.mark.parametrize(
-    "n,obs",
-    [(21 * 256, (84, 84, 4)), (101 * 64, (72, 96, 3))],
+    "n,obs,packed",
+    [
+        (21 * 256, (84, 84, 4), {(9, 16, 112): 4, (5, 32, 96): 4}),
+        (101 * 64, (72, 96, 3), {(8, 16, 96): 4, (5, 32, 96): 8}),
+    ],
     ids=["breakout_cell", "dmlab_cell"],
 )
-def test_deep_torso_pool_gradient(one_chip, n, obs):
+def test_deep_torso_pool_gradient(one_chip, n, obs, packed):
     """The bf16 deep torso's gradient at the benchmark cells' (T+1)*B
     images (a compile takes as long at 128, and there both versions fit
     without temporaries worth comparing): each pool is two
@@ -193,10 +200,24 @@ def test_deep_torso_pool_gradient(one_chip, n, obs):
     observations' own re-layout (the learner's AUTO input layouts are
     what removes that one) — and the step needs fewer
     temporaries than with XLA's pool compiled beside it (the convolution
-    outputs are no longer kept for the backward)."""
+    outputs are no longer kept for the backward).
+
+    ISSUE 31, in the same compile: with `packed_gradients`, as
+    `resolve_kernels` builds a TPU's torso, the residual blocks' weight
+    gradients are the packed products (`rhs_dilate=1xp`, `p*C` output
+    features, float32), one per convolution the torso's `packed_convs`
+    lists, XLA's own `[3,3,C,C]` weight gradient is left only where
+    nothing divides, and the reshape of the cotangent beside each is no
+    `copy` either (the same `moved` list)."""
+    torsos = {
+        kernel: AtariDeepTorso(
+            dtype=jnp.bfloat16, pool_kernel=kernel, packed_gradients=True
+        )
+        for kernel in (True, False)
+    }
 
     def compiled(pool_kernel):
-        torso = AtariDeepTorso(dtype=jnp.bfloat16, pool_kernel=pool_kernel)
+        torso = torsos[pool_kernel]
         params = jax.eval_shape(
             torso.init, jax.random.key(0), jnp.zeros((1, *obs), jnp.uint8)
         )
@@ -217,6 +238,19 @@ def test_deep_torso_pool_gradient(one_chip, n, obs):
 
     kernel, xla = compiled(True), compiled(False)
     text = kernel.as_text()
+    products = collections.Counter(
+        tuple(map(int, m.group(1).split(",")))
+        for m in re.finditer(
+            r"= f32\[3,(\d+,\d+,\d+)\]\S* convolution\([^\n]*rhs_dilate=1x",
+            text,
+        )
+    )
+    assert products == packed
+    assert packed == collections.Counter(
+        (p + 2, c, p * c) for c, _, _, p in torsos[True].packed_convs(obs)
+    )
+    plain = len(re.findall(r"= \w+\[3,3,\d+,\d+\]\S* convolution\(", text))
+    assert plain == 3 + 4 * 3 - sum(packed.values())
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert "max_pool_forward" in text and "max_pool_backward" in text
     assert "select-and-scatter(" not in text
@@ -364,3 +398,32 @@ def test_mosaic_kernel_is_refused_under_a_mesh(data_mesh):
     )
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile(_vtrace, tb, tb, tb, tb, boot)
+
+
+def test_packed_weight_gradients_partition_under_data_mesh(data_mesh):
+    """The residual blocks' packed weight gradients are XLA convolutions
+    and partition like any other (the batch they contract is sharded
+    over `data`, so the gradients' all-reduce follows them): a block's
+    gradient at 42x42x16 compiles for four chips with both packed
+    products in it and no Mosaic call."""
+    from torched_impala_tpu.models.torsos import ResidualBlock
+
+    block = ResidualBlock(16, dtype=jnp.bfloat16, packed_gradient=True)
+    shape = (4 * 64, 42, 42, 16)
+    params = jax.eval_shape(
+        block.init, jax.random.key(0), jnp.zeros((1, *shape[1:]), jnp.bfloat16)
+    )
+    rep = NamedSharding(data_mesh, spec_layout.replicated_spec())
+    rows = NamedSharding(data_mesh, spec_layout.state_spec())
+
+    def loss(p, x):
+        return jnp.sum(block.apply(p, x).astype(F32))
+
+    text = _compile(
+        jax.grad(loss),
+        jax.tree.map(lambda a: _shape(rep, a.shape, a.dtype), params),
+        _shape(rows, shape, jnp.bfloat16),
+    )
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+    assert len(re.findall(r"convolution\([^\n]*rhs_dilate=1x7", text)) == 2

@@ -11,13 +11,17 @@ TPU notes: convs/matmuls run on the MXU; `dtype` selects the compute dtype
 stay float32. Pixel observations arrive uint8 `[..., H, W, C]` and are
 scaled inside the torso so the host→device transfer stays 1 byte/pixel.
 
-Three TPU-shaped rewrites live here, none of which changes a parameter
+Four TPU-shaped rewrites live here, none of which changes a parameter
 tree: the first pixel convolution's kernel-side 1/255 fold and its
-space-to-depth form (`_FirstPixelConv`), and the deep torso's max-pool,
+space-to-depth form (`_FirstPixelConv`), the deep torso's max-pool,
 whose backward routes gradients from a one-byte winner index saved by
 the forward instead of XLA's `select-and-scatter` over the kept
 convolution output (`ops/maxpool_pallas.py`; not differentiated it is
-`nn.max_pool` as before).
+`nn.max_pool` as before), and the residual blocks' 3x3 convolutions,
+whose weight gradient in a step built for a TPU takes several adjacent
+pixels a product so that 16 channels fill the MXU's rows
+(`ops/conv_packed.py`; forward and input gradient are `nn.Conv`'s
+everywhere, and so is the weight gradient off a TPU).
 """
 
 from __future__ import annotations
@@ -27,7 +31,9 @@ from typing import Sequence
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from flax.linen.dtypes import promote_dtype
 
+from torched_impala_tpu.ops.conv_packed import conv3x3, pack_width
 from torched_impala_tpu.ops.maxpool_pallas import max_pool
 
 
@@ -226,10 +232,9 @@ class AtariShallowTorso(nn.Module):
 class _ConvParams(nn.Module):
     """Param-only 3x3 conv holder: same param names, shapes, and default
     initializers as `nn.Conv(features, (3, 3))`, so a `ResidualBlock`
-    with `fused=True` has a param tree bitwise identical to the
-    reference branch (the submodule is named `Conv_0`/`Conv_1`, matching
-    flax's auto-naming — same RNG paths at init, same checkpoint
-    layout)."""
+    has the param tree of two `nn.Conv` whichever way it computes (the
+    submodule is named `Conv_0`/`Conv_1`, matching flax's auto-naming —
+    same RNG paths at init, same checkpoint layout)."""
 
     features: int
 
@@ -248,7 +253,11 @@ class _ConvParams(nn.Module):
 
 
 class ResidualBlock(nn.Module):
-    """Two 3x3 convs with a skip connection (analog `haiku_nets.py:79-101`).
+    """Two 3x3 convs with a skip connection (analog `haiku_nets.py:79-101`):
+    relu, conv, relu, conv, add, the convolutions computed by
+    `ops/conv_packed.py` from the parameters of two `nn.Conv` (with
+    `packed_gradient` their weight gradient is the W-packed one,
+    everything else `nn.Conv`'s).
 
     With `fused=True` the whole block — relu, both convs, the skip add —
     runs as one Pallas kernel per image (`ops/conv_pallas.py`), keeping
@@ -259,21 +268,29 @@ class ResidualBlock(nn.Module):
     channels: int
     dtype: jnp.dtype = jnp.float32
     fused: bool = False
+    packed_gradient: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
+        k1, b1 = _ConvParams(self.channels, name="Conv_0")(x)
+        k2, b2 = _ConvParams(self.channels, name="Conv_1")(x)
         if self.fused:
             from torched_impala_tpu.ops.conv_pallas import (
                 fused_residual_block,
             )
 
-            k1, b1 = _ConvParams(self.channels, name="Conv_0")(x)
-            k2, b2 = _ConvParams(self.channels, name="Conv_1")(x)
             return fused_residual_block(x.astype(self.dtype), k1, b1, k2, b2)
-        out = nn.relu(x)
-        out = nn.Conv(self.channels, (3, 3), dtype=self.dtype)(out)
-        out = nn.relu(out)
-        out = nn.Conv(self.channels, (3, 3), dtype=self.dtype)(out)
+
+        def conv(x, kernel, bias):
+            # `promote_dtype`: what `nn.Conv(dtype=...)` does with its
+            # input and parameters.
+            return conv3x3(
+                *promote_dtype(x, kernel, bias, dtype=self.dtype),
+                packed=self.packed_gradient,
+            )
+
+        out = conv(nn.relu(x), k1, b1)
+        out = conv(nn.relu(out), k2, b2)
         return x + out
 
 
@@ -295,6 +312,11 @@ class AtariDeepTorso(nn.Module):
     # the user's: `resolve_kernels` clears it for a learner on a mesh of
     # several TPU devices, where a Mosaic kernel cannot be partitioned.
     pool_kernel: bool = True
+    # The residual blocks' W-packed weight gradient (ops/conv_packed.py).
+    # Not a choice of the user's either: `resolve_kernels` sets it for a
+    # step that runs on a TPU; off it the torso traces `nn.Conv`'s own
+    # program.
+    packed_gradients: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -312,8 +334,27 @@ class AtariDeepTorso(nn.Module):
             x = max_pool(x, kernel=self.pool_kernel)
             for _ in range(self.blocks_per_section):
                 x = ResidualBlock(
-                    channels, dtype=self.dtype, fused=self.fused_blocks
+                    channels,
+                    dtype=self.dtype,
+                    fused=self.fused_blocks,
+                    packed_gradient=self.packed_gradients,
                 )(x)
         x = nn.relu(x)
         x = x.reshape(*x.shape[:-3], -1)
         return nn.relu(nn.Dense(self.hidden_size, dtype=self.dtype)(x))
+
+    def packed_convs(self, obs_shape: Sequence[int]) -> list:
+        """`(C, H, W, p)` of each residual-block convolution over
+        `[..., H, W, C]` observations whose weight gradient is the packed
+        one (ops/conv_packed.py), from the shapes alone: a section's
+        pool halves H and W, rounded up."""
+        if self.fused_blocks or not self.packed_gradients:
+            return []
+        h, w = obs_shape[-3:-1]
+        taken = []
+        for channels in self.channel_sections:
+            h, w = -(-h // 2), -(-w // 2)
+            p = pack_width(w, channels)
+            if p > 1:
+                taken += [(channels, h, w, p)] * (2 * self.blocks_per_section)
+        return taken

@@ -295,7 +295,8 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
     Returns `(agent, loss, resolved)`: `loss.vtrace_implementation` is
     never 'auto' afterwards, and `resolved` names what was chosen
     (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`, `devices`,
-    `why`).
+    `why`; a `Learner` adds `packed_convs`, which needs the observations'
+    shape).
 
     On one device the choice is per platform: the Pallas kernels on a
     TPU, the scan elsewhere (the model's own kernels pick compiled vs
@@ -305,8 +306,11 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
     XLA's max-pool, einsum attention — because Mosaic kernels cannot be
     auto-partitioned and nothing here wraps them per shard yet. That is decided HERE, by
     construction, and logged once; it is never recovered from a failed
-    lowering. The param tree is identical across implementations
-    (tests/test_pallas_lstm.py), so actors may keep the fused net."""
+    lowering. On a TPU, one device or a mesh, a deep torso's residual
+    blocks take the W-packed weight gradient (ops/conv_packed.py: XLA
+    convolutions, which partition like any other). The param tree is
+    identical across implementations (tests/test_pallas_lstm.py), so
+    actors may keep the fused net."""
     devices = (
         list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
     )
@@ -339,6 +343,8 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
         )
     elif impl == "auto":
         impl = vtrace_ops.resolve_implementation("auto", devices)
+    if platform == "tpu" and hasattr(net.torso, "packed_gradients"):
+        net = net.clone(torso=net.torso.clone(packed_gradients=True))
     kind = net._core_kind()
     resolved = {
         "vtrace": impl,
@@ -558,6 +564,20 @@ class Learner:
         # — see utils/checkpoint.py for the determinism story.
         self._rng = rng
         self._params = agent.init_params(rng, jnp.asarray(example_obs))
+        # Which of the torso's convolutions take the packed weight
+        # gradient in this learner's step, `(C, H, W, p)` each: none off
+        # a TPU (ops/conv_packed.py).
+        packed_convs = getattr(agent.net.torso, "packed_convs", None)
+        self.kernels["packed_convs"] = (
+            packed_convs(np.shape(example_obs)) if packed_convs else []
+        )
+        if self.kernels["packed_convs"]:
+            import logging
+
+            logging.getLogger(__name__).info(
+                "learner packed weight gradients (C, H, W, p): %s",
+                self.kernels["packed_convs"],
+            )
         self._opt_state = optimizer.init(self._params)
         self._popart_state = (
             popart_ops.init(config.popart.num_values)
