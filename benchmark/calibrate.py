@@ -91,6 +91,7 @@ def main(argv=None, probe=None, root: str = ROOT) -> int:
         row["sound"] = check.compare(sound, ref, decay, prep.net.leaf_groups)
         row["losses"] = {"program": sound["losses"], "reference": ref["losses"]}
         row["grad_norm_unclipped"] = ref["grad_norm_unclipped"]
+        del sound  # a record is 5 bytes a parameter: two at a time from here
 
         def in_place(prep=prep, **planted) -> dict:
             rec = check.reference_record(prep, batches, **planted)
@@ -117,7 +118,7 @@ def main(argv=None, probe=None, root: str = ROOT) -> int:
         row["seconds"] = {"prepare": t_prep, "all": time.monotonic() - t0}
         out["seeds"].append(row)
         print(json.dumps(row), flush=True)
-        del prep, sound, ref, batches
+        del prep, ref, batches
         gc.collect()
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "w") as f:

@@ -59,10 +59,22 @@ configuration states apart: `.torso` (bfloat16 in both presets) and `.core`
 T=100 amplifies the torso's rounding on some seeds (PERF.md section 2); the
 heads' does not. `.median_leaf`, of the whole model or of a part, is the
 steady reading where the worst leaf is one odd leaf's noise.
+
+Host memory. A record holds, by leaf name, only what `compare` reads
+element by element: the first step's second moments or gradient (float32)
+and which way that step moved every element (one byte); the three steps'
+change is kept as its norm by leaf. For a network of P parameters that is
+5 P bytes a side, through the window and after it, and every tree of the
+device is read one leaf at a time (`step_signs`, `change_norms`), as
+`compare` goes through the leaves one at a time: about twice the largest
+leaf in float64 beside the records. Before PR 33 the two records held eight
+float32 trees and `compare` built five float64 ones beside them, 72 P bytes:
+a network of 0.4 billion parameters ended on the host's 40 GiB.
 """
 
 from __future__ import annotations
 
+import collections.abc
 import math
 
 import numpy as np
@@ -70,23 +82,48 @@ import numpy as np
 NEGLIGIBLE_GRADIENT = 1e-3  # of the median leaf's norm
 
 
-def _norms(tree) -> dict:
+def leaves(tree) -> dict:
+    """{leaf name: leaf} of a tree, in the tree's own order."""
     import jax
 
     return {
-        jax.tree_util.keystr(path): float(
-            np.sqrt(np.sum(np.square(np.asarray(leaf, np.float64))))
-        )
+        jax.tree_util.keystr(path): leaf
         for path, leaf in jax.tree_util.tree_leaves_with_path(tree)
     }
 
 
-def _diff(a, b):
-    import jax
+def _norm(x) -> float:
+    return float(np.sqrt(np.sum(np.square(np.asarray(x, np.float64)))))
 
-    return jax.tree.map(
-        lambda x, y: np.asarray(x, np.float64) - np.asarray(y, np.float64), a, b
-    )
+
+def _host_pairs(start: dict, after: dict):
+    """(name, host leaf of `start`, host leaf of `after`), one pair of
+    leaves on the host at a time; both are {name: device array}."""
+    from benchmark import program
+
+    if start.keys() != after.keys():
+        raise ValueError(f"leaves differ: {sorted(set(start) ^ set(after))}")
+    for name, leaf in start.items():
+        yield name, program.host(leaf), program.host(after[name])
+
+
+def step_signs(start: dict, after: dict) -> dict:
+    """By leaf, which way a step moved every element: the sign of
+    `after - start` as one byte (-1, 0, 1; a NaN reads 0)."""
+    return {
+        name: np.greater(b, a).view(np.int8) - np.less(b, a).view(np.int8)
+        for name, a, b in _host_pairs(start, after)
+    }
+
+
+def change_norms(start: dict, after: dict) -> dict:
+    """By leaf, the norm of `after - start`, worked out in float64."""
+    norms = {}
+    for name, a, b in _host_pairs(start, after):
+        change = np.array(b, np.float64)
+        change -= a  # float32 to float64 is exact: float64(b) - float64(a)
+        norms[name] = _norm(change)
+    return norms
 
 
 def leaf_gaps(prog: dict, ref: dict) -> dict:
@@ -109,41 +146,32 @@ def _worst(gaps: dict, keep) -> tuple:
     return worst, where
 
 
-def shape_gaps(magnitude, grads, floor: float) -> dict:
-    """By leaf, the distance between the program's element magnitudes and
-    the reference's, each scaled to unit norm; a leaf whose reference norm
-    is under `floor` counts in that proportion. All zeros read 1."""
-    import jax
-
-    def gap(m, g):
-        r = np.abs(np.asarray(g, np.float64))
-        nm, nr = np.linalg.norm(m), np.linalg.norm(r)
-        unit_m = m / nm if nm > 0 else m
-        unit_r = r / nr if nr > 0 else r
-        return float(np.linalg.norm(unit_m - unit_r) * nr / max(nr, floor, 1e-30))
-
-    gaps = jax.tree.map(gap, magnitude, grads)
-    return {
-        jax.tree_util.keystr(path): v
-        for path, v in jax.tree_util.tree_leaves_with_path(gaps)
-    }
+def shape_gap(magnitude, grad, floor: float) -> float:
+    """One leaf: the distance between the program's element magnitudes
+    (float64; overwritten) and the reference's gradient, each scaled to
+    unit norm; a leaf whose reference norm is under `floor` counts in that
+    proportion. All zeros read 1."""
+    m = magnitude
+    r = np.array(grad, np.float64)
+    np.abs(r, out=r)
+    nm, nr = np.linalg.norm(m), np.linalg.norm(r)
+    if nm > 0:
+        m /= nm
+    if nr > 0:
+        r /= nr
+    m -= r
+    return float(np.linalg.norm(m) * nr / max(nr, floor, 1e-30))
 
 
-def wrong_way(d_prog, d_ref, grads) -> dict:
-    """By leaf, the share of the reference's first gradient's energy (sum
+def wrong_way(moved_prog, moved_ref, grad) -> float:
+    """One leaf: the share of the reference's first gradient's energy (sum
     of squares) that lies on elements which the program moved against the
-    reference, or not at all."""
-    import jax
-
-    def share(dp, dr, g):
-        energy = np.square(np.asarray(g, np.float64))
-        return float(np.sum(energy * (dp * dr <= 0.0)) / max(np.sum(energy), 1e-300))
-
-    shares = jax.tree.map(share, d_prog, d_ref, grads)
-    return {
-        jax.tree_util.keystr(path): v
-        for path, v in jax.tree_util.tree_leaves_with_path(shares)
-    }
+    reference, or not at all; `moved_*` are `step_signs` of the first step."""
+    energy = np.array(grad, np.float64)
+    np.square(energy, out=energy)
+    total = np.sum(energy)
+    energy *= moved_prog * moved_ref <= 0
+    return float(np.sum(energy) / max(total, 1e-300))
 
 
 def popart_gap(program: dict, reference: dict) -> float:
@@ -164,48 +192,44 @@ def compare(
     program: dict, reference: dict, rmsprop_decay: float, leaf_groups
 ) -> dict:
     """The numbers, from two records in the program's leaf names;
-    `leaf_groups` is the network file's.
+    `leaf_groups` is the network file's. One leaf at a time: what is built
+    of a leaf is reduced to that leaf's scalars and dropped.
 
-    program:   losses [3], params0, params1 and nu1 (parameters and second
-               moments after step 1), params3, popart0 and popart1 (PopArt's
+    program:   losses [3]; by leaf name nu1 (second moments after step 1),
+               moved1 (`step_signs` of step 1) and delta3 (`change_norms`
+               of the three steps); popart0 and popart1 (PopArt's
                statistics at the start and after step 1; None with one task).
     reference: the same with grads1 (clipped) for nu1, and loss_scales."""
-    import jax
-
     numbers, where = {}, {}
     for i, (lp, lr, scale) in enumerate(
         zip(program["losses"], reference["losses"], reference["loss_scales"])
     ):
         numbers[f"loss_gap_step{i + 1}"] = abs(lp - lr) / max(scale, 1e-30)
-    g_ref = _norms(reference["grads1"])
+    grads = reference["grads1"]
+    g_ref = {name: _norm(g) for name, g in grads.items()}
     g_floor = float(np.median(list(g_ref.values())))
     moving = [k for k, v in g_ref.items() if v >= NEGLIGIBLE_GRADIENT * g_floor]
-    # every element's magnitude, from RMSProp's second moments after a step
-    magnitude = jax.tree.map(
-        lambda n: np.sqrt(
-            np.maximum(np.asarray(n, np.float64), 0.0) / (1.0 - rmsprop_decay)
-        ),
-        program["nu1"],
-    )
-    g_prog = _norms(magnitude)
-    d_prog = _diff(program["params3"], program["params0"])
-    d_ref = _diff(reference["params3"], reference["params0"])
-    d_norm = _norms(d_ref)
+    g_prog, elem, wrong = {}, {}, {}
+    for name, g in grads.items():
+        # every element's magnitude, from RMSProp's second moments after a step
+        magnitude = np.array(program["nu1"][name], np.float64)
+        np.maximum(magnitude, 0.0, out=magnitude)
+        magnitude /= 1.0 - rmsprop_decay
+        np.sqrt(magnitude, out=magnitude)
+        g_prog[name] = _norm(magnitude)
+        elem[name] = shape_gap(magnitude, g, g_floor)
+        del magnitude  # before the next arrays of this leaf's size
+        wrong[name] = wrong_way(
+            program["moved1"][name], reference["moved1"][name], g
+        )
     per_leaf = {
         "grad_norm_gap": (leaf_gaps(g_prog, g_ref), list(g_ref)),
-        "grad_elem_gap": (
-            shape_gaps(magnitude, reference["grads1"], g_floor),
-            list(g_ref),
-        ),
-        "delta_norm_gap": (leaf_gaps(_norms(d_prog), d_norm), moving),
-        "update_wrong_way": (
-            wrong_way(
-                _diff(program["params1"], program["params0"]),
-                _diff(reference["params1"], reference["params0"]),
-                reference["grads1"],
-            ),
+        "grad_elem_gap": (elem, list(g_ref)),
+        "delta_norm_gap": (
+            leaf_gaps(program["delta3"], reference["delta3"]),
             moving,
         ),
+        "update_wrong_way": (wrong, moving),
     }
     if reference["popart0"] is not None:
         numbers["popart_gap"] = popart_gap(program, reference)
@@ -273,7 +297,6 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
     from benchmark import program, reference as ref
 
     config, net = prep.config, prep.net
-    to_program = net.to_program_params
     if dtypes is None:
         dtypes = ("float32",) * len(net.stated_dtypes(config))
     dtypes = tuple(jnp.dtype(d) for d in dtypes)
@@ -281,12 +304,12 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
     params = prep.weights
     nu, popart = ref.init_state(params, prep.popart)
     block = int(config["reference_block_rows"])
-    record = {
-        "losses": [],
-        "loss_scales": [],
-        "params0": program.host(to_program(params)),
-        "popart0": prep.popart,
-    }
+
+    def named(tree) -> dict:  # the reference's tree by the program's leaf names
+        return leaves(net.to_program_params(tree))
+
+    start = named(prep.weights)
+    record = {"losses": [], "loss_scales": [], "popart0": prep.popart}
     network = (net.forward, net.sizes(config))
     for k, b in enumerate(batches):
         batch = ref.Batch(
@@ -302,29 +325,42 @@ def reference_record(prep, batches: list, rows=None, dtypes=None) -> dict:
         record["losses"].append(out.loss)
         record["loss_scales"].append(out.loss_scale)
         if k == 0:
-            record["grads1"] = program.host(to_program(out.grads))
+            record["grads1"] = program.host(named(out.grads))
             record["grad_norm_unclipped"] = out.grad_norm_unclipped
-            record["params1"] = program.host(to_program(params))
+            record["moved1"] = step_signs(start, named(params))
             record["popart1"] = popart and {
                 k: np.asarray(v) for k, v in popart.items()
             }
-    record["params3"] = program.host(to_program(params))
+        del out  # its gradient leaves the device before the next step's
+    record["delta3"] = change_norms(start, named(params))
     return record
 
 
-def as_program_record(ref_record: dict, rmsprop_decay: float) -> dict:
-    """A reference record put in the program's place: RMSProp's second
-    moments after one step are (1 - decay) * g^2."""
-    import jax
+class _SecondMoments(collections.abc.Mapping):
+    """RMSProp's second moments after one step, (1 - decay) * g^2, worked
+    out from the first gradient a leaf at a time as each is asked for."""
 
+    def __init__(self, grads: dict, rmsprop_decay: float):
+        self._grads, self._decay = grads, rmsprop_decay
+
+    def __getitem__(self, name):
+        return (1.0 - self._decay) * np.square(self._grads[name])
+
+    def __iter__(self):
+        return iter(self._grads)
+
+    def __len__(self):
+        return len(self._grads)
+
+
+def as_program_record(ref_record: dict, rmsprop_decay: float) -> dict:
+    """A reference record put in the program's place; it shares the
+    reference record's leaves and copies none."""
     return {
         "losses": ref_record["losses"],
-        "params0": ref_record["params0"],
-        "params1": ref_record["params1"],
-        "params3": ref_record["params3"],
+        "moved1": ref_record["moved1"],
+        "delta3": ref_record["delta3"],
         "popart0": ref_record["popart0"],
         "popart1": ref_record["popart1"],
-        "nu1": jax.tree.map(
-            lambda g: (1.0 - rmsprop_decay) * np.square(g), ref_record["grads1"]
-        ),
+        "nu1": _SecondMoments(ref_record["grads1"], rmsprop_decay),
     }

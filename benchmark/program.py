@@ -225,20 +225,26 @@ def queue_closed_error():
 
 
 def host(tree: Any) -> Any:
-    """Owning host copies of a tree of device arrays."""
+    """Owning host copies of a tree of device arrays (or of one). Each is
+    read through a second handle on the array's buffer: a `jax.Array` keeps
+    the host copy it was first read into for as long as it lives, and the
+    reference's weights live to the end of the run."""
     import jax
 
-    return jax.tree.map(lambda x: np.array(x, copy=True), tree)
+    return jax.tree.map(
+        lambda x: np.array(x.addressable_data(0), copy=True), tree
+    )
 
 
 def read_state(learner) -> dict:
-    """Host copies of what the check compares: parameters, RMSProp's
-    second moments (first link of optax's `rmsprop` chain) and PopArt's
-    statistics, in the program's leaf names."""
+    """What the check compares, in the program's leaf names: parameters and
+    RMSProp's second moments (first link of optax's `rmsprop` chain) as
+    they stand on the device, for the check to read a leaf at a time, and
+    host copies of PopArt's statistics."""
     popart = learner.popart_state
     return {
-        "params": host(learner.params),
-        "nu": host(learner.opt_state[0].nu),
+        "params": learner.params,
+        "nu": learner.opt_state[0].nu,
         "popart": (
             {"mu": np.array(popart.mu), "nu": np.array(popart.nu)}
             if popart != ()

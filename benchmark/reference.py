@@ -232,9 +232,12 @@ def _popart_update(pa, cnt, tot, tot_sq, hp):
     return {"mu": mu, "nu": nu}
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(1, 2))
 def _apply(params, nu, grads, k, hp):
-    """Clip by global norm, RMSProp, linear anneal; step index `k` from 0."""
+    """Clip by global norm, RMSProp, linear anneal; step index `k` from 0.
+    The second moments and the gradient are updated in their own buffers
+    (8 bytes a parameter less on the device); the parameters are not, since
+    the first step's are the weights every later reading starts from."""
     gnorm = jnp.sqrt(
         sum(jnp.sum(jnp.square(g)) for g in jax.tree.leaves(grads))
     )

@@ -295,11 +295,13 @@ STEP = {"temp_bytes": 4000, "argument_bytes": 170, "output_bytes": 3, "alias_byt
         ([_Chip(1000, 4000, 4000)], None, 5000),
         # the scratch no longer stood when the window closed
         ([_Chip(1000, 4000, 2500)], STEP, None),
-        # the reserved peak is not this step's temporaries
+        # the reserved peak is not this step's temporaries: far over them,
+        # and so far under that it is not this step (2.5% under is: since
+        # PR 33 that side is read and admitted, test_benchmark_check_at_size)
         ([_Chip(1000, 9000, 9000)], STEP, None),
-        ([_Chip(1000, 3900, 3900)], STEP, None),
+        ([_Chip(1000, 2900, 2900)], STEP, None),
     ],
-    ids=["fullest_chip", "no_step_at_hand", "scratch_released", "far_over", "under"],
+    ids=["fullest_chip", "no_step_at_hand", "scratch_released", "far_over", "far_under"],
 )
 def test_memory_peak_is_buffers_plus_scratch_and_is_cross_checked(chips, step, want):
     from benchmark import driver
