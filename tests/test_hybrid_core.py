@@ -1,0 +1,516 @@
+"""The hybrid temporal core (models/hybrid.py) on the CPU at a small size
+(d_model 64, d_inner 128, d_state 16, 4 query heads on 2 key/value heads,
+window 4, full cache 8, T 11, B 2), float32: against the plain forward
+pass of the benchmark's network file (outputs and gradients), step mode
+against the unroll, the mixed carry through the learner's batcher, the
+actor and the server."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from torched_impala_tpu import configs
+from torched_impala_tpu.models.hybrid import HybridCore, HybridCoreState
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+T, B = 11, 2
+SMALL = dict(
+    hybrid_d_model=64, hybrid_heads=4, hybrid_kv_heads=2, hybrid_head_dim=16,
+    hybrid_window=4, hybrid_full_cache=8, hybrid_d_intermediate=128,
+    hybrid_d_inner=128, hybrid_d_state=16, hybrid_dt_rank=4,
+    hybrid_dtype="float32", compute_dtype="float32",
+)
+
+
+def small_cfg(**more):
+    return dataclasses.replace(
+        configs.REGISTRY["pong_phi4flash"], unroll_length=T - 1,
+        batch_size=B, **SMALL, **more,
+    )
+
+
+@pytest.fixture(scope="module")
+def network():
+    """The benchmark's network file and its configuration at the test's
+    sizes: the plain float32 forward pass."""
+    from benchmark import driver
+
+    spec = driver.Spec(ROOT)
+    config = spec.config("pong_phi4flash_core")
+    config["model"].update(
+        hidden_size=64, intermediate_size=128, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, sliding_window=4, full_cache=8,
+        d_inner=128, d_state=16, dt_rank=4,
+    )
+    config.update(batch_size=B, unroll_length=T - 1)
+    return spec.network(config), config
+
+
+def _inputs(net, config, seed, firsts, other_episode=True):
+    rng = np.random.default_rng(seed)
+    obs = rng.integers(0, 256, size=(T, B, 84, 84, 4), dtype=np.uint8)
+    first = np.zeros((T, B), bool)
+    for t, b in firsts:
+        first[t, b] = True
+    parts = net.draw_state(rng, B, config)
+    state = [np.concatenate(list(p), axis=0) for p in parts]
+    if other_episode:
+        # the running episode holds the newest 3 slots of the full cache
+        # (and of the window's), the rest is the episode before it
+        seg = state[11]
+        for slots, width in ((4, 4), (8, 8)):
+            state[slots] = np.where(
+                np.arange(width)[None, :] >= width - 3,
+                seg[:, None], seg[:, None] - 1,
+            ).astype(np.int32)
+    return obs, first, tuple(state)
+
+
+RESETS = {
+    "none": (),
+    "first_position": ((0, 0), (0, 1)),
+    "middle_position": ((5, 0),),
+    "last_position": ((T - 1, 1),),
+    "first_middle_last": ((0, 0), (5, 0), (T - 1, 0), (6, 1)),
+    # window 4: the query at step 7 sees steps 4..7; a reset at step 4 is
+    # exactly one window behind it, one at step 3 just outside
+    "one_window_behind_a_query": ((4, 0), (3, 1)),
+    "two_in_one_window": ((5, 0), (7, 0)),
+    "every_position": tuple((t, 0) for t in range(T)),
+}
+
+
+@pytest.mark.parametrize("resets", sorted(RESETS))
+def test_core_matches_the_network_files_forward(network, resets):
+    """Outputs and every parameter's gradient, with the caches partly
+    from another episode."""
+    net, config = network
+    obs, first, state = _inputs(net, config, 3, RESETS[resets])
+    weights = net.init_params(17, config)
+    agent = configs.make_agent(small_cfg())
+    params = net.to_program_params(weights)
+    carried = jax.tree.unflatten(
+        jax.tree.structure(agent.initial_state(B)), state
+    )
+    rng = np.random.default_rng(5)
+    wl = jnp.asarray(rng.standard_normal((T, B, 6)), jnp.float32)
+    wv = jnp.asarray(rng.standard_normal((T, B, 1)), jnp.float32)
+
+    def ours(p):
+        out, _ = agent.unroll(p, obs, first, carried)
+        return out.policy_logits, out.values
+
+    def theirs(w):
+        with jax.default_matmul_precision("highest"):
+            return net.forward(net.sizes(config), w, obs, first, state)
+
+    def scalar(fn, p):
+        logits, values = fn(p)
+        return jnp.sum(logits * wl) + jnp.sum(values * wv)
+
+    got, want = ours(params), theirs(weights)
+    np.testing.assert_allclose(got[0], want[0], rtol=0, atol=1e-5)
+    np.testing.assert_allclose(got[1], want[1], rtol=0, atol=1e-5)
+    g_ours = jax.grad(lambda p: scalar(ours, p))(params)
+    g_theirs = net.to_program_params(
+        jax.grad(lambda w: scalar(theirs, w))(weights)
+    )
+    flat_ours = jax.tree_util.tree_leaves_with_path(g_ours)
+    flat_theirs = dict(jax.tree_util.tree_leaves_with_path(g_theirs))
+    assert len(flat_ours) == len(flat_theirs)
+    for path, g in flat_ours:
+        w = flat_theirs[path]
+        # a gradient adds up 22 positions' terms through four blocks in
+        # another order than the plain pass: 1e-5 on the outputs, 5e-5 of
+        # the leaf's largest element here (the widest read 2e-5)
+        scale = float(jnp.max(jnp.abs(w))) + 1e-6
+        np.testing.assert_allclose(
+            g / scale, w / scale, rtol=0, atol=5e-5,
+            err_msg=jax.tree_util.keystr(path),
+        )
+
+
+@pytest.mark.parametrize("kernel", ["pallas", "einsum"])
+def test_attention_kernel_and_written_out_mask_agree(network, kernel):
+    """The fused attention (interpreted here) and the mask written out
+    give the network file's outputs alike; so does the scan's kernel."""
+    net, config = network
+    obs, first, state = _inputs(net, config, 4, RESETS["first_middle_last"])
+    weights = net.init_params(19, config)
+    agent = configs.make_agent(small_cfg(transformer_dense_kernel=kernel))
+    assert dict(agent.net.hybrid)["attention_kernel"] == kernel
+    carried = jax.tree.unflatten(
+        jax.tree.structure(agent.initial_state(B)), state
+    )
+    out, _ = agent.unroll(net.to_program_params(weights), obs, first, carried)
+    with jax.default_matmul_precision("highest"):
+        want, _ = net.forward(net.sizes(config), weights, obs, first, state)
+    np.testing.assert_allclose(out.policy_logits, want, rtol=0, atol=1e-5)
+
+
+# Step mode sees what the caches hold: the full layer 8 positions, where
+# the unroll sees its cache and the unroll. The two agree as long as an
+# episode's history fits the full cache, so these keep every episode at 8
+# steps or fewer (3 of them in the cache at the start).
+STEP_RESETS = {
+    "middle": ((5, 0), (5, 1)),
+    "first_middle_last": ((0, 0), (5, 0), (T - 1, 0), (0, 1), (6, 1)),
+    "one_window_behind_a_query": ((4, 0), (3, 1)),
+}
+
+
+@pytest.mark.parametrize("resets", sorted(STEP_RESETS))
+def test_step_mode_equals_the_unroll(network, resets):
+    """T=1, eleven times through the carry: outputs and the final state
+    (scan states, convolution windows, both caches, counters)."""
+    net, config = network
+    obs, first, state = _inputs(net, config, 6, STEP_RESETS[resets])
+    agent = configs.make_agent(small_cfg())
+    params = net.to_program_params(net.init_params(23, config))
+    carried = jax.tree.unflatten(
+        jax.tree.structure(agent.initial_state(B)), state
+    )
+    out, final = agent.unroll(params, obs, first, carried)
+    step = jax.jit(
+        lambda s, o, f: agent.net.apply(params, o, f, s, unroll=False)
+    )
+    s, logits = carried, []
+    for t in range(T):
+        o, s = step(s, obs[t], first[t])
+        logits.append(o.policy_logits)
+    np.testing.assert_allclose(
+        np.stack(logits), out.policy_logits, rtol=0, atol=2e-5
+    )
+    assert isinstance(s, HybridCoreState)
+    for name, a, b in zip(s._fields, s, final):
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-5, err_msg=name)
+
+
+def test_the_carry_of_a_fresh_state_and_its_size():
+    core = HybridCore(parent=None)
+    one = jax.eval_shape(lambda: core.initial_state(1))
+    assert one.conv.shape == (1, 2, 3, 5120)
+    assert one.ssm.shape == (1, 2, 16, 5120)
+    assert one.k_win.shape == (1, 1, 512, 1280)
+    assert one.k_full.shape == (1, 1, 2048, 1280)
+    assert one.win_seg.shape == (1, 512) and one.full_pos.shape == (1, 2048)
+    # 2 x (61 KB + 328 KB) + 2 x 2.6 MB + 2 x 10.5 MB + counters
+    assert core.state_bytes_per_row() == 4 * (
+        2 * (3 * 5120 + 5120 * 16) + 2 * 512 * 1280 + 2 * 2048 * 1280
+        + 2 * 512 + 2 * 2048 + 2
+    )
+    fresh = HybridCore(parent=None, window=4, full_cache=8).initial_state(3)
+    assert (np.asarray(fresh.win_seg) == -1).all()
+    assert (np.asarray(fresh.full_seg) == -1).all()
+    with pytest.raises(ValueError, match="unknown layer kinds"):
+        HybridCore(parent=None, layers=("mamba", "gmu")).initial_state(1)
+
+
+def test_the_learners_batcher_stacks_the_mixed_carry_leaf_by_leaf(network):
+    """Two unrolls through `Learner.enqueue` -> batcher -> one step; the
+    batch that reaches the device holds every leaf of both carries, and
+    the gauges read the carry's size and the episode starts."""
+    from torched_impala_tpu.runtime import Learner
+    from torched_impala_tpu.runtime.learner import stack_trajectories
+    from torched_impala_tpu.runtime.types import Trajectory
+    from torched_impala_tpu.telemetry.registry import Registry
+
+    net, config = network
+    cfg = small_cfg()
+    agent = configs.make_agent(cfg)
+    obs, first, state = _inputs(net, config, 8, ((0, 0), (4, 0), (9, 1)))
+    structure = jax.tree.structure(agent.initial_state(1))
+    rng = np.random.default_rng(0)
+    trajs = [
+        Trajectory(
+            obs=obs[:, i], first=first[:, i],
+            actions=rng.integers(0, 6, T - 1).astype(np.int32),
+            behaviour_logits=rng.standard_normal((T - 1, 6)).astype(np.float32),
+            rewards=rng.standard_normal(T - 1).astype(np.float32),
+            cont=np.ones(T - 1, np.float32),
+            agent_state=jax.tree.unflatten(
+                structure, [leaf[i : i + 1] for leaf in state]
+            ),
+            task=0,
+        )
+        for i in range(B)
+    ]
+    stacked = stack_trajectories(trajs)
+    assert isinstance(stacked.agent_state, HybridCoreState)
+    for leaf, want in zip(stacked.agent_state, state):
+        np.testing.assert_array_equal(leaf, want)
+    reg = Registry()
+    learner = Learner(
+        agent=agent, optimizer=configs.make_optimizer(cfg),
+        config=configs.make_learner_config(cfg),
+        example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
+        telemetry=reg,
+    )
+    assert learner.kernels["attention"] == "einsum"  # no TPU here
+    assert learner.kernels["selective_scan"] == "xla_scan"
+    learner.start()
+    try:
+        for t in trajs:
+            learner.enqueue(t)
+        logs = learner.step_once(timeout=300)
+        learner.drain()
+        assert np.isfinite(float(logs["total_loss"]))
+    finally:
+        learner.stop()
+    gauges = {m.name: m.value for m in reg.metrics() if hasattr(m, "value")}
+    assert gauges["core/resets_in_batch"] == 3
+    assert gauges["core/state_bytes_per_row"] == (
+        agent.net._hybrid_core(bound=False).state_bytes_per_row()
+    )
+
+
+def test_other_cores_have_no_core_gauges():
+    from torched_impala_tpu.runtime import Learner
+    from torched_impala_tpu.telemetry.registry import Registry
+
+    cfg = configs.REGISTRY["cartpole"]
+    reg = Registry()
+    learner = Learner(
+        agent=configs.make_agent(cfg), optimizer=configs.make_optimizer(cfg),
+        config=configs.make_learner_config(cfg),
+        example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
+        telemetry=reg,
+    )
+    learner.stop()
+    assert not [m.name for m in reg.metrics() if m.name.startswith("core/")]
+    assert learner.kernels["selective_scan"] is None
+
+
+def test_the_preset_states_the_published_widths():
+    cfg = configs.REGISTRY["pong_phi4flash"]
+    core = dict(configs.make_agent(cfg).net.hybrid)
+    with open(os.path.join(
+        ROOT, "benchmark", "configs", "pong_phi4flash_core.json"
+    )) as f:
+        stated = json.load(f)
+    assert core["d_model"] == stated["hidden_size"] == 2560
+    assert core["d_intermediate"] == stated["intermediate_size"] == 10240
+    assert core["num_heads"] == stated["num_attention_heads"] == 40
+    assert core["num_kv_heads"] == stated["num_key_value_heads"] == 20
+    assert core["window"] == stated["sliding_window"] == 512
+    assert core["head_dim"] == 64 and core["full_cache"] == 2048
+    assert (core["d_inner"], core["d_state"], core["d_conv"]) == (5120, 16, 4)
+    assert core["dt_rank"] == 160 and core["remat"] is True
+    assert core["layers"] == ("mamba", "window", "mamba", "full")
+    assert jnp.dtype(core["dtype"]) == jnp.bfloat16
+    assert (cfg.unroll_length, cfg.batch_size) == (2047, 2)
+    assert cfg.obs_shape == (84, 84, 4) and cfg.num_actions == 6
+
+
+@pytest.mark.parametrize("forced,want", [("auto", "einsum"),
+                                         ("pallas", "pallas"),
+                                         ("einsum", "einsum")])
+def test_attention_kernel_resolution(forced, want):
+    """Off a TPU 'auto' keeps the written-out mask; on one the hybrid core
+    takes the fused kernel whatever PALLAS_MIN_SCORE_ELEMS says (the small
+    unroll here is far under it)."""
+    cfg = small_cfg(transformer_dense_kernel=forced)
+    assert (T * (4 + T)) < configs.PALLAS_MIN_SCORE_ELEMS
+    assert dict(configs.make_agent(cfg).net.hybrid)["attention_kernel"] == want
+
+
+def _mlp_agent():
+    from torched_impala_tpu.models import Agent, ImpalaNet
+    from torched_impala_tpu.models.torsos import MLPTorso
+
+    return Agent(ImpalaNet(
+        num_actions=3, torso=MLPTorso(hidden_sizes=(16,)), core="hybrid",
+        hybrid=(
+            ("d_model", 32), ("num_heads", 4), ("num_kv_heads", 2),
+            ("head_dim", 8), ("window", 4), ("full_cache", 8),
+            ("d_intermediate", 64), ("d_inner", 128), ("dt_rank", 2),
+        ),
+    ))
+
+
+def test_the_actors_step_threads_the_mixed_carry():
+    """Step mode (T=1) as the actor calls it: positions and episode
+    counters advance, the newest cache slot is the step's own, the scan
+    state moves, and a start resets it."""
+    agent = _mlp_agent()
+    obs = np.random.default_rng(0).normal(size=(3, 5)).astype(np.float32)
+    params = agent.init_params(jax.random.key(1), obs[0])
+    step = lambda first, state: agent.step(  # noqa: E731
+        params, jax.random.key(0), obs, jnp.asarray(first), state
+    )
+    one = step([True] * 3, agent.initial_state(3)).state
+    assert isinstance(one, HybridCoreState)
+    assert np.asarray(one.pos).tolist() == [1, 1, 1]
+    assert np.asarray(one.seg).tolist() == [1, 1, 1]
+    assert (np.asarray(one.win_seg)[:, -1] == 1).all()
+    assert (np.asarray(one.win_seg)[:, :-1] == -1).all()
+    two = step([False, False, True], one).state
+    assert np.asarray(two.pos).tolist() == [2, 2, 2]
+    assert np.asarray(two.seg).tolist() == [1, 1, 2]
+    assert (np.asarray(two.full_seg)[:2, -2:] == 1).all()
+    ssm1, ssm2 = np.asarray(one.ssm), np.asarray(two.ssm)
+    assert np.abs(ssm1).max() > 0
+    assert not np.allclose(ssm2[0], ssm1[0])  # went on from the first step
+    np.testing.assert_allclose(ssm2[2], ssm1[2], atol=1e-6)  # started anew
+
+
+def test_the_mixed_carry_lives_on_the_policy_server():
+    """Per-client slots of the carry, leaf by leaf: a client stepping a
+    sequence through the server gets exactly the actions of a direct
+    `agent.step` loop chaining its own carry (tests/test_serving.py's
+    LSTM case, with scan states, convolution windows and two caches)."""
+    from torched_impala_tpu.runtime.param_store import ParamStore
+    from torched_impala_tpu.serving import InProcessClient, PolicyServer
+    from torched_impala_tpu.serving.registry import VersionRegistry
+    from torched_impala_tpu.telemetry.registry import Registry
+
+    agent = _mlp_agent()
+    seq = np.random.default_rng(3).normal(size=(10, 5)).astype(np.float32)
+    params = agent.init_params(jax.random.key(0), seq[0])
+    store = ParamStore()
+    store.publish(0, params)
+    server = PolicyServer(
+        agent=agent,
+        registry=VersionRegistry.serving_latest(store, telemetry=Registry()),
+        example_obs=np.zeros((5,), np.float32),
+        telemetry=Registry(), max_clients=4, max_batch=2, max_wait_s=0.0,
+    ).start()
+    try:
+        starts = {0, 6}  # the second episode starts inside the sequence
+        ref, state = [], agent.initial_state(1)
+        for t in range(seq.shape[0]):
+            out = agent.step(
+                params, jax.random.key(0), seq[t][None],
+                np.asarray([t in starts]), state,
+            )
+            ref.append(int(np.argmax(np.asarray(out.policy_logits))))
+            state = out.state
+        client = InProcessClient(server)
+        got = [client.act(seq[t], t in starts) for t in range(seq.shape[0])]
+        assert got == ref
+    finally:
+        server.close()
+
+
+@pytest.mark.parametrize("bound", [64 << 20, 8192])
+def test_a_snapshots_copies_are_requested_within_a_bound(
+    network, monkeypatch, bound
+):
+    """The copies of a version to the host stand requested and unread by
+    `SNAPSHOT_COPY_AHEAD_BYTES` at the most (at 1.76 GB a version, all
+    requested at once made the runtime's host side grow without bound):
+    a tree that fits is requested whole as it is queued, a leaf over the
+    bound never; what is published is the same either way, every
+    version, the step's own parameters in bytes of their own."""
+    from torched_impala_tpu.runtime import Learner, learner as learner_mod
+    from torched_impala_tpu.runtime.types import Trajectory
+
+    monkeypatch.setattr(learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", bound)
+    events = []
+    impl = type(jnp.zeros(1))
+    real = impl.copy_to_host_async
+    monkeypatch.setattr(
+        impl, "copy_to_host_async",
+        lambda self: (events.append(("ask", id(self), self.shape)), real(self))[1],
+    )
+    read = learner_mod.owned_array
+    monkeypatch.setattr(
+        learner_mod, "owned_array",
+        lambda leaf: (events.append(("read", id(leaf), leaf.shape)), read(leaf))[1],
+    )
+    net, config = network
+    cfg = small_cfg()
+    agent = configs.make_agent(cfg)
+    obs, first, state = _inputs(net, config, 9, ((4, 0),))
+    structure = jax.tree.structure(agent.initial_state(1))
+    learner = Learner(
+        agent=agent, optimizer=configs.make_optimizer(cfg),
+        config=configs.make_learner_config(cfg),
+        example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
+    )
+    learner.start()
+    try:
+        for _ in range(2):
+            for i in range(B):
+                learner.enqueue(Trajectory(
+                    obs=obs[:, i], first=first[:, i],
+                    actions=np.zeros(T - 1, np.int32),
+                    behaviour_logits=np.zeros((T - 1, 6), np.float32),
+                    rewards=np.ones(T - 1, np.float32),
+                    cont=np.ones(T - 1, np.float32),
+                    agent_state=jax.tree.unflatten(
+                        structure, [leaf[i : i + 1] for leaf in state]
+                    ),
+                    task=0,
+                ))
+            learner.step_once(timeout=300)
+        learner.drain()
+        version, published = learner.param_store.get()
+        assert version == 2 * B * (T - 1)
+        for got, own in zip(
+            jax.tree.leaves(published), jax.tree.leaves(learner.params)
+        ):
+            np.testing.assert_array_equal(got, np.asarray(own))
+            assert got.flags.owndata
+    finally:
+        learner.stop()
+    # three versions (construction's and two steps'), every leaf read once
+    leaves = len(jax.tree.leaves(learner.params))
+    assert sum(e[0] == "read" for e in events) == 3 * leaves
+    unread, most = {}, 0
+    for kind, key, shape in events:
+        if kind == "ask":
+            unread[key] = int(np.prod(shape)) * 4
+        else:
+            unread.pop(key, None)
+        most = max(most, sum(unread.values()))
+    assert 0 < most <= bound and not unread
+    # the core's input projection, 128 KB: requested where it fits
+    assert (("ask", (512, 64)) in {(e[0], e[2]) for e in events}) is (
+        bound > 512 * 64 * 4
+    )
+    if bound == 64 << 20:
+        # a tree that fits: every leaf requested before the first is read
+        assert [e[0] for e in events].index("read") == leaves
+
+
+def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
+    """Unrolls collected in step mode: every trajectory starts from its
+    own env's row of every leaf, and the learner's stacking puts E of them
+    back together; the second cycle starts from the carried state."""
+    from torched_impala_tpu.envs.fake import ScriptedEnv
+    from torched_impala_tpu.runtime import ParamStore, VectorActor
+    from torched_impala_tpu.runtime.learner import stack_trajectories
+
+    steps, envs = 4, 3
+    agent = _mlp_agent()
+    params = agent.init_params(jax.random.key(0), jnp.zeros((4,), jnp.float32))
+    store = ParamStore()
+    store.publish(0, params)
+    pushed = []
+    actor = VectorActor(
+        actor_id=0, envs=[ScriptedEnv(episode_len=3) for _ in range(envs)],
+        agent=agent, param_store=store, enqueue=pushed.append,
+        unroll_length=steps, seed=0,
+    )
+    actor.unroll_and_push()
+    actor.unroll_and_push()
+    assert len(pushed) == 2 * envs
+    fresh = agent.initial_state(1)
+    for traj in pushed:
+        assert isinstance(traj.agent_state, HybridCoreState)
+        for leaf, like in zip(traj.agent_state, fresh):
+            assert leaf.shape == like.shape and leaf.dtype == like.dtype
+    second = pushed[envs:]
+    assert all(int(t.agent_state.pos[0]) == steps for t in second)
+    assert all(np.abs(np.asarray(t.agent_state.ssm)).max() > 0 for t in second)
+    assert all((np.asarray(t.agent_state.full_seg)[0, -steps:] >= 1).all()
+               for t in second)
+    stacked = stack_trajectories(second).agent_state
+    assert stacked.k_full.shape == (envs, 1, 8, 16)
+    assert stacked.conv.shape == (envs, 2, 3, 128)

@@ -296,6 +296,60 @@ def test_windowed_attention(one_chip, dtype):
     assert text.count("tpu_custom_call") >= 3
 
 
+@pytest.mark.parametrize("kind,cache,window", [("window", 512, 512),
+                                               ("full", 2048, None)])
+def test_hybrid_attention(one_chip, kind, cache, window):
+    """The hybrid core's two attention layers at the published widths
+    (`pong_phi4flash`): 40 query heads on 20 key/value heads of 64,
+    bfloat16, B=2, 2,048 positions behind a cache of 512 under a window
+    of 512, and behind a cache of 2,048 with none."""
+    B, T, H, Hkv, dh = 2, 2048, 40, 20, 64
+    S = cache + T
+
+    def loss(q, k, v, seg_q, seg_ctx):
+        return jnp.sum(
+            windowed_attention(
+                q, k, v, seg_q, seg_ctx, cache, None, window,
+                f"attention_{kind}",
+            ).astype(F32)
+        )
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1, 2)),
+        _shape(one_chip, (B, T, H, dh), jnp.bfloat16),
+        _shape(one_chip, (B, S, Hkv, dh), jnp.bfloat16),
+        _shape(one_chip, (B, S, Hkv, dh), jnp.bfloat16),
+        _shape(one_chip, (B, T), jnp.int32),
+        _shape(one_chip, (B, S), jnp.int32),
+    )
+    for part in ("forward", "backward_dq", "backward_dkv"):
+        assert f"attention_{kind}_{part}" in text, part
+
+
+def test_selective_scan(one_chip):
+    """The scan's forward and backward kernels at the published widths:
+    B=2, 2,048 positions, d_inner 5120, d_state 16; the state is never
+    written out per step (that would be 1.3 GB)."""
+    from torched_impala_tpu.ops.selective_scan import selective_scan
+
+    B, T, DI, N = 2, 2048, 5120, 16
+
+    def loss(x, dt, a, b, c, first, s0):
+        y, last = selective_scan(x, dt, a, b, c, first, s0)
+        return jnp.sum(y) + jnp.sum(last)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        _shape(one_chip, (B, T, DI)), _shape(one_chip, (B, T, DI)),
+        _shape(one_chip, (DI, N)), _shape(one_chip, (B, T, N)),
+        _shape(one_chip, (B, T, N)), _shape(one_chip, (B, T), jnp.bool_),
+        _shape(one_chip, (B, DI, N)),
+    ).compile()
+    text = compiled.as_text()
+    assert "selective_scan_forward" in text
+    assert "selective_scan_backward" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 600e6
+
+
 # ---- four chips: the mesh path resolves to XLA by construction ---------
 
 
@@ -337,6 +391,35 @@ def test_mesh_resolves_kernels_to_xla(data_mesh):
             ImpalaLossConfig(vtrace_implementation="pallas"),
             data_mesh,
         )
+
+
+def test_mesh_resolves_the_hybrid_cores_kernels_to_xla(data_mesh):
+    """The hybrid core under a mesh: the written-out attention mask and
+    the scan one step at a time; on one TPU device both kernels."""
+    from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
+    from torched_impala_tpu.runtime.learner import resolve_kernels
+
+    def agent():
+        return Agent(ImpalaNet(
+            num_actions=4, torso=MLPTorso(), core="hybrid",
+            hybrid=(("d_model", 64), ("attention_kernel", "pallas")),
+        ))
+
+    resolved_agent, _, resolved = resolve_kernels(
+        agent(), ImpalaLossConfig(), data_mesh
+    )
+    core = dict(resolved_agent.net.hybrid)
+    assert core["attention_kernel"] == "einsum"
+    assert core["scan_kernel"] is False and core["d_model"] == 64
+    assert resolved["attention"] == "einsum"
+    assert resolved["selective_scan"] == "xla_scan"
+    one = Mesh(
+        np.array(data_mesh.devices.flat[:1]).reshape(1, 1),
+        ("data", "model"),
+    )
+    _, _, resolved = resolve_kernels(agent(), ImpalaLossConfig(), one)
+    assert resolved["attention"] == "pallas"
+    assert resolved["selective_scan"] == "pallas"
 
 
 def test_vtrace_and_lstm_under_data_mesh(data_mesh):

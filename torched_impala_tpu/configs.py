@@ -116,6 +116,30 @@ class ExperimentConfig:
     # measured PALLAS_MIN_SCORE_ELEMS crossover; "pallas"/"einsum" force
     # (the retuning affordance for non-v5e TPU generations).
     transformer_dense_kernel: str = "auto"
+    # core="hybrid" (models/hybrid.py): state-space, window- and
+    # full-attention layers, one kind a layer in `hybrid_layers`, each
+    # followed by a gated MLP. `hybrid_window` is the sliding window and
+    # the window layers' cache length, `hybrid_full_cache` the full
+    # layers'. `hybrid_dtype` is the operands' precision in the core's
+    # matrix products (float32 accumulation; LayerNorm, softmax, the scan
+    # and the residual stream stay float32). `hybrid_remat` recomputes
+    # each block in the backward pass. Attention takes the fused kernel
+    # and the scan its Pallas kernels whenever the step runs on one TPU
+    # device (make_agent; `transformer_dense_kernel` still forces).
+    hybrid_layers: tuple = ("mamba", "window", "mamba", "full")
+    hybrid_d_model: int = 2560
+    hybrid_heads: int = 40
+    hybrid_kv_heads: int = 20
+    hybrid_head_dim: int = 64
+    hybrid_window: int = 512
+    hybrid_full_cache: int = 2048
+    hybrid_d_intermediate: int = 10240
+    hybrid_d_inner: int = 5120
+    hybrid_d_state: int = 16
+    hybrid_d_conv: int = 4
+    hybrid_dt_rank: int = 160
+    hybrid_dtype: str = "bfloat16"
+    hybrid_remat: bool = True
     # Shard the unroll's time axis over this many devices (the 'seq' mesh
     # axis); 0 = off. Combined with dp_devices as a ('data','seq') mesh.
     sp_devices: int = 0
@@ -400,6 +424,41 @@ def make_agent(cfg: ExperimentConfig, mesh=None) -> Agent:
             and score_elems >= PALLAS_MIN_SCORE_ELEMS
             else "einsum"
         )
+    # The hybrid core takes the fused attention whenever it runs on a TPU,
+    # whatever PALLAS_MIN_SCORE_ELEMS says: with 40 query heads an einsum's
+    # scores over (cache + unroll) are 40 x 2048 x 4096 x 4 B = 2.7 GB a
+    # layer at the preset's sizes, and grouped heads and the window live
+    # in the kernel. 'einsum' still forces the written-out mask.
+    on_tpu = resolve_implementation("auto", devices) == "pallas"
+    if cfg.hybrid_dtype not in ("float32", "bfloat16"):
+        raise ValueError(
+            f"unknown hybrid_dtype {cfg.hybrid_dtype!r}; "
+            "expected 'float32' or 'bfloat16'"
+        )
+    hybrid = ()
+    if cfg.core == "hybrid":
+        hybrid = (
+            ("d_model", cfg.hybrid_d_model),
+            ("layers", tuple(cfg.hybrid_layers)),
+            ("num_heads", cfg.hybrid_heads),
+            ("num_kv_heads", cfg.hybrid_kv_heads),
+            ("head_dim", cfg.hybrid_head_dim),
+            ("window", cfg.hybrid_window),
+            ("full_cache", cfg.hybrid_full_cache),
+            ("d_intermediate", cfg.hybrid_d_intermediate),
+            ("d_inner", cfg.hybrid_d_inner),
+            ("d_state", cfg.hybrid_d_state),
+            ("d_conv", cfg.hybrid_d_conv),
+            ("dt_rank", cfg.hybrid_dt_rank),
+            ("dtype", jnp.dtype(cfg.hybrid_dtype)),
+            ("remat", cfg.hybrid_remat),
+            (
+                "attention_kernel",
+                cfg.transformer_dense_kernel
+                if cfg.transformer_dense_kernel != "auto"
+                else ("pallas" if on_tpu else "einsum"),
+            ),
+        )
     transformer = (
         ("d_model", cfg.transformer_d_model),
         ("num_layers", cfg.transformer_layers),
@@ -430,6 +489,7 @@ def make_agent(cfg: ExperimentConfig, mesh=None) -> Agent:
         core=cfg.core,
         lstm_size=cfg.lstm_size,
         transformer=transformer,
+        hybrid=hybrid,
         num_values=cfg.num_tasks,
     )
     return Agent(net)
@@ -835,6 +895,32 @@ PONG_TRANSFORMER = ExperimentConfig(
     total_env_frames=200_000_000,
 )
 
+# The hybrid temporal core at the published widths of
+# Phi-4-mini-flash-reasoning's self-decoder (arXiv:2507.06607; layers
+# 14-17 of its 32: Mamba, window attention, Mamba, full attention) on
+# Pong's shapes: a long-memory agent, few long unrolls a chip. 439M
+# parameters; benchmark/configs/pong_phi4flash_core.json states the cut.
+# Every step publishes a version of 1.76 GB: the host's copy of it, not
+# the chip's 0.2 s step, sets the pace (PERF.md section 6, PR 34).
+PONG_PHI4FLASH = ExperimentConfig(
+    name="pong_phi4flash",
+    env_family="atari",
+    env_id="PongNoFrameskip-v4",
+    obs_shape=(84, 84, 4),
+    obs_dtype="uint8",
+    num_actions=6,
+    model="shallow_cnn",
+    compute_dtype="bfloat16",
+    episodic_life=True,
+    fire_reset=True,
+    core="hybrid",
+    actor_mode="process",
+    num_actors=2,
+    unroll_length=2047,
+    batch_size=2,
+    total_env_frames=200_000_000,
+)
+
 # On-device (Anakin) presets: the whole actor-learner is one XLA program
 # over pure-JAX envs (runtime/anakin.py). batch_size = on-device env count.
 # Same MDPs as their host counterparts (envs/jax_envs.py parity tests), so
@@ -899,6 +985,7 @@ REGISTRY: dict[str, ExperimentConfig] = {
         PROCGEN,
         DMLAB30,
         PONG_TRANSFORMER,
+        PONG_PHI4FLASH,
         CARTPOLE_ANAKIN,
         CATCH_ANAKIN,
         PIXELS_ANAKIN,

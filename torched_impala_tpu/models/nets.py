@@ -64,14 +64,19 @@ class ImpalaNet(nn.Module):
       torso: a Flax module mapping `[N, ...obs]` → `[N, F]` features.
       use_lstm: insert an LSTM(lstm_size) core between torso and heads
         (equivalent to core="lstm"; kept for the reference-parity surface).
-      core: "none" | "lstm" | "transformer" — the temporal core. The
-        transformer core (models/transformer.py) attends causally over the
-        unroll with a sliding-window KV cache as its recurrent state
+      core: "none" | "lstm" | "transformer" | "hybrid" — the temporal
+        core. The transformer core (models/transformer.py) attends causally
+        over the unroll with a sliding-window KV cache as its recurrent state
         (long-context policies; SP-ready, see parallel/ring_attention.py).
+        The hybrid core (models/hybrid.py) stacks state-space, window- and
+        full-attention layers; its carry holds scan states, convolution
+        windows and two lengths of key/value cache.
       lstm_size: LSTM hidden width (reference uses 256, SURVEY.md §1 item 4).
       transformer: TransformerCore hyper-parameters, used when
         core="transformer" (a dict so the module stays hashable; keys are
         TransformerCore fields).
+      hybrid: HybridCore hyper-parameters, used when core="hybrid" (the
+        same kind of tuple; keys are HybridCore fields).
       lstm_impl: "fused" (default) computes the LSTM cell with the
         single-pass Pallas kernel (ops/lstm_pallas.py; interpret mode
         off-TPU), "flax" keeps nn.OptimizedLSTMCell. Both produce a
@@ -87,6 +92,7 @@ class ImpalaNet(nn.Module):
     core: str = "auto"  # "auto" resolves via use_lstm for back-compat
     lstm_size: int = 256
     transformer: tuple = ()  # e.g. (("d_model", 128), ("num_layers", 2))
+    hybrid: tuple = ()  # e.g. (("d_model", 64), ("layers", ("mamba", "full")))
     lstm_impl: str = "fused"
     num_values: int = 1
 
@@ -110,6 +116,15 @@ class ImpalaNet(nn.Module):
         # otherwise try to adopt the child into a scopeless parent).
         return TransformerCore(parent=None, **kwargs)
 
+    def _hybrid_core(self, *, bound: bool):
+        """As `_transformer_core`, for core="hybrid"."""
+        from torched_impala_tpu.models.hybrid import HybridCore
+
+        kwargs = dict(self.hybrid)
+        if bound:
+            return HybridCore(name="hybrid", **kwargs)
+        return HybridCore(parent=None, **kwargs)
+
     def initial_state(self, batch_size: int) -> NetState:
         """Zero recurrent state; a pure function of the config (no params)."""
         kind = self._core_kind()
@@ -119,6 +134,8 @@ class ImpalaNet(nn.Module):
             return self._transformer_core(bound=False).initial_state(
                 batch_size
             )
+        if kind == "hybrid":
+            return self._hybrid_core(bound=False).initial_state(batch_size)
         shape = (batch_size, self.lstm_size)
         return (jnp.zeros(shape, jnp.float32), jnp.zeros(shape, jnp.float32))
 
@@ -156,12 +173,17 @@ class ImpalaNet(nn.Module):
             features = self.torso(obs)
 
         kind = self._core_kind()
-        if kind == "transformer":
-            core = self._transformer_core(bound=True)
+        if kind in ("transformer", "hybrid"):
+            core = (
+                self._transformer_core(bound=True)
+                if kind == "transformer"
+                else self._hybrid_core(bound=True)
+            )
             if unroll:
                 core_out, state = core(features, first, state)
             else:
-                # Step mode is the T=1 unroll; the KV cache is the carry.
+                # Step mode is the T=1 unroll; the caches (and the hybrid
+                # core's scan states and convolution windows) are the carry.
                 core_out, state = core(
                     features[None], first[None], state
                 )

@@ -66,7 +66,7 @@ _PAD_SEG = -2_147_483_000  # matches no real segment id (kv empty is -1)
 
 
 def _visible_tile(
-    seg_q, seg_c, t_offset, s_offset, Tb: int, Sb: int, W: int
+    seg_q, seg_c, t_offset, s_offset, Tb: int, Sb: int, W: int, window=None
 ):
     """The visibility mask every kernel shares (THE correctness-critical
     invariant: cache slot or causal in-unroll, same episode). seg_q
@@ -75,15 +75,26 @@ def _visible_tile(
     tile's absolute start rows/cols in the padded [Tp, Sp] score matrix."""
     tq = t_offset + jax.lax.broadcasted_iota(jnp.int32, (Tb, Sb), 0)
     s_idx = s_offset + jax.lax.broadcasted_iota(jnp.int32, (Tb, Sb), 1)
-    return (seg_q == seg_c) & ((s_idx < W) | (s_idx - W <= tq))
+    visible = (seg_q == seg_c) & ((s_idx < W) | (s_idx - W <= tq))
+    if window is not None:
+        # Context index s stands `s - W` steps from the unroll's start,
+        # cache slots included (slot W-1 is the step before the unroll):
+        # the query at t is `t + W - s` steps past it.
+        visible &= tq + W - s_idx < window
+    return visible
 
 
-def _tile_may_see(t_offset, s_offset, Tb: int, W: int):
+def _tile_may_see(t_offset, s_offset, Tb: int, Sb: int, W: int, window=None):
     """Cheap per-tile position test: can ANY (t, s) in this tile be
     visible? False for the strictly-above-causal tiles (s past the cache
-    and past every query row), which lets the kernels skip both matmuls —
-    on a dense causal T=S grid that's ~half the tiles."""
-    return (s_offset < W) | (s_offset - W <= t_offset + Tb - 1)
+    and past every query row) and, with a `window`, for the tiles that
+    lie wholly behind it, which lets the kernels skip both matmuls —
+    on a dense causal T=S grid that's ~half the tiles; with a window of
+    512 in an unroll of 2,048 about four fifths."""
+    may = (s_offset < W) | (s_offset - W <= t_offset + Tb - 1)
+    if window is not None:
+        may &= s_offset + Sb - 1 > t_offset + W - window
+    return may
 
 
 def _pad_segs(seg_q, seg_ctx, Tp: int, Sp: int):
@@ -113,14 +124,14 @@ def _dot(a, b, dims):
     )
 
 
-def _tile_probs(q, k, seg_q, seg_c, lse, t_off, s_off, scale, W):
+def _tile_probs(q, k, seg_q, seg_c, lse, t_off, s_off, scale, W, window):
     """Recompute one [Tb, Sb] probability tile from q/k + the forward's
     row logsumexp (backward-pass rematerialization). `lse` is `[Tb, 1]`.
     Masked entries are zeroed EXPLICITLY (never via exp alone): padded
     rows carry lse=NEG_INF and would otherwise produce inf."""
     Tb, Sb = q.shape[0], k.shape[0]
     logits = _dot(q, k, ((1,), (1,))) * scale
-    visible = _visible_tile(seg_q, seg_c, t_off, s_off, Tb, Sb, W)
+    visible = _visible_tile(seg_q, seg_c, t_off, s_off, Tb, Sb, W, window)
     return jnp.where(visible, jnp.exp(logits - lse), 0.0)
 
 
@@ -139,6 +150,7 @@ def _fwd_kernel(
     scale: float,
     W: int,
     num_s: int,
+    window=None,
 ):
     """Online-softmax forward: for one (b, h, t-block), sweep the S tiles
     (innermost grid dim) carrying (m, l, acc) in VMEM scratch; emit the
@@ -155,14 +167,14 @@ def _fwd_kernel(
 
     t_off = pl.program_id(2) * Tb
 
-    @pl.when(_tile_may_see(t_off, s * Sb, Tb, W))
+    @pl.when(_tile_may_see(t_off, s * Sb, Tb, Sb, W, window))
     def _online_update():
         q = q_ref[0, 0]  # [Tb, dh]
         k = k_ref[0, 0]  # [Sb, dh]
         v = v_ref[0, 0]
         logits = _dot(q, k, ((1,), (1,))) * scale  # [Tb, Sb]
         visible = _visible_tile(
-            segq_ref[0], segc_ref[0], t_off, s * Sb, Tb, Sb, W
+            segq_ref[0], segc_ref[0], t_off, s * Sb, Tb, Sb, W, window
         )
         logits = jnp.where(visible, logits, NEG_INF)
 
@@ -213,7 +225,9 @@ def _block_sizes(T: int, S: int):
     return Tb, _round_up(T, Tb), d * 128, Sp
 
 
-def _tile_specs(Tb: int, Sb: int, dh: int, t_inner: bool):
+def _tile_specs(
+    Tb: int, Sb: int, dh: int, t_inner: bool, group: int = 1, num_t: int = 1
+):
     """The five BlockSpecs every kernel grid uses, for a (b, h, x, y)
     grid over `[B, H, seq, dh]`-layout tensors: t_inner=False means
     (x, y) = (t-block, s-block) — the forward and dQ sweeps;
@@ -233,27 +247,54 @@ def _tile_specs(Tb: int, Sb: int, dh: int, t_inner: bool):
     - seg_q: `[B, Tp, 1]` (sublane), seg_c: `[B, 1, Sp]` (lane) so the
       in-kernel equality is a native [Tb,1]==[1,Sb] broadcast.
 
-    Returns (t_spec, s_spec, row_spec, segq_spec, segc_spec)."""
+    Grouped heads (`group` query heads on one key/value head, query
+    head `h` on key/value head `h // group`): the forward and dQ grids
+    run over the query heads and fetch k/v of head `h // group`; the
+    dK/dV grid runs over the key/value heads and its innermost dim over
+    `group * num_t` steps, the T sweep of each query head of the group
+    in turn, so that a key/value block's scratch gathers all of them.
 
-    def pick(x, y):
-        return (y, x) if t_inner else (x, y)
+    Returns (t_spec, s_spec, row_spec, segq_spec, segc_spec)."""
 
     def vmem(block, index_map):
         return pl.BlockSpec(block, index_map, memory_space=pltpu.VMEM)
 
+    if t_inner:
+        hq = lambda h, y: h * group + y // num_t  # noqa: E731
+        tb = lambda y: y % num_t  # noqa: E731
+        return (
+            vmem((1, 1, Tb, dh), lambda b, h, x, y: (b, hq(h, y), tb(y), 0)),
+            vmem((1, 1, Sb, dh), lambda b, h, x, y: (b, h, x, 0)),
+            vmem((1, 1, Tb, 1), lambda b, h, x, y: (b, hq(h, y), tb(y), 0)),
+            vmem((1, Tb, 1), lambda b, h, x, y: (b, tb(y), 0)),
+            vmem((1, 1, Sb), lambda b, h, x, y: (b, 0, x)),
+        )
     return (
-        vmem((1, 1, Tb, dh), lambda b, h, x, y: (b, h, pick(x, y)[0], 0)),
-        vmem((1, 1, Sb, dh), lambda b, h, x, y: (b, h, pick(x, y)[1], 0)),
-        vmem((1, 1, Tb, 1), lambda b, h, x, y: (b, h, pick(x, y)[0], 0)),
-        vmem((1, Tb, 1), lambda b, h, x, y: (b, pick(x, y)[0], 0)),
-        vmem((1, 1, Sb), lambda b, h, x, y: (b, 0, pick(x, y)[1])),
+        vmem((1, 1, Tb, dh), lambda b, h, x, y: (b, h, x, 0)),
+        vmem((1, 1, Sb, dh), lambda b, h, x, y: (b, h // group, y, 0)),
+        vmem((1, 1, Tb, 1), lambda b, h, x, y: (b, h, x, 0)),
+        vmem((1, Tb, 1), lambda b, h, x, y: (b, x, 0)),
+        vmem((1, 1, Sb), lambda b, h, x, y: (b, 0, y)),
     )
 
 
-def _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret):
+def _groups(q, k_ctx) -> int:
+    H, Hkv = q.shape[2], k_ctx.shape[2]
+    if H % Hkv:
+        raise ValueError(
+            f"{H} query heads do not divide over {Hkv} key/value heads"
+        )
+    return H // Hkv
+
+
+def _forward(
+    q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret, window=None,
+    name="attention",
+):
     """Returns (out `[B, T, H, dh]` f32, lse `[B, H, Tp, 1]` f32)."""
     B, T, H, dh = q.shape
     S = k_ctx.shape[1]
+    group = _groups(q, k_ctx)
     f32 = jnp.float32
 
     # Kernel layout is [B, H, seq, dh] (see _tile_specs); operands keep
@@ -281,13 +322,15 @@ def _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret):
     segq_p, segc_p = segq_p[:, :, None], segc_p[:, None, :]
 
     kernel = functools.partial(
-        _fwd_kernel, scale=1.0 / (dh**0.5), W=W, num_s=Sp // Sb
+        _fwd_kernel, scale=1.0 / (dh**0.5), W=W, num_s=Sp // Sb,
+        window=window,
     )
     q_spec, kv_spec, lse_spec, segq_spec, segc_spec = _tile_specs(
-        Tb, Sb, dh, t_inner=False
+        Tb, Sb, dh, t_inner=False, group=group
     )
     out, lse = pallas_call(
         kernel,
+        name=f"{name}_forward",
         grid=(B, H, Tp // Tb, Sp // Sb),
         in_specs=[q_spec, kv_spec, kv_spec, segq_spec, segc_spec],
         out_specs=(q_spec, lse_spec),
@@ -320,6 +363,7 @@ def _dq_kernel(
     scale: float,
     W: int,
     num_s: int,
+    window=None,
 ):
     """dQ for one (b, h, t-block), accumulated over the S sweep:
     dS = P * (dP - D), dQ = dS K * scale, with P recomputed per tile
@@ -334,7 +378,7 @@ def _dq_kernel(
 
     t_off = pl.program_id(2) * Tb
 
-    @pl.when(_tile_may_see(t_off, s * Sb, Tb, W))
+    @pl.when(_tile_may_see(t_off, s * Sb, Tb, Sb, W, window))
     def _accumulate():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -342,7 +386,7 @@ def _dq_kernel(
         g = g_ref[0, 0]
         p = _tile_probs(
             q, k, segq_ref[0], segc_ref[0], lse_ref[0, 0],
-            t_off, s * Sb, scale, W,
+            t_off, s * Sb, scale, W, window,
         )  # [Tb, Sb]
         dp = _dot(g, v, ((1,), (1,)))  # [Tb, Sb]
         ds = p * (dp - dcap_ref[0, 0])
@@ -371,21 +415,26 @@ def _dkv_kernel(
     scale: float,
     W: int,
     num_t: int,
+    group: int = 1,
+    window=None,
 ):
-    """dK/dV for one (b, h, s-block), accumulated over the T sweep
-    (innermost grid dim): dV = P^T dO, dK = dS^T Q * scale."""
-    t = pl.program_id(3)
+    """dK/dV for one (b, key/value head, s-block), accumulated over the
+    innermost grid dim: the T sweep of each of the `group` query heads
+    that share the head, one after the other: dV = P^T dO,
+    dK = dS^T Q * scale."""
+    y = pl.program_id(3)
+    t = y % num_t
     Tb = q_ref.shape[2]
     Sb = k_ref.shape[2]
 
-    @pl.when(t == 0)
+    @pl.when(y == 0)
     def _init():
         dk_scr[...] = jnp.zeros_like(dk_scr)
         dv_scr[...] = jnp.zeros_like(dv_scr)
 
     s_off = pl.program_id(2) * Sb
 
-    @pl.when(_tile_may_see(t * Tb, s_off, Tb, W))
+    @pl.when(_tile_may_see(t * Tb, s_off, Tb, Sb, W, window))
     def _accumulate():
         q = q_ref[0, 0]
         k = k_ref[0, 0]
@@ -393,7 +442,7 @@ def _dkv_kernel(
         g = g_ref[0, 0]
         p = _tile_probs(
             q, k, segq_ref[0], segc_ref[0], lse_ref[0, 0],
-            t * Tb, s_off, scale, W,
+            t * Tb, s_off, scale, W, window,
         )  # [Tb, Sb]
         dv_scr[...] += _dot(
             p.astype(g.dtype), g, ((0,), (0,))
@@ -402,17 +451,22 @@ def _dkv_kernel(
         ds = p * (dp - dcap_ref[0, 0])
         dk_scr[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
 
-    @pl.when(t == num_t - 1)
+    @pl.when(y == group * num_t - 1)
     def _emit():
         dk_ref[0, 0] = dk_scr[...]
         dv_ref[0, 0] = dv_scr[...]
 
 
-def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
+def _bwd_pallas(
+    q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret, window=None,
+    name="attention",
+):
     """S-tiled flash backward: two pallas_calls (dQ sweep over S; dK/dV
     sweep over T) sharing the tile-probability recomputation."""
     B, T, H, dh = q.shape
     S = k_ctx.shape[1]
+    group = _groups(q, k_ctx)
+    Hkv = H // group
     f32 = jnp.float32
     # Kernel layout is [B, H, seq, dh] (see _tile_specs). Operands keep
     # their input dtype (see _forward); o is the saved f32 forward
@@ -435,12 +489,13 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
 
     scale = 1.0 / (dh**0.5)
     t_spec, s_spec, row_spec, segq_spec, segc_spec = _tile_specs(
-        Tb, Sb, dh, t_inner=False
+        Tb, Sb, dh, t_inner=False, group=group
     )
     dq = pallas_call(
         functools.partial(
-            _dq_kernel, scale=scale, W=W, num_s=Sp // Sb
+            _dq_kernel, scale=scale, W=W, num_s=Sp // Sb, window=window
         ),
+        name=f"{name}_backward_dq",
         grid=(B, H, Tp // Tb, Sp // Sb),
         in_specs=[
             t_spec, s_spec, s_spec, t_spec, row_spec, row_spec,
@@ -454,22 +509,25 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
 
     # dK/dV: same specs with the roles of the last two grid dims swapped —
     # s indexes the OUTER dim (block stays resident), t sweeps innermost.
+    num_t = Tp // Tb
     t_spec2, s_spec2, row_spec2, segq_spec2, segc_spec2 = _tile_specs(
-        Tb, Sb, dh, t_inner=True
+        Tb, Sb, dh, t_inner=True, group=group, num_t=num_t
     )
     dk, dv = pallas_call(
         functools.partial(
-            _dkv_kernel, scale=scale, W=W, num_t=Tp // Tb
+            _dkv_kernel, scale=scale, W=W, num_t=num_t, group=group,
+            window=window,
         ),
-        grid=(B, H, Sp // Sb, Tp // Tb),
+        name=f"{name}_backward_dkv",
+        grid=(B, Hkv, Sp // Sb, group * num_t),
         in_specs=[
             t_spec2, s_spec2, s_spec2, t_spec2, row_spec2, row_spec2,
             segq_spec2, segc_spec2,
         ],
         out_specs=(s_spec2, s_spec2),
         out_shape=(
-            jax.ShapeDtypeStruct((B, H, Sp, dh), f32),
-            jax.ShapeDtypeStruct((B, H, Sp, dh), f32),
+            jax.ShapeDtypeStruct((B, Hkv, Sp, dh), f32),
+            jax.ShapeDtypeStruct((B, Hkv, Sp, dh), f32),
         ),
         scratch_shapes=[
             pltpu.VMEM((Sb, dh), f32),
@@ -484,50 +542,71 @@ def _bwd_pallas(q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret):
     )
 
 
-def _visibility(seg_q, seg_ctx, T: int, S: int, W: int):
+def _visibility(seg_q, seg_ctx, T: int, S: int, W: int, window=None):
     """The einsum path's mask (models/transformer.py dense path), exposed
     for the tests' reference implementation."""
     t = jnp.arange(T, dtype=jnp.int32)
     s = jnp.arange(S, dtype=jnp.int32)
     pos_ok = (s[None, :] < W) | (s[None, :] - W <= t[:, None])  # [T, S]
+    if window is not None:
+        pos_ok &= t[:, None] + W - s[None, :] < window
     return (
         seg_q[:, :, None] == seg_ctx[:, None, :]
     ) & pos_ok[None, :, :]  # [B, T, S]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def windowed_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def windowed_attention(
+    q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None, window=None,
+    name="attention",
+):
     """Masked single-device flash attention, Pallas-fused fwd + bwd.
 
     Args:
       q: `[B, T, H, dh]` rotary'd queries.
-      k_ctx/v_ctx: `[B, S, H, dh]` context (W cache slots then T current
-        tokens, S = W + T; keys already rotary'd).
+      k_ctx/v_ctx: `[B, S, Hkv, dh]` context (W cache slots then T
+        current tokens, S = W + T; keys already rotary'd). `Hkv` divides
+        `H`: query head `h` reads key/value head `h // (H // Hkv)`.
       seg_q: `[B, T]` int32 query segment (episode) ids.
       seg_ctx: `[B, S]` int32 context segment ids (-1 = empty cache slot).
       W: static int, number of cache slots at the front of the context.
       interpret: None (default) compiles the kernels where the call is
         lowered for a TPU and interprets them elsewhere
         (ops/pallas_util.py); True/False forces one mode.
+      window: static int or None. With it a query also sees only the
+        `window` newest positions up to its own, counted through cache
+        and unroll alike (the cache's slots hold the `W` steps before
+        the unroll, oldest first): `W` alone bounds the cache, not the
+        unroll. Tiles wholly behind the window are skipped.
+      name: the kernels' names in the compiled program and a trace:
+        `<name>_forward`, `<name>_backward_dq`, `<name>_backward_dkv`.
 
     Returns `[B, T, H, dh]` attention output in q's dtype (math in f32),
     differentiable w.r.t. q/k_ctx/v_ctx.
     """
-    out, _ = _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret)
+    out, _ = _forward(
+        q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret, window, name
+    )
     return out.astype(q.dtype)
 
 
-def _fwd(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None):
-    out, lse = _forward(q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret)
+def _fwd(
+    q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret=None, window=None,
+    name="attention",
+):
+    out, lse = _forward(
+        q, k_ctx, v_ctx, seg_q, seg_ctx, W, interpret, window, name
+    )
     # Residuals carry the f32 output (for D) + row logsumexp (for tile
     # probability recomputation) — O(T*dh + T) per (b, h), never [T, S].
     return out.astype(q.dtype), (q, k_ctx, v_ctx, seg_q, seg_ctx, out, lse)
 
 
-def _bwd(W, interpret, res, g):
+def _bwd(W, interpret, window, name, res, g):
     q, k_ctx, v_ctx, seg_q, seg_ctx, o, lse = res
     dq, dk, dv = _bwd_pallas(
-        q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret
+        q, k_ctx, v_ctx, g, o, lse, seg_q, seg_ctx, W, interpret, window,
+        name,
     )
     # Cotangent dtypes must match the primals' (bf16 inputs get bf16
     # grads even though the math above runs in f32).

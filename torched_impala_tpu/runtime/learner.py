@@ -67,6 +67,7 @@ from torched_impala_tpu.runtime.types import (
     Trajectory,
     crossed_interval,
     host_snapshot,
+    owned_array,
     tree_nbytes,
 )
 
@@ -232,10 +233,56 @@ class _InFlight(NamedTuple):
     version: int  # num_frames after the step: what its parameters publish as
     dispatch_t0_ns: int  # where its `learner/step_in_flight` span opens
     probe: list  # one device leaf that is ready when the step (and copy) is
-    snapshot: Any = None  # on-device copy of its parameters, D2H requested
+    snapshot: Any = None  # on-device copy of its parameters
+    requested: int = 0  # how many of its leaves have their D2H requested
     ring_slot: int = -1  # donated ring slot to recycle once it completed
     logs: Optional[dict] = None  # its log scalars, when it crossed log_interval
     meta: Optional[BatchLineage] = None
+
+
+# Bytes of a version whose copies to the host may stand requested and
+# unread. With a step in flight the runtime's host side of such requests
+# grows outside the process's resident set, and by what is outstanding:
+# 1.76 GB of parameters (the hybrid core at published widths) all
+# requested at once, as the snapshot is queued or after the wait for
+# it, grew the machine's count 0.4-0.65 GiB a step until a 30 s window
+# met its 40 GiB, 256 MiB ahead as much, 16-64 MiB ahead 0.03-0.04 GiB a
+# step (my chip runs, PR 34). The 6 MB of an LSTM preset fit whole, so
+# every leaf is requested as the snapshot is queued; a larger tree has
+# the leaves that fit requested and the rest as the ones before them are
+# read, and a leaf over the bound is one synchronous copy.
+SNAPSHOT_COPY_AHEAD_BYTES = 64 << 20
+
+
+def _request_copies(leaves, begin: int, unread: int):
+    """Request the D2H of `leaves[begin:]`, in order, for as long as the
+    bytes requested and not yet read stay within
+    `SNAPSHOT_COPY_AHEAD_BYTES`: `(where it stopped, those bytes)`."""
+    while (
+        begin < len(leaves)
+        and unread + leaves[begin].nbytes <= SNAPSHOT_COPY_AHEAD_BYTES
+    ):
+        leaves[begin].copy_to_host_async()
+        unread += leaves[begin].nbytes
+        begin += 1
+    return begin, unread
+
+
+def _snapshot_to_host(snapshot, requested: int):
+    """`host_snapshot` of a device tree whose first `requested` leaves
+    have their D2H requested: leaf by leaf in the tree's order, the next
+    leaves' copies requested as the bound allows."""
+    leaves, treedef = jax.tree.flatten(snapshot)
+    unread = sum(leaf.nbytes for leaf in leaves[:requested])
+    host = []
+    for i, leaf in enumerate(leaves):
+        host.append(owned_array(leaf))
+        if i < requested:
+            unread -= leaf.nbytes
+        requested, unread = _request_copies(
+            leaves, max(requested, i + 1), unread
+        )
+    return jax.tree.unflatten(treedef, host)
 
 
 @jax.jit
@@ -294,16 +341,17 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
 
     Returns `(agent, loss, resolved)`: `loss.vtrace_implementation` is
     never 'auto' afterwards, and `resolved` names what was chosen
-    (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`, `devices`,
-    `why`; a `Learner` adds `packed_convs`, which needs the observations'
-    shape).
+    (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`,
+    `selective_scan`, `devices`, `why`; a `Learner` adds `packed_convs`,
+    which needs the observations' shape).
 
     On one device the choice is per platform: the Pallas kernels on a
     TPU, the scan elsewhere (the model's own kernels pick compiled vs
     interpreted at lowering time, ops/pallas_util.py). On a mesh of more
     than one TPU device every kernel resolves to its XLA implementation
     — the scan V-trace, the flax LSTM cell, unfused residual blocks,
-    XLA's max-pool, einsum attention — because Mosaic kernels cannot be
+    XLA's max-pool, einsum attention, the hybrid core's scan one step at
+    a time — because Mosaic kernels cannot be
     auto-partitioned and nothing here wraps them per shard yet. That is decided HERE, by
     construction, and logged once; it is never recovered from a failed
     lowering. On a TPU, one device or a mesh, a deep torso's residual
@@ -340,6 +388,15 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
                 (k, "einsum" if k == "dense_kernel" else v)
                 for k, v in net.transformer
             ),
+            hybrid=tuple(
+                {
+                    **dict(net.hybrid),
+                    "attention_kernel": "einsum",
+                    "scan_kernel": False,
+                }.items()
+            )
+            if net.hybrid
+            else (),
         )
     elif impl == "auto":
         impl = vtrace_ops.resolve_implementation("auto", devices)
@@ -356,7 +413,20 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
         "attention": (
             dict(net.transformer).get("dense_kernel")
             if kind == "transformer"
+            else dict(net.hybrid).get("attention_kernel")
+            if kind == "hybrid"
             else None
+        ),
+        # The hybrid core's scan: its Pallas kernels where the step is
+        # lowered for one TPU device, the step-by-step scan elsewhere
+        # (ops/selective_scan.py chooses at lowering time).
+        "selective_scan": (
+            None
+            if kind != "hybrid"
+            else "xla_scan"
+            if not dict(net.hybrid).get("scan_kernel", True)
+            or platform != "tpu"
+            else "pallas"
         ),
         "devices": [str(d) for d in devices],
         "why": why,
@@ -701,6 +771,17 @@ class Learner:
         # through the aliasing-fallback owning copy before device_put.
         self._m_host_stack_bytes = reg.counter("learner/host_stack_bytes")
         self._m_ring_stage_bytes = reg.counter("learner/ring_stage_bytes")
+        # The hybrid core's carry (models/hybrid.py): what one row of it
+        # weighs, and how many episode starts the batch just stacked
+        # holds (each resets the scan and the convolution inside the
+        # unroll and bounds what attention may see). Counted on the host
+        # from `first`, in the batcher; absent with any other core.
+        self._m_core_resets = None
+        if agent.net._core_kind() == "hybrid":
+            reg.gauge("core/state_bytes_per_row").set(
+                agent.net._hybrid_core(bound=False).state_bytes_per_row()
+            )
+            self._m_core_resets = reg.gauge("core/resets_in_batch")
         self._m_device_put = reg.timer("learner/device_put")
         self._m_train_step = reg.timer("learner/train_step")
         self._m_publish = reg.timer("learner/publish")
@@ -1721,6 +1802,8 @@ class Learner:
             {"batch": meta.batch, "lineage": list(meta.lineage)},
         )
         self._count_stack_bytes(batch)
+        if self._m_core_resets is not None:
+            self._m_core_resets.set(int(np.count_nonzero(batch.first)))
         return batch
 
     def _count_stack_bytes(self, batch: Trajectory) -> None:
@@ -2158,13 +2241,9 @@ class Learner:
         actors, blocking: construction and `set_state`, where nothing is
         in flight. The step loop publishes through `_settle`."""
         t0 = time.monotonic_ns()
-        # All leaf D2H copies requested before any is materialised:
-        # np.asarray alone would serialise one transfer per leaf.
-        for leaf in jax.tree.leaves(self._params):
-            if hasattr(leaf, "copy_to_host_async"):
-                leaf.copy_to_host_async()
+        requested, _ = _request_copies(jax.tree.leaves(self._params), 0, 0)
         self.param_store.publish(
-            self.num_frames, host_snapshot(self._params)
+            self.num_frames, _snapshot_to_host(self._params, requested)
         )
         dur = time.monotonic_ns() - t0
         self._m_publish.observe(dur / 1e9)
@@ -2213,12 +2292,13 @@ class Learner:
             )
             landed = ready
             if done.snapshot is not None:
-                # host_snapshot, not bare np.asarray: published trees
-                # must own their bytes (see types.host_snapshot). The
-                # D2H was requested when the snapshot was queued, so this
-                # mostly finds the bytes on the host already.
+                # Published trees own their bytes (types.owned_array).
+                # The D2H of what fits the bound was requested when the
+                # snapshot was queued, so this mostly finds those bytes
+                # on the host already.
                 self.param_store.publish(
-                    done.version, host_snapshot(done.snapshot)
+                    done.version,
+                    _snapshot_to_host(done.snapshot, done.requested),
                 )
                 landed = time.monotonic_ns()
                 self._m_publish.observe((landed - t0) / 1e9)
@@ -2633,17 +2713,17 @@ class Learner:
             self._logger is not None or self._health is not None
         ) and crossed_interval(self.num_steps, K, self._config.log_interval)
         queued = queueing = time.monotonic_ns()
-        snapshot = None
+        snapshot, requested = None, 0
         if publishes:
             # Queued behind this step and before the next is ever
-            # dispatched, and its D2H requested at once (all leaves
-            # before any is materialised: np.asarray alone would
-            # serialise one synchronous transfer per leaf), so the bytes
-            # travel as soon as the device has them.
+            # dispatched, and its D2H requested at once (before any leaf
+            # is materialised: np.asarray alone would serialise one
+            # synchronous transfer per leaf), so the bytes travel as soon
+            # as the device has them; of a large tree, the leaves that
+            # fit `SNAPSHOT_COPY_AHEAD_BYTES`.
             snapshot = _publish_snapshot(self._params)
             leaves = jax.tree.leaves(snapshot)
-            for leaf in leaves:
-                leaf.copy_to_host_async()
+            requested, _ = _request_copies(leaves, 0, 0)
             probe = leaves[:1]
             queued = time.monotonic_ns()
             self._tracer.complete(
@@ -2662,6 +2742,7 @@ class Learner:
                 dispatch_t0_ns=step_t0_ns,
                 probe=probe,
                 snapshot=snapshot,
+                requested=requested,
                 ring_slot=meta.ring_slot,
                 logs=dict(logs) if logs_due else None,
                 meta=meta,

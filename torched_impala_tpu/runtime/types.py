@@ -70,12 +70,13 @@ def host_snapshot(tree: Any) -> Any:
     params, checkpoint snapshots, trajectory start states) must own its
     bytes. On TPU `np.asarray` is already a fresh D2H copy, and the
     owndata check keeps that single-copy."""
+    return jax.tree.map(owned_array, tree)
 
-    def owned(leaf):
-        arr = np.asarray(leaf)
-        return arr if arr.flags.owndata else np.array(arr, copy=True)
 
-    return jax.tree.map(owned, tree)
+def owned_array(leaf: Any) -> np.ndarray:
+    """One leaf of `host_snapshot`: host numpy that owns its bytes."""
+    arr = np.asarray(leaf)
+    return arr if arr.flags.owndata else np.array(arr, copy=True)
 
 
 def tree_nbytes(tree: Any) -> int:
