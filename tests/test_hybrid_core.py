@@ -397,86 +397,524 @@ def test_the_mixed_carry_lives_on_the_policy_server():
         server.close()
 
 
+class _Walk:
+    """What a learner asks of the runtime while it publishes: every
+    `copy_to_host_async` (`("ask", id, shape, bytes)`) and every piece
+    read (`("read", id, shape, bytes)`, as its bytes have landed, on
+    whichever thread), in order."""
+
+    def __init__(self, monkeypatch, bound=None):
+        from torched_impala_tpu.runtime import learner as learner_mod
+
+        if bound is not None:
+            monkeypatch.setattr(
+                learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", bound
+            )
+        self.events = events = []
+        impl = type(jnp.zeros(1))
+        ask = impl.copy_to_host_async
+        monkeypatch.setattr(
+            impl, "copy_to_host_async",
+            lambda self: (
+                events.append(("ask", id(self), self.shape, self.nbytes)),
+                ask(self),
+            )[1],
+        )
+        read = learner_mod.owned_array
+        monkeypatch.setattr(
+            learner_mod, "owned_array",
+            lambda piece, out=None: (
+                read(piece, out),
+                events.append(("read", id(piece), piece.shape, piece.nbytes)),
+            )[0],
+        )
+
+    def outstanding(self):
+        """`(bytes, pieces)` requested and unread after each event, and
+        whatever was asked for and never read."""
+        unread, series = {}, []
+        for kind, key, _, nbytes in self.events:
+            if kind == "ask":
+                unread[key] = nbytes
+            else:
+                unread.pop(key, None)
+            series.append((sum(unread.values()), len(unread)))
+        return series, unread
+
+
+def _unrolls(network, agent):
+    from torched_impala_tpu.runtime.types import Trajectory
+
+    net, config = network
+    obs, first, state = _inputs(net, config, 9, ((4, 0),))
+    structure = jax.tree.structure(agent.initial_state(1))
+    return [
+        Trajectory(
+            obs=obs[:, i], first=first[:, i],
+            actions=np.zeros(T - 1, np.int32),
+            behaviour_logits=np.zeros((T - 1, 6), np.float32),
+            rewards=np.ones(T - 1, np.float32),
+            cont=np.ones(T - 1, np.float32),
+            agent_state=jax.tree.unflatten(
+                structure, [leaf[i : i + 1] for leaf in state]
+            ),
+            task=0,
+        )
+        for i in range(B)
+    ]
+
+
+_RUNS: dict = {}
+
+
+def _publishing_run(network, bound):
+    """Construction and two steps of the small hybrid learner under
+    `bound` (run once a bound): the walk, the three versions as
+    published, the learner's own parameters at each, the gauges after
+    the last."""
+    from torched_impala_tpu.runtime import Learner
+    from torched_impala_tpu.telemetry.registry import Registry
+
+    if bound in _RUNS:
+        return _RUNS[bound]
+    with pytest.MonkeyPatch.context() as patch:
+        walk = _Walk(patch, bound)
+        cfg = small_cfg()
+        agent = configs.make_agent(cfg)
+        reg = Registry()
+        learner = Learner(
+            agent=agent, optimizer=configs.make_optimizer(cfg),
+            config=configs.make_learner_config(cfg),
+            example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
+            telemetry=reg,
+        )
+        events = list(walk.events)  # the reads below are not the learner's
+        own = {0: [np.array(x) for x in jax.tree.leaves(learner.params)]}
+        learner.start()
+        try:
+            for step in (1, 2):
+                for unroll in _unrolls(network, agent):
+                    learner.enqueue(unroll)
+                del walk.events[:]
+                learner.step_once(timeout=300)
+                events += walk.events
+                own[step * B * (T - 1)] = [
+                    np.array(x) for x in jax.tree.leaves(learner.params)
+                ]
+            del walk.events[:]
+            learner.drain()
+            events += walk.events
+            assert learner.param_store.versions() == sorted(own)
+            published = {
+                version: jax.tree.leaves(
+                    learner.param_store.get_version(version)
+                )
+                for version in own
+            }
+        finally:
+            learner.stop()
+        walk.events[:] = events
+    gauges = {m.name: m.value for m in reg.metrics() if hasattr(m, "value")}
+    _RUNS[bound] = walk, published, own, gauges
+    return _RUNS[bound]
+
+
+def _rows(leaf, bound):
+    """`learner._piece_rows` of a host leaf as if on one device, under
+    `bound` (`test_piece_rows` holds the function itself to numbers)."""
+    import types
+
+    from torched_impala_tpu.runtime import learner as learner_mod
+
+    one_device = types.SimpleNamespace(is_fully_replicated=True)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", bound)
+        return learner_mod._piece_rows(types.SimpleNamespace(
+            shape=leaf.shape, nbytes=leaf.nbytes, sharding=one_device
+        ))
+
+
+CUT = 65536  # a bound whose pieces cut the 128 KB input projection in 8
+
+
+def _alone(versions):
+    """Every leaf of every version is one C-contiguous array whose bytes
+    are its own, or its version's slab's (a cut leaf: a view, whose slab
+    no other version's leaves lie in): nobody else writes them while a
+    reader holds them. Returns the slabs."""
+    slabs = []
+    for leaves in versions:
+        views = [x for x in leaves if not x.flags.owndata]
+        assert all(x.flags.c_contiguous and x.flags.aligned for x in leaves)
+        if views:
+            (slab,) = {id(x.base): x.base for x in views}.values()
+            assert isinstance(slab, np.ndarray) and slab.dtype == np.uint8
+            assert all(np.shares_memory(x, slab) for x in views)
+            slabs.append(slab)
+    assert not any(
+        np.shares_memory(a, b) for a in slabs for b in slabs if a is not b
+    )
+    return slabs
+
+
 @pytest.mark.parametrize("bound", [64 << 20, 8192])
-def test_a_snapshots_copies_are_requested_within_a_bound(
-    network, monkeypatch, bound
-):
+def test_a_snapshots_copies_are_requested_within_a_bound(network, bound):
     """The copies of a version to the host stand requested and unread by
     `SNAPSHOT_COPY_AHEAD_BYTES` at the most (at 1.76 GB a version, all
     requested at once made the runtime's host side grow without bound):
-    a tree that fits is requested whole as it is queued, a leaf over the
-    bound never; what is published is the same either way, every
-    version, the step's own parameters in bytes of their own."""
-    from torched_impala_tpu.runtime import Learner, learner as learner_mod
+    a tree that fits is requested whole as it is queued; a larger one
+    travels in pieces of a quarter of the bound, none of them ever over
+    it; what is published is the same either way, every version, the
+    step's own parameters in bytes no other version shares."""
+    walk, published, own, gauges = _publishing_run(network, bound)
+    leaves = len(own[0])
+    version = 2 * B * (T - 1)
+    for got, want in zip(published[version], own[version]):
+        np.testing.assert_array_equal(got, want)
+    slabs = _alone(published.values())
+    # a slab a step's version with a cut leaf; the construction's comes
+    # whole from the live parameters, and so does a tree that fits
+    assert len(slabs) == (0 if bound == 64 << 20 else 2)
+    # three versions: the construction's read leaf by leaf from the live
+    # parameters, the two steps' piece by piece, each piece once
+    pieces = int(gauges["learner/publish_pieces"])
+    assert sum(e[0] == "read" for e in walk.events) == leaves + 2 * pieces
+    series, unread = walk.outstanding()
+    assert 0 < max(held for held, _ in series) <= bound and not unread
+    asked = {e[2] for e in walk.events if e[0] == "ask"}
+    # the core's input projection, 128 KB: requested whole where it fits
+    # a piece, in blocks of 8 rows (2 KB) under the small bound
+    assert ((512, 64) in asked) is (bound > 512 * 64 * 4)
+    if bound == 64 << 20:
+        # a tree that fits: every leaf requested before the first is
+        # read, and the pieces are the leaves
+        assert [e[0] for e in walk.events].index("read") == leaves
+        assert pieces == leaves
+    else:
+        assert (8, 64) in asked and pieces > leaves
+
+
+@pytest.mark.parametrize("version", [0, 1, 2])
+def test_a_version_cut_in_pieces_is_published_whole(network, version):
+    """(i) With one leaf cut in eight and others in two, every published
+    leaf is the learner's own parameter of that version bit for bit, one
+    C-contiguous array a leaf, a cut one a view of its version's slab:
+    the construction's version and each step's."""
+    walk, published, own, _ = _publishing_run(network, CUT)
+    cut = [x for x in own[0] if _rows(x, CUT)]
+    assert max(-(-x.shape[0] // _rows(x, CUT)) for x in cut) >= 3
+    version *= B * (T - 1)
+    assert len(published[version]) == len(own[version])
+    for got, want in zip(published[version], own[version]):
+        assert isinstance(got, np.ndarray) and got.dtype == want.dtype
+        assert got.shape == want.shape and got.tobytes() == want.tobytes()
+        assert got.flags.owndata is not (version > 0 and bool(_rows(want, CUT)))
+    _alone([published[version]])
+    # the steps moved the parameters: the versions are not one tree
+    assert any(
+        a.tobytes() != b.tobytes()
+        for a, b in zip(published[0], published[2 * B * (T - 1)])
+    )
+
+
+def test_the_next_pieces_stand_requested_while_one_is_read(network):
+    """(ii) Requested-and-unread bytes never pass the bound, and at some
+    moment more than one piece stood requested: the link moves the next
+    pieces while the host writes this one into its leaf."""
+    walk, _, own, _ = _publishing_run(network, CUT)
+    series, unread = walk.outstanding()
+    assert not unread
+    assert max(held for held, _ in series) <= CUT
+    # four pieces of 16 KB fill the bound
+    assert max(count for _, count in series) >= 4
+    # the torso's dense kernel, [3136, 512], travels in 392 blocks of 8
+    # rows: as one of them lands the next three stand requested or are
+    # being read (on two threads: fewer where a later one landed
+    # first), never more, which a walk over whole leaves cannot do with
+    # a leaf over the bound
+    ahead, unread = [], {}
+    for kind, key, shape, _ in walk.events:
+        if kind == "ask":
+            unread[key] = shape
+        else:
+            unread.pop(key, None)
+            if shape == (8, 512):
+                ahead.append(list(unread.values()).count((8, 512)))
+    assert len(ahead) == 2 * 392
+    assert max(ahead) == 3 and sorted(ahead)[len(ahead) // 2] >= 2
+
+
+def test_the_gauges_count_pieces_and_outstanding_bytes(network):
+    """(iii) `learner/publish_pieces` is the last version's pieces (its
+    leaves where nothing is cut), `learner/publish_outstanding_peak_bytes`
+    the most that stood requested and unread, within the bound."""
+    walk, _, own, gauges = _publishing_run(network, CUT)
+    leaves = own[0]
+    want = sum(
+        -(-x.shape[0] // _rows(x, CUT)) if _rows(x, CUT) else 1
+        for x in leaves
+    )
+    assert gauges["learner/publish_pieces"] == want > len(leaves)
+    series, _ = walk.outstanding()
+    peak = gauges["learner/publish_outstanding_peak_bytes"]
+    assert 0 < peak <= CUT
+    # the walk saw two versions' requests at once (step k's queued before
+    # step k-1's are read), the gauge counts one version's
+    assert peak <= max(held for held, _ in series) <= 2 * CUT
+    assert gauges["learner/publish_gb_per_s"] > 0
+    _, _, _, whole = _publishing_run(network, 64 << 20)
+    assert whole["learner/publish_pieces"] == len(leaves)
+    assert whole["learner/publish_outstanding_peak_bytes"] == sum(
+        x.nbytes for x in leaves
+    )
+
+
+def test_an_lstm_presets_copies_are_all_asked_then_all_read(monkeypatch):
+    """(iv) At the real bound a 6 MB tree (the breakout preset's, 48
+    leaves, none over a piece) is published call for call as before the
+    pieces: every leaf asked for as the snapshot is queued, every leaf
+    read after the wait on the loop's own thread, in the tree's order
+    (the readers' threads take only the pieces of cut leaves)."""
+    from torched_impala_tpu.runtime import Learner
     from torched_impala_tpu.runtime.types import Trajectory
 
-    monkeypatch.setattr(learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", bound)
-    events = []
-    impl = type(jnp.zeros(1))
-    real = impl.copy_to_host_async
-    monkeypatch.setattr(
-        impl, "copy_to_host_async",
-        lambda self: (events.append(("ask", id(self), self.shape)), real(self))[1],
+    cfg = dataclasses.replace(
+        configs.REGISTRY["breakout"], batch_size=2, unroll_length=2
     )
-    read = learner_mod.owned_array
-    monkeypatch.setattr(
-        learner_mod, "owned_array",
-        lambda leaf: (events.append(("read", id(leaf), leaf.shape)), read(leaf))[1],
-    )
-    net, config = network
-    cfg = small_cfg()
+    walk = _Walk(monkeypatch)
     agent = configs.make_agent(cfg)
-    obs, first, state = _inputs(net, config, 9, ((4, 0),))
-    structure = jax.tree.structure(agent.initial_state(1))
     learner = Learner(
         agent=agent, optimizer=configs.make_optimizer(cfg),
         config=configs.make_learner_config(cfg),
         example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
     )
+    shapes = [x.shape for x in jax.tree.leaves(learner.params)]
+    assert len(shapes) == 48
+    sequences = [list(walk.events)]
     learner.start()
     try:
+        state = jax.tree.map(np.asarray, agent.initial_state(1))
         for _ in range(2):
-            for i in range(B):
-                learner.enqueue(Trajectory(
-                    obs=obs[:, i], first=first[:, i],
-                    actions=np.zeros(T - 1, np.int32),
-                    behaviour_logits=np.zeros((T - 1, 6), np.float32),
-                    rewards=np.ones(T - 1, np.float32),
-                    cont=np.ones(T - 1, np.float32),
-                    agent_state=jax.tree.unflatten(
-                        structure, [leaf[i : i + 1] for leaf in state]
-                    ),
-                    task=0,
-                ))
-            learner.step_once(timeout=300)
+            learner.enqueue(Trajectory(
+                obs=np.zeros((3, 84, 84, 4), np.uint8),
+                first=np.zeros(3, bool), actions=np.zeros(2, np.int32),
+                behaviour_logits=np.zeros((2, 4), np.float32),
+                rewards=np.ones(2, np.float32), cont=np.ones(2, np.float32),
+                agent_state=state, task=0,
+            ))
+        del walk.events[:]
+        learner.step_once(timeout=300)
         learner.drain()
+        sequences.append(list(walk.events))
+    finally:
+        learner.stop()
+    for events in sequences:  # the construction's version, the step's
+        assert [(e[0], e[2]) for e in events] == (
+            [("ask", shape) for shape in shapes]
+            + [("read", shape) for shape in shapes]
+        )
+        assert [e[1] for e in events[:48]] == [e[1] for e in events[48:]]
+
+
+@pytest.mark.parametrize("shape,sharded,want", [
+    ((2560, 10240), False, 368),   # 105 MB: 7 pieces of 15.1 MB
+    ((10240, 2560), False, 1464),  # 105 MB: 6 x 1464 rows and 1456
+    ((5120, 2560), False, 1280),   # 52 MB: 4 even pieces
+    ((2560, 2560), False, 1280),   # 26 MB: 2
+    ((3136, 512), False, 0),       # 6.4 MB fits a piece: whole
+    ((8 << 20,), False, 4 << 20),  # one axis, 32 MiB: 2 pieces
+    ((16, 1 << 20), False, 8),     # 8 rows alone are 32 MiB: cut to 8 rows
+    ((4, 8 << 20), False, 0),      # under 8 rows there is nothing to cut
+    ((2560, 10240), True, 0),      # sharded over a mesh: one piece
+])
+def test_piece_rows(shape, sharded, want):
+    """A piece is a quarter of the bound, its rows a multiple of 8 and
+    the pieces of a leaf as even as that allows; a leaf that fits, has
+    under 8 rows a piece or is sharded is one piece."""
+    import types
+
+    from torched_impala_tpu.runtime.learner import (
+        SNAPSHOT_COPY_AHEAD_BYTES, _piece_rows,
+    )
+
+    leaf = types.SimpleNamespace(
+        shape=shape, nbytes=4 * int(np.prod(shape)),
+        sharding=types.SimpleNamespace(is_fully_replicated=not sharded),
+    )
+    rows = _piece_rows(leaf)
+    assert rows == want
+    piece, row_bytes = SNAPSHOT_COPY_AHEAD_BYTES // 4, leaf.nbytes // shape[0]
+    if rows:
+        assert rows % 8 == 0 and rows < shape[0]
+        assert rows * row_bytes <= max(piece, 8 * row_bytes)
+
+
+def test_a_leaf_sharded_over_the_mesh_is_never_cut(monkeypatch):
+    """On four devices (data 2 x model 2) under a bound of 512 bytes: a
+    replicated leaf over a piece travels in blocks of rows, a leaf that
+    is sharded over the model axis is one piece whatever its size, and
+    both are published bit for bit."""
+    import optax
+
+    from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
+    from torched_impala_tpu.parallel import make_mesh
+    from torched_impala_tpu.runtime.learner import Learner, LearnerConfig
+    from torched_impala_tpu.runtime.types import Trajectory
+
+    walk = _Walk(monkeypatch, 512)
+    agent = Agent(
+        ImpalaNet(num_actions=3, torso=MLPTorso(hidden_sizes=(32, 32)))
+    )
+    learner = Learner(
+        agent=agent, optimizer=optax.sgd(1e-2),
+        config=LearnerConfig(batch_size=4, unroll_length=3),
+        example_obs=np.zeros((8,), np.float32), rng=jax.random.key(0),
+        mesh=make_mesh(num_data=2, num_model=2),
+    )
+    sharded = {
+        x.shape for x in jax.tree.leaves(learner.params)
+        if not x.sharding.is_fully_replicated
+    }
+    replicated = {
+        x.shape for x in jax.tree.leaves(learner.params)
+        if x.sharding.is_fully_replicated and x.nbytes > 128
+    }
+    assert (32, 32) in sharded and replicated == {(32, 3)}
+    learner.start()
+    try:
+        for i in range(4):
+            learner.enqueue(Trajectory(
+                obs=np.full((4, 8), i, np.float32), first=np.zeros(4, bool),
+                actions=np.zeros(3, np.int32),
+                behaviour_logits=np.zeros((3, 3), np.float32),
+                rewards=np.ones(3, np.float32), cont=np.ones(3, np.float32),
+                agent_state=(), task=0,
+            ))
+        del walk.events[:]
+        learner.step_once(timeout=120)
+        learner.drain()
+        read = [e[2] for e in walk.events if e[0] == "read"]
         version, published = learner.param_store.get()
-        assert version == 2 * B * (T - 1)
+        assert version == 12
         for got, own in zip(
             jax.tree.leaves(published), jax.tree.leaves(learner.params)
         ):
-            np.testing.assert_array_equal(got, np.asarray(own))
-            assert got.flags.owndata
+            assert got.tobytes() == np.asarray(own).tobytes()
+            assert got.flags.owndata is (got.shape != (32, 3))
+        _alone([jax.tree.leaves(published)])
     finally:
         learner.stop()
-    # three versions (construction's and two steps'), every leaf read once
-    leaves = len(jax.tree.leaves(learner.params))
-    assert sum(e[0] == "read" for e in events) == 3 * leaves
-    unread, most = {}, 0
-    for kind, key, shape in events:
-        if kind == "ask":
-            unread[key] = int(np.prod(shape)) * 4
-        else:
-            unread.pop(key, None)
-        most = max(most, sum(unread.values()))
-    assert 0 < most <= bound and not unread
-    # the core's input projection, 128 KB: requested where it fits
-    assert (("ask", (512, 64)) in {(e[0], e[2]) for e in events}) is (
-        bound > 512 * 64 * 4
-    )
-    if bound == 64 << 20:
-        # a tree that fits: every leaf requested before the first is read
-        assert [e[0] for e in events].index("read") == leaves
+    for shape in sharded:
+        assert shape in read
+    assert (32, 3) not in read and read.count((8, 3)) == 4
+
+
+def test_a_held_version_keeps_its_bytes(network):
+    """The readers' guarantee: a tree taken from `param_store.get()` and
+    held while `keep_versions + 3` later versions are published still
+    reads its own version's bytes: no later landing writes where a
+    reader may still look."""
+    from torched_impala_tpu.runtime import Learner, learner as learner_mod
+
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", CUT)
+        cfg = small_cfg()
+        agent = configs.make_agent(cfg)
+        learner = Learner(
+            agent=agent, optimizer=configs.make_optimizer(cfg),
+            config=configs.make_learner_config(cfg),
+            example_obs=configs.example_obs(cfg), rng=jax.random.key(0),
+        )
+        learner.start()
+        try:
+            unrolls = _unrolls(network, agent)
+            for unroll in unrolls:
+                learner.enqueue(unroll)
+            learner.step_once(timeout=300)
+            learner.drain()
+            version, held = learner.param_store.get()
+            assert version == B * (T - 1)
+            then = [x.tobytes() for x in jax.tree.leaves(held)]
+            later = learner.param_store.keep_versions + 3
+            for _ in range(later):
+                for unroll in unrolls:
+                    learner.enqueue(unroll)
+                learner.step_once(timeout=300)
+            learner.drain()
+            assert learner.param_store.version == (later + 1) * version
+            assert version not in learner.param_store.versions()
+            newest = jax.tree.leaves(learner.param_store.get()[1])
+        finally:
+            learner.stop()
+    now = [x.tobytes() for x in jax.tree.leaves(held)]
+    assert now == then
+    assert any(a != b.tobytes() for a, b in zip(then, newest))
+    # the held version's slab was never handed out again: a landing
+    # writes where no reader can be looking
+    assert len(_alone([jax.tree.leaves(held), newest])) == 2
+
+
+def test_a_slab_is_used_again_only_after_its_version_died():
+    """`_Slabs` hands a slab out again when the last view of the version
+    that had it is gone, whoever held it (a leaf, or a view of a leaf's
+    view), and not before."""
+    from torched_impala_tpu.runtime.learner import _Slabs
+
+    slabs = _Slabs()
+    first = slabs.take(4096)
+    where = first.ctypes.data
+    leaf = first[64:128].view(np.float32).reshape(4, 4)
+    inner = leaf[1:3][:, :2]  # a reader's view of a view
+    second = slabs.take(4096)
+    assert second.ctypes.data != where
+    del first, leaf
+    assert slabs.take(4096).ctypes.data not in (where, second.ctypes.data)
+    inner[:] = 7.0
+    del inner  # the last view of the first version
+    again = slabs.take(4096)
+    assert again.ctypes.data == where
+    del again  # a tree of another size does not get it
+    other = slabs.take(8192)
+    assert other.nbytes == 8192 and other.ctypes.data != where
+
+
+def test_slabs_taken_and_dropped_on_many_threads_are_never_shared():
+    """More threads than cores take slabs, stamp them, hold them a moment
+    and drop them (finalizers run on whichever thread lets the last view
+    go): a slab in someone's hands never shows another's stamp."""
+    import sys
+    import threading
+    import time
+
+    from torched_impala_tpu.runtime.learner import _Slabs
+
+    slabs, wrong, deadline = _Slabs(), [], time.monotonic() + 2.0
+    interval = sys.getswitchinterval()
+
+    def worker(stamp):
+        held = []
+        while time.monotonic() < deadline and not wrong:
+            slab = slabs.take(1 << 12)
+            slab[:] = stamp
+            held.append(slab[8:72].view(np.float32))  # a reader's view
+            del slab
+            if len(held) > 3:
+                view = held.pop(0)
+                if (view.view(np.uint8) != stamp).any():
+                    wrong.append(stamp)
+
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=worker, args=(i + 1,)) for i in range(24)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and not wrong
 
 
 def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
@@ -514,3 +952,4 @@ def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
     stacked = stack_trajectories(second).agent_state
     assert stacked.k_full.shape == (envs, 1, 8, 16)
     assert stacked.conv.shape == (envs, 2, 3, 128)
+

@@ -172,8 +172,9 @@ def test_every_crossing_version_is_published_once_in_order_with_its_own_bytes(
         # the parameters of that step, not of the step after it ...
         for got, own in zip(at_publish, params_after[version]):
             assert got.tobytes() == own.tobytes()
-        # ... in memory of their own, which no later step's donation has
-        # deleted or written over since
+        # ... in memory of their own (no leaf of this tree is over a
+        # piece: a cut leaf would be a view of its version's slab), which
+        # no later step's donation has deleted or written over since
         for leaf, then in zip(jax.tree.leaves(params), at_publish):
             assert isinstance(leaf, np.ndarray) and leaf.flags.owndata
             assert leaf.tobytes() == then.tobytes()

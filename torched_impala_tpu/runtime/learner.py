@@ -19,7 +19,10 @@ stacks), TPU-first:
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import dataclasses
+import functools
+import itertools
 import queue
 import re
 import sys
@@ -233,8 +236,8 @@ class _InFlight(NamedTuple):
     version: int  # num_frames after the step: what its parameters publish as
     dispatch_t0_ns: int  # where its `learner/step_in_flight` span opens
     probe: list  # one device leaf that is ready when the step (and copy) is
-    snapshot: Any = None  # on-device copy of its parameters
-    requested: int = 0  # how many of its leaves have their D2H requested
+    snapshot: Any = None  # on-device copy of its parameters, in pieces
+    requested: int = 0  # how many of its pieces have their D2H requested
     ring_slot: int = -1  # donated ring slot to recycle once it completed
     logs: Optional[dict] = None  # its log scalars, when it crossed log_interval
     meta: Optional[BatchLineage] = None
@@ -248,50 +251,201 @@ class _InFlight(NamedTuple):
 # it, grew the machine's count 0.4-0.65 GiB a step until a 30 s window
 # met its 40 GiB, 256 MiB ahead as much, 16-64 MiB ahead 0.03-0.04 GiB a
 # step (my chip runs, PR 34). The 6 MB of an LSTM preset fit whole, so
-# every leaf is requested as the snapshot is queued; a larger tree has
-# the leaves that fit requested and the rest as the ones before them are
-# read, and a leaf over the bound is one synchronous copy.
+# every leaf is requested as the snapshot is queued. A larger tree
+# travels in pieces of a quarter of the bound (`_piece_rows`): while one
+# is read the next three stand requested, so the link and the host's
+# copy overlap under the same bound (PR 35).
 SNAPSHOT_COPY_AHEAD_BYTES = 64 << 20
 
 
-def _request_copies(leaves, begin: int, unread: int):
-    """Request the D2H of `leaves[begin:]`, in order, for as long as the
+def _piece_rows(leaf) -> int:
+    """How many rows of axis 0 a piece of `leaf` holds in a snapshot; 0
+    where the leaf is one piece: it fits a quarter of
+    `SNAPSHOT_COPY_AHEAD_BYTES`, or it is neither whole on one device
+    nor fully replicated. Rows are a multiple of 8, so that no tile of a
+    piece is padded, and the pieces of a leaf are as even as that allows;
+    where 8 rows alone exceed a piece, a piece is 8 rows."""
+    piece = SNAPSHOT_COPY_AHEAD_BYTES // 4
+    if leaf.nbytes <= piece or not leaf.sharding.is_fully_replicated:
+        return 0
+    fit = max(8, piece * leaf.shape[0] // leaf.nbytes // 8 * 8)
+    count = -(-leaf.shape[0] // fit)
+    rows = -(-leaf.shape[0] // (8 * count)) * 8
+    return rows if rows < leaf.shape[0] else 0
+
+
+def _request_copies(pieces, begin: int, unread: int):
+    """Request the D2H of `pieces[begin:]`, in order, for as long as the
     bytes requested and not yet read stay within
     `SNAPSHOT_COPY_AHEAD_BYTES`: `(where it stopped, those bytes)`."""
     while (
-        begin < len(leaves)
-        and unread + leaves[begin].nbytes <= SNAPSHOT_COPY_AHEAD_BYTES
+        begin < len(pieces)
+        and unread + pieces[begin].nbytes <= SNAPSHOT_COPY_AHEAD_BYTES
     ):
-        leaves[begin].copy_to_host_async()
-        unread += leaves[begin].nbytes
+        pieces[begin].copy_to_host_async()
+        unread += pieces[begin].nbytes
         begin += 1
     return begin, unread
 
 
-def _snapshot_to_host(snapshot, requested: int):
-    """`host_snapshot` of a device tree whose first `requested` leaves
-    have their D2H requested: leaf by leaf in the tree's order, the next
-    leaves' copies requested as the bound allows."""
-    leaves, treedef = jax.tree.flatten(snapshot)
-    unread = sum(leaf.nbytes for leaf in leaves[:requested])
-    host = []
-    for i, leaf in enumerate(leaves):
-        host.append(owned_array(leaf))
-        if i < requested:
-            unread -= leaf.nbytes
-        requested, unread = _request_copies(
-            leaves, max(requested, i + 1), unread
-        )
-    return jax.tree.unflatten(treedef, host)
+# Threads that read the pieces of cut leaves (`_snapshot_to_host`): the
+# wait for a piece's bytes and the copy into its leaf both release the
+# GIL. One thread lands 1.76 GB in 0.23 s, two in 0.17 s, beside a
+# device step of 0.2 s (my chip runs, PR 35).
+_LANDING_THREADS = 2
 
 
-@jax.jit
-def _publish_snapshot(params):
+class _Lease:
+    """The owner every view of one version's slab leads back to (numpy
+    follows a view's `base` down to the first object that is no array):
+    it dies with the last of them, and `_Slabs` then has the memory back."""
+
+    def __init__(self, raw: np.ndarray):
+        self._raw = raw
+
+    def __buffer__(self, flags):
+        return memoryview(self._raw)
+
+
+class _Slabs:
+    """Host memory for the cut leaves of published versions: one slab a
+    version, its cut leaves views of it, and a slab used again only when
+    nothing can read the version that had it any more (the `ParamStore`
+    keeps four, actors and the serving registry hold theirs as long as
+    they like). So a landing writes memory the process has touched: on
+    the chip's host a copy into pages it has not runs at 0.9-2.9 GB/s
+    and varies with the machine's state, into touched ones at 18 GB/s
+    (my chip runs, PR 35); and no reader sees another version's bytes."""
+
+    def __init__(self):
+        self._free: list = []
+        self._lock = threading.Lock()  # a finalizer runs on any thread
+
+    def take(self, nbytes: int) -> np.ndarray:
+        """`nbytes` of uint8 that are the caller's until the last view
+        of them is gone."""
+        with self._lock:
+            raw = self._free.pop() if self._free else None
+        if raw is None or raw.nbytes != nbytes:
+            raw = np.empty(nbytes, np.uint8)
+        lease = _Lease(raw)
+        weakref.finalize(lease, self._give, raw).atexit = False
+        return np.frombuffer(lease, np.uint8)
+
+    def _give(self, raw: np.ndarray) -> None:
+        with self._lock:
+            self._free.append(raw)
+
+
+def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
+    """`host_snapshot` of a version that is on the device as one tuple of
+    pieces a leaf (`_publish_snapshot`), the first `requested` pieces
+    with their D2H requested: `(host leaves, the most bytes that stood
+    requested and unread)`. Piece by piece in the tree's order, the next
+    pieces' copies requested as the bound allows, so the link moves them
+    while the ones before are written into their leaf; a piece over the
+    bound is one synchronous copy. The pieces of a cut leaf are written
+    into its part of the version's slab (`slabs`), its blocks of rows,
+    on the threads of `readers` (an executor) where there is one. A leaf
+    that is one piece is read here as `owned_array` gives it, unless a
+    reader is busy: this thread would then wait behind the pieces in
+    flight and request nothing meanwhile, so the leaf goes to the readers
+    too. A tree with no cut leaf is so read here alone, call for call as
+    before there were pieces. Each leaf is one C-contiguous array whose
+    bytes nobody else writes while anything can read them: its own, or
+    its version's slab. The cut leaves of `snapshot` are emptied: such a
+    piece is let go of as it is read, so its buffers on the device and on
+    the host are freed then, and the runtime's host side of a version is
+    what stands requested, not the version (whole leaves die with the
+    caller's list as they always did: freeing 48 small buffers one by
+    one cost an LSTM preset's landing 3 ms on the chip)."""
+    flat = [piece for leaf in snapshot for piece in leaf]
+    leaf_of = [i for i, leaf in enumerate(snapshot) for _ in leaf]
+    # Where each piece lands: a whole leaf in an array of its own (None
+    # here), a cut leaf's in their rows of its part of the slab, each
+    # part on a cache line of its own.
+    sizes = [
+        sum(piece.nbytes for piece in leaf) if len(leaf) > 1 else 0
+        for leaf in snapshot
+    ]
+    starts = list(
+        itertools.accumulate((-(-size // 64) * 64 for size in sizes), initial=0)
+    )
+    slab = slabs.take(starts[-1]) if starts[-1] else None
+    host, blocks = [], []
+    for leaf, size, start in zip(snapshot, sizes, starts):
+        if not size:
+            host.append(None)
+            blocks.append(None)
+            continue
+        part = slab[start : start + size].view(leaf[0].dtype)
+        host.append(part.reshape((-1,) + leaf[0].shape[1:]))
+        row = 0
+        for piece in leaf:
+            blocks.append(host[-1][row : row + piece.shape[0]])
+            row += piece.shape[0]
+    snapshot[:] = [leaf if len(leaf) == 1 else () for leaf in snapshot]
+    unread = peak = sum(piece.nbytes for piece in flat[:requested])
+    pending = collections.deque()  # (a read, its bytes, the whole leaf it is)
+
+    def landed():
+        nonlocal unread
+        read, nbytes, whole = pending.popleft()
+        array = read.result()
+        unread -= nbytes
+        if whole is not None:
+            host[whole] = array
+
+    for at, block in enumerate(blocks):
+        piece = flat[at]
+        nbytes = piece.nbytes
+        while at >= requested:
+            requested, unread = _request_copies(flat, requested, unread)
+            if at < requested:
+                break
+            if not pending:  # alone over the bound: not requested
+                requested, nbytes = at + 1, 0
+                break
+            landed()
+        peak = max(peak, unread)
+        if block is not None:
+            flat[at] = None
+        if readers is None or (block is None and not pending):
+            array = owned_array(piece, block)
+            unread -= nbytes
+            if block is None:
+                host[leaf_of[at]] = array
+        else:
+            whole = leaf_of[at] if block is None else None
+            pending.append(
+                (readers.submit(owned_array, piece, block), nbytes, whole)
+            )
+            while pending and pending[0][0].done():
+                landed()
+        requested, unread = _request_copies(flat, requested, unread)
+        peak = max(peak, unread)
+    while pending:
+        landed()
+    return host, peak
+
+
+@functools.partial(jax.jit, static_argnames="rows")
+def _publish_snapshot(params, rows):
     """Step k's parameters in fresh buffers, as one program queued right
-    behind step k. Step k+1 donates the originals, so the copy to the host
-    reads these instead. `jnp.copy`, not an identity: a jitted identity
-    forwards its inputs and copies nothing."""
-    return jax.tree.map(jnp.copy, params)
+    behind step k: a list over the tree's leaves, each a tuple of pieces.
+    Step k+1 donates the originals, so the copy to the host reads these
+    instead. A leaf with `rows[i]` rows a piece (`_piece_rows`) comes as
+    its blocks of rows, each an output written straight from the leaf (no
+    whole copy beside them), any other as one `jnp.copy`, not an
+    identity: a jitted identity forwards its inputs and copies nothing."""
+    return [
+        tuple(
+            leaf[at : at + step] for at in range(0, leaf.shape[0], step)
+        )
+        if step
+        else (jnp.copy(leaf),)
+        for leaf, step in zip(jax.tree.leaves(params), rows)
+    ]
 
 
 # Sanitizer for flax module names -> health gauge sub-keys
@@ -796,8 +950,8 @@ class Learner:
         #   bookkeeping   the rest of _finish_step: counters, lineage,
         #                 step k-1's slot release and logger, post_step
         #   publish_copy  two pieces: queueing step k's snapshot program
-        #                 and its D2H; after the wait, step k-1's copies,
-        #                 host_snapshot, ParamStore.publish
+        #                 and its D2H; after the wait, step k-1's copies
+        #                 piece by piece into its leaves, ParamStore.publish
         #   step_wait     blocked until the device has finished step k-1
         #                 and its snapshot (steps that owe the host
         #                 something: a publish, log scalars, a ring slot)
@@ -815,6 +969,16 @@ class Learner:
         self._m_bookkeeping = reg.timer("learner/bookkeeping")
         self._m_step_wait = reg.timer("learner/step_wait")
         self._m_publish_copy = reg.timer("learner/publish_copy")
+        # How the last version reached the host (`_snapshot_to_host`):
+        # its pieces (its leaf count where nothing was cut), the most
+        # bytes that stood requested and unread (within
+        # `SNAPSHOT_COPY_AHEAD_BYTES`), and its bytes over the time its
+        # landing took, in GB/s.
+        self._m_publish_pieces = reg.gauge("learner/publish_pieces")
+        self._m_publish_outstanding = reg.gauge(
+            "learner/publish_outstanding_peak_bytes"
+        )
+        self._m_publish_rate = reg.gauge("learner/publish_gb_per_s")
         self._m_outside_step = reg.timer("learner/outside_step")
         self._m_loop_overhead = reg.timer("learner/loop_overhead")
         self._m_dispatch_lead = reg.timer("learner/dispatch_lead")
@@ -833,6 +997,11 @@ class Learner:
         # set_state() drain from another thread than the step loop's.
         self._in_flight: Optional[_InFlight] = None
         self._settle_lock = threading.RLock()
+        # Where the cut leaves of a version land, and the threads that
+        # read their pieces: started and joined with the learner (none:
+        # they are read on the caller's).
+        self._slabs = _Slabs()
+        self._readers: Optional[concurrent.futures.Executor] = None
         self._settled_ns = [0, 0, 0]
         self._m_steps_per_sec = reg.gauge("learner/steps_per_sec")
         self._m_param_lag = reg.gauge("learner/param_lag_frames")
@@ -2214,6 +2383,11 @@ class Learner:
                 target=self._batcher_loop, name="batcher", daemon=True
             )
             self._batcher_thread.start()
+        with self._settle_lock:
+            if self._readers is None:
+                self._readers = concurrent.futures.ThreadPoolExecutor(
+                    _LANDING_THREADS, thread_name_prefix="learner-landing"
+                )
 
     def stop(self) -> None:
         self._stop.set()
@@ -2233,6 +2407,10 @@ class Learner:
             logging.getLogger(__name__).exception(
                 "stop: settling the step in flight failed"
             )
+        with self._settle_lock:
+            readers, self._readers = self._readers, None
+        if readers is not None:
+            readers.shutdown(wait=True)
 
     # ---- stepping ------------------------------------------------------
 
@@ -2241,14 +2419,34 @@ class Learner:
         actors, blocking: construction and `set_state`, where nothing is
         in flight. The step loop publishes through `_settle`."""
         t0 = time.monotonic_ns()
-        requested, _ = _request_copies(jax.tree.leaves(self._params), 0, 0)
-        self.param_store.publish(
-            self.num_frames, _snapshot_to_host(self._params, requested)
-        )
+        leaves = jax.tree.leaves(self._params)
+        requested, _ = _request_copies(leaves, 0, 0)
+        with self._settle_lock:
+            self._land(
+                self.num_frames, [(leaf,) for leaf in leaves], requested, t0
+            )
         dur = time.monotonic_ns() - t0
         self._m_publish.observe(dur / 1e9)
         self._tracer.complete(
             "learner/publish", t0, dur, {"version": self.num_frames}
+        )
+
+    def _land(self, version: int, snapshot, requested: int, t0: int) -> None:
+        """Bring a version's pieces to the host (`_snapshot_to_host`)
+        and publish it; `t0`: from where its landing is timed. Under
+        `_settle_lock`, where `stop()` takes the readers away."""
+        pieces = sum(len(leaf) for leaf in snapshot)
+        host, peak = _snapshot_to_host(
+            snapshot, requested, self._slabs, self._readers
+        )
+        self.param_store.publish(
+            version, jax.tree.unflatten(jax.tree.structure(self._params), host)
+        )
+        self._m_publish_pieces.set(pieces)
+        self._m_publish_outstanding.set(peak)
+        self._m_publish_rate.set(
+            sum(leaf.nbytes for leaf in host)
+            / max(time.monotonic_ns() - t0, 1)
         )
 
     def drain(self) -> None:
@@ -2294,11 +2492,11 @@ class Learner:
             if done.snapshot is not None:
                 # Published trees own their bytes (types.owned_array).
                 # The D2H of what fits the bound was requested when the
-                # snapshot was queued, so this mostly finds those bytes
-                # on the host already.
-                self.param_store.publish(
-                    done.version,
-                    _snapshot_to_host(done.snapshot, done.requested),
+                # snapshot was queued, so this finds those bytes on the
+                # host already; of a larger tree the next pieces travel
+                # while the one before them is written into its leaf.
+                self._land(
+                    done.version, done.snapshot, done.requested, ready
                 )
                 landed = time.monotonic_ns()
                 self._m_publish.observe((landed - t0) / 1e9)
@@ -2719,12 +2917,15 @@ class Learner:
             # dispatched, and its D2H requested at once (before any leaf
             # is materialised: np.asarray alone would serialise one
             # synchronous transfer per leaf), so the bytes travel as soon
-            # as the device has them; of a large tree, the leaves that
+            # as the device has them; of a large tree, the pieces that
             # fit `SNAPSHOT_COPY_AHEAD_BYTES`.
-            snapshot = _publish_snapshot(self._params)
-            leaves = jax.tree.leaves(snapshot)
-            requested, _ = _request_copies(leaves, 0, 0)
-            probe = leaves[:1]
+            snapshot = _publish_snapshot(
+                self._params,
+                tuple(map(_piece_rows, jax.tree.leaves(self._params))),
+            )
+            pieces = [piece for leaf in snapshot for piece in leaf]
+            requested, _ = _request_copies(pieces, 0, 0)
+            probe = pieces[:1]
             queued = time.monotonic_ns()
             self._tracer.complete(
                 "learner/publish_copy", queueing, queued - queueing, step_tag

@@ -8,7 +8,7 @@ started from (`learner.py:96`).
 
 from __future__ import annotations
 
-from typing import Any, NamedTuple
+from typing import Any, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -73,9 +73,14 @@ def host_snapshot(tree: Any) -> Any:
     return jax.tree.map(owned_array, tree)
 
 
-def owned_array(leaf: Any) -> np.ndarray:
-    """One leaf of `host_snapshot`: host numpy that owns its bytes."""
+def owned_array(leaf: Any, out: Optional[np.ndarray] = None) -> np.ndarray:
+    """One leaf of `host_snapshot`: host numpy that owns its bytes. With
+    `out`, the bytes are written there instead (the learner lands a
+    piece of a leaf in its block of rows)."""
     arr = np.asarray(leaf)
+    if out is not None:
+        np.copyto(out, arr)
+        return out
     return arr if arr.flags.owndata else np.array(arr, copy=True)
 
 
