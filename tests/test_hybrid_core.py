@@ -188,6 +188,9 @@ def test_step_mode_equals_the_unroll(network, resets):
     )
     assert isinstance(s, HybridCoreState)
     for name, a, b in zip(s._fields, s, final):
+        if a is None:  # a kind of state no layer of this core has
+            assert b is None, name
+            continue
         np.testing.assert_allclose(a, b, rtol=0, atol=2e-5, err_msg=name)
 
 
@@ -319,7 +322,16 @@ def test_attention_kernel_resolution(forced, want):
     assert dict(configs.make_agent(cfg).net.hybrid)["attention_kernel"] == want
 
 
-def _mlp_agent():
+# The routed core's kinds (`pong_lfm2_moe`): rotary attention, gated short
+# convolutions, RMSNorm and a mixture of 8 experts of which 4 are held.
+ROUTED = (
+    ("layers", ("full_rope", "short_conv", "short_conv")), ("norm", "rms"),
+    ("mlp", "moe"), ("num_experts", 8), ("experts_per_token", 2),
+    ("experts_held", (2, 4)), ("expert_width", 16),
+)
+
+
+def _mlp_agent(core="phi4flash"):
     from torched_impala_tpu.models import Agent, ImpalaNet
     from torched_impala_tpu.models.torsos import MLPTorso
 
@@ -329,7 +341,7 @@ def _mlp_agent():
             ("d_model", 32), ("num_heads", 4), ("num_kv_heads", 2),
             ("head_dim", 8), ("window", 4), ("full_cache", 8),
             ("d_intermediate", 64), ("d_inner", 128), ("dt_rank", 2),
-        ),
+        ) + (ROUTED if core == "lfm2_moe" else ()),
     ))
 
 
@@ -359,17 +371,20 @@ def test_the_actors_step_threads_the_mixed_carry():
     np.testing.assert_allclose(ssm2[2], ssm1[2], atol=1e-6)  # started anew
 
 
-def test_the_mixed_carry_lives_on_the_policy_server():
+@pytest.mark.parametrize("core", ["phi4flash", "lfm2_moe"])
+def test_the_mixed_carry_lives_on_the_policy_server(core):
     """Per-client slots of the carry, leaf by leaf: a client stepping a
     sequence through the server gets exactly the actions of a direct
     `agent.step` loop chaining its own carry (tests/test_serving.py's
-    LSTM case, with scan states, convolution windows and two caches)."""
+    LSTM case, with scan states, convolution windows and two caches; or
+    the routed core's cache and short convolutions' inputs, its `None`
+    kinds passing through)."""
     from torched_impala_tpu.runtime.param_store import ParamStore
     from torched_impala_tpu.serving import InProcessClient, PolicyServer
     from torched_impala_tpu.serving.registry import VersionRegistry
     from torched_impala_tpu.telemetry.registry import Registry
 
-    agent = _mlp_agent()
+    agent = _mlp_agent(core)
     seq = np.random.default_rng(3).normal(size=(10, 5)).astype(np.float32)
     params = agent.init_params(jax.random.key(0), seq[0])
     store = ParamStore()
@@ -726,11 +741,16 @@ def test_an_lstm_presets_copies_are_all_asked_then_all_read(monkeypatch):
     ((16, 1 << 20), False, 8),     # 8 rows alone are 32 MiB: cut to 8 rows
     ((4, 8 << 20), False, 0),      # under 8 rows there is nothing to cut
     ((2560, 10240), True, 0),      # sharded over a mesh: one piece
+    ((8, 2048, 1792), False, 1),   # 8 held experts, 117 MB: one a piece
+    ((8, 1792, 2048), False, 1),
+    ((2, 3136, 4096), False, 1),   # axis 0 is not tiled: 51 MB pieces
+    ((64, 256, 512), False, 32),   # 33.6 MB: 2 pieces of 32 rows
 ])
 def test_piece_rows(shape, sharded, want):
-    """A piece is a quarter of the bound, its rows a multiple of 8 and
-    the pieces of a leaf as even as that allows; a leaf that fits, has
-    under 8 rows a piece or is sharded is one piece."""
+    """A piece is a quarter of the bound and the pieces of a leaf as even
+    as its rows allow: a multiple of 8 rows where axis 0 is tiled (one or
+    two axes), any count in a leaf of more axes; a leaf that fits, has
+    under 8 rows of two axes or is sharded is one piece."""
     import types
 
     from torched_impala_tpu.runtime.learner import (
@@ -744,9 +764,10 @@ def test_piece_rows(shape, sharded, want):
     rows = _piece_rows(leaf)
     assert rows == want
     piece, row_bytes = SNAPSHOT_COPY_AHEAD_BYTES // 4, leaf.nbytes // shape[0]
+    align = 8 if len(shape) <= 2 else 1
     if rows:
-        assert rows % 8 == 0 and rows < shape[0]
-        assert rows * row_bytes <= max(piece, 8 * row_bytes)
+        assert rows % align == 0 and rows < shape[0]
+        assert rows * row_bytes <= max(piece, align * row_bytes)
 
 
 def test_a_leaf_sharded_over_the_mesh_is_never_cut(monkeypatch):
@@ -917,7 +938,8 @@ def test_slabs_taken_and_dropped_on_many_threads_are_never_shared():
     assert not any(t.is_alive() for t in threads) and not wrong
 
 
-def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
+@pytest.mark.parametrize("core", ["phi4flash", "lfm2_moe"])
+def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry(core):
     """Unrolls collected in step mode: every trajectory starts from its
     own env's row of every leaf, and the learner's stacking puts E of them
     back together; the second cycle starts from the carried state."""
@@ -926,7 +948,7 @@ def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
     from torched_impala_tpu.runtime.learner import stack_trajectories
 
     steps, envs = 4, 3
-    agent = _mlp_agent()
+    agent = _mlp_agent(core)
     params = agent.init_params(jax.random.key(0), jnp.zeros((4,), jnp.float32))
     store = ParamStore()
     store.publish(0, params)
@@ -942,14 +964,24 @@ def test_the_vector_actor_hands_each_env_its_row_of_the_mixed_carry():
     fresh = agent.initial_state(1)
     for traj in pushed:
         assert isinstance(traj.agent_state, HybridCoreState)
-        for leaf, like in zip(traj.agent_state, fresh):
+        assert jax.tree.structure(traj.agent_state) == jax.tree.structure(fresh)
+        for leaf, like in zip(
+            jax.tree.leaves(traj.agent_state), jax.tree.leaves(fresh)
+        ):
             assert leaf.shape == like.shape and leaf.dtype == like.dtype
     second = pushed[envs:]
     assert all(int(t.agent_state.pos[0]) == steps for t in second)
-    assert all(np.abs(np.asarray(t.agent_state.ssm)).max() > 0 for t in second)
+    moving = "sconv" if core == "lfm2_moe" else "ssm"
+    assert all(
+        np.abs(np.asarray(getattr(t.agent_state, moving))).max() > 0
+        for t in second
+    )
     assert all((np.asarray(t.agent_state.full_seg)[0, -steps:] >= 1).all()
                for t in second)
     stacked = stack_trajectories(second).agent_state
     assert stacked.k_full.shape == (envs, 1, 8, 16)
-    assert stacked.conv.shape == (envs, 2, 3, 128)
-
+    if core == "lfm2_moe":
+        assert stacked.sconv.shape == (envs, 2, 2, 32)
+        assert stacked.conv is None and stacked.win_seg is None
+    else:
+        assert stacked.conv.shape == (envs, 2, 3, 128)

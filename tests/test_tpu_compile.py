@@ -350,6 +350,40 @@ def test_selective_scan(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 600e6
 
 
+@pytest.mark.parametrize("d,f", [(2048, 1792), (1792, 2048)])
+def test_grouped_expert_products(one_chip, d, f):
+    """The held experts' products at the published widths
+    (`pong_lfm2_moe`: 8 experts of 2,048 x 1,792, B=2 x 2,048 positions,
+    4 of the router's 32 experts a position): forward, the input's
+    gradient and the weights' gradient, each one of XLA's own ragged-product
+    kernels (`%ragged-dot-*` custom calls)."""
+    from torched_impala_tpu.ops.grouped_matmul import (
+        capacity,
+        group_rows,
+        grouped_matmul,
+    )
+
+    n, k, held = 4096, 4, 8
+    m = capacity(n, k, held)
+
+    def loss(x, w, local):
+        _, rows = group_rows(local, held)
+        return jnp.sum(jnp.square(grouped_matmul(x, w, rows)))
+
+    text = _compile(
+        jax.grad(loss, argnums=(0, 1)),
+        _shape(one_chip, (m, d), jnp.bfloat16),
+        _shape(one_chip, (held, d, f), jnp.bfloat16),
+        _shape(one_chip, (n, k), jnp.int32),
+    )
+    calls = re.findall(
+        r"^\s*%(ragged-dot(?!-metadata)\S*) = \w+\[([\d,]+)\]", text, re.M
+    )
+    shapes = sorted(shape for _, shape in calls)
+    # forward [m, f], the input's gradient [m, d], the weights' [held, d, f]
+    assert shapes == sorted([f"{m},{f}", f"{m},{d}", f"{held},{d},{f}"]), calls
+
+
 # ---- four chips: the mesh path resolves to XLA by construction ---------
 
 
@@ -393,16 +427,20 @@ def test_mesh_resolves_kernels_to_xla(data_mesh):
         )
 
 
-def test_mesh_resolves_the_hybrid_cores_kernels_to_xla(data_mesh):
-    """The hybrid core under a mesh: the written-out attention mask and
-    the scan one step at a time; on one TPU device both kernels."""
+@pytest.mark.parametrize("routed", [False, True])
+def test_mesh_resolves_the_hybrid_cores_kernels_to_xla(data_mesh, routed):
+    """The hybrid core under a mesh: the written-out attention mask, the
+    scan one step at a time; on one TPU device the kernels. A routed core's
+    experts take XLA's ragged product either way."""
     from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
     from torched_impala_tpu.runtime.learner import resolve_kernels
+
+    moe = (("mlp", "moe"),) if routed else ()
 
     def agent():
         return Agent(ImpalaNet(
             num_actions=4, torso=MLPTorso(), core="hybrid",
-            hybrid=(("d_model", 64), ("attention_kernel", "pallas")),
+            hybrid=(("d_model", 64), ("attention_kernel", "pallas")) + moe,
         ))
 
     resolved_agent, _, resolved = resolve_kernels(
@@ -413,6 +451,7 @@ def test_mesh_resolves_the_hybrid_cores_kernels_to_xla(data_mesh):
     assert core["scan_kernel"] is False and core["d_model"] == 64
     assert resolved["attention"] == "einsum"
     assert resolved["selective_scan"] == "xla_scan"
+    assert resolved["moe"] == ("ragged_dot" if routed else None)
     one = Mesh(
         np.array(data_mesh.devices.flat[:1]).reshape(1, 1),
         ("data", "model"),
@@ -420,6 +459,7 @@ def test_mesh_resolves_the_hybrid_cores_kernels_to_xla(data_mesh):
     _, _, resolved = resolve_kernels(agent(), ImpalaLossConfig(), one)
     assert resolved["attention"] == "pallas"
     assert resolved["selective_scan"] == "pallas"
+    assert resolved["moe"] == ("ragged_dot" if routed else None)
 
 
 def test_vtrace_and_lstm_under_data_mesh(data_mesh):

@@ -140,6 +140,16 @@ class ExperimentConfig:
     hybrid_dt_rank: int = 160
     hybrid_dtype: str = "bfloat16"
     hybrid_remat: bool = True
+    # `hybrid_norm` "layer" | "rms"; `hybrid_mlp` "gated" | "moe": a router
+    # over `hybrid_experts` experts of width `hybrid_expert_width`,
+    # `hybrid_experts_per_token` a token, of which this learner holds
+    # `hybrid_experts_held` = (first, count).
+    hybrid_norm: str = "layer"
+    hybrid_mlp: str = "gated"
+    hybrid_experts: int = 32
+    hybrid_experts_per_token: int = 4
+    hybrid_experts_held: tuple = (0, 8)
+    hybrid_expert_width: int = 1792
     # Shard the unroll's time axis over this many devices (the 'seq' mesh
     # axis); 0 = off. Combined with dp_devices as a ('data','seq') mesh.
     sp_devices: int = 0
@@ -458,6 +468,12 @@ def make_agent(cfg: ExperimentConfig, mesh=None) -> Agent:
                 if cfg.transformer_dense_kernel != "auto"
                 else ("pallas" if on_tpu else "einsum"),
             ),
+            ("norm", cfg.hybrid_norm),
+            ("mlp", cfg.hybrid_mlp),
+            ("num_experts", cfg.hybrid_experts),
+            ("experts_per_token", cfg.hybrid_experts_per_token),
+            ("experts_held", tuple(cfg.hybrid_experts_held)),
+            ("expert_width", cfg.hybrid_expert_width),
         )
     transformer = (
         ("d_model", cfg.transformer_d_model),
@@ -921,6 +937,30 @@ PONG_PHI4FLASH = ExperimentConfig(
     total_env_frames=200_000_000,
 )
 
+# Layers 2-5 of LiquidAI's LFM2-8B-A1B (full attention with rotary
+# embeddings and per-head q/k norms, then three gated short convolutions),
+# every one with its routed experts, as the temporal core on Pong's shapes,
+# at the published widths. The learner is one of the four chips that share
+# each layer's 32 experts: it holds 8 of them (413M parameters in the
+# core), routes over all 32 and computes its own experts' part;
+# benchmark/configs/pong_lfm2_moe_core.json states the cut.
+PONG_LFM2_MOE = dataclasses.replace(
+    PONG_PHI4FLASH,
+    name="pong_lfm2_moe",
+    hybrid_layers=("full_rope", "short_conv", "short_conv", "short_conv"),
+    hybrid_d_model=2048,
+    hybrid_heads=32,
+    hybrid_kv_heads=8,
+    hybrid_head_dim=64,
+    hybrid_full_cache=2048,
+    hybrid_norm="rms",
+    hybrid_mlp="moe",
+    hybrid_experts=32,
+    hybrid_experts_per_token=4,
+    hybrid_experts_held=(0, 8),
+    hybrid_expert_width=1792,
+)
+
 # On-device (Anakin) presets: the whole actor-learner is one XLA program
 # over pure-JAX envs (runtime/anakin.py). batch_size = on-device env count.
 # Same MDPs as their host counterparts (envs/jax_envs.py parity tests), so
@@ -986,6 +1026,7 @@ REGISTRY: dict[str, ExperimentConfig] = {
         DMLAB30,
         PONG_TRANSFORMER,
         PONG_PHI4FLASH,
+        PONG_LFM2_MOE,
         CARTPOLE_ANAKIN,
         CATCH_ANAKIN,
         PIXELS_ANAKIN,

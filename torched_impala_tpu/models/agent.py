@@ -70,3 +70,22 @@ class Agent:
     ) -> tuple[NetOutput, NetState]:
         """Learner re-forward: obs `[T, B, ...]`, first `[T, B]`, time-major."""
         return self.net.apply(params, obs, first, state, unroll=True)
+
+    def unroll_with_stats(
+        self,
+        params: Params,
+        obs: jax.Array,
+        first: jax.Array,
+        state: NetState,
+    ) -> tuple[NetOutput, NetState, dict]:
+        """`unroll`, and the scalars the net sows into its `intermediates`
+        collection by name (the hybrid core's routing counts), each the
+        last sown."""
+        (out, state), sown = self.net.apply(
+            params, obs, first, state, unroll=True, mutable=["intermediates"]
+        )
+        flat = jax.tree_util.tree_flatten_with_path(
+            sown.get("intermediates", {}), is_leaf=lambda x: isinstance(x, tuple)
+        )[0]
+        stats = {path[-1].key: values[-1] for path, values in flat}
+        return out, state, stats

@@ -262,15 +262,19 @@ def _piece_rows(leaf) -> int:
     """How many rows of axis 0 a piece of `leaf` holds in a snapshot; 0
     where the leaf is one piece: it fits a quarter of
     `SNAPSHOT_COPY_AHEAD_BYTES`, or it is neither whole on one device
-    nor fully replicated. Rows are a multiple of 8, so that no tile of a
-    piece is padded, and the pieces of a leaf are as even as that allows;
-    where 8 rows alone exceed a piece, a piece is 8 rows."""
+    nor fully replicated. Where axis 0 is one of the two tiled axes (a
+    leaf of one or two axes) rows are a multiple of 8, so that no tile of
+    a piece is padded; a leaf of more axes (the held experts' matrices,
+    `[8, 2048, 1792]`) is cut by any count of rows. The pieces of a leaf
+    are as even as that allows; where the least rows alone exceed a
+    piece, a piece is that many rows."""
     piece = SNAPSHOT_COPY_AHEAD_BYTES // 4
     if leaf.nbytes <= piece or not leaf.sharding.is_fully_replicated:
         return 0
-    fit = max(8, piece * leaf.shape[0] // leaf.nbytes // 8 * 8)
+    align = 8 if len(leaf.shape) <= 2 else 1
+    fit = max(align, piece * leaf.shape[0] // leaf.nbytes // align * align)
     count = -(-leaf.shape[0] // fit)
-    rows = -(-leaf.shape[0] // (8 * count)) * 8
+    rows = -(-leaf.shape[0] // (align * count)) * align
     return rows if rows < leaf.shape[0] else 0
 
 
@@ -490,13 +494,19 @@ def _put_format(x, fmt):
     return jax.device_put(x, fmt)
 
 
+def _moe_read(last: list, i: int) -> float:
+    """A routed core's gauge: entry `i` of the last step's load scalars
+    (device arrays until read), NaN before the first step."""
+    return float("nan") if last[0] is None else float(last[0][i])
+
+
 def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
     """Pick the kernel implementations for the devices the step runs on.
 
     Returns `(agent, loss, resolved)`: `loss.vtrace_implementation` is
     never 'auto' afterwards, and `resolved` names what was chosen
     (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`,
-    `selective_scan`, `devices`, `why`; a `Learner` adds `packed_convs`,
+    `selective_scan`, `moe`, `devices`, `why`; a `Learner` adds `packed_convs`,
     which needs the observations' shape).
 
     On one device the choice is per platform: the Pallas kernels on a
@@ -581,6 +591,13 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
             if not dict(net.hybrid).get("scan_kernel", True)
             or platform != "tpu"
             else "pallas"
+        ),
+        # The held experts' products of a routed core: XLA's ragged product
+        # everywhere (ops/grouped_matmul.py).
+        "moe": (
+            "ragged_dot"
+            if kind == "hybrid" and dict(net.hybrid).get("mlp") == "moe"
+            else None
         ),
         "devices": [str(d) for d in devices],
         "why": why,
@@ -936,6 +953,16 @@ class Learner:
                 agent.net._hybrid_core(bound=False).state_bytes_per_row()
             )
             self._m_core_resets = reg.gauge("core/resets_in_batch")
+        # A routed core's load (models/hybrid.py, mlp="moe"): rows routed to
+        # the held experts in the last step dispatched, summed over the
+        # layers, and the fullest held expert's rows over a balanced
+        # router's share. Read from the step's own scalars when the
+        # registry is read, never in the step loop.
+        self._moe_last: Optional[list] = None
+        if dict(agent.net.hybrid).get("mlp") == "moe":
+            last = self._moe_last = [None]
+            for i, name in enumerate(("moe_held_rows", "moe_load_max_share")):
+                reg.gauge(f"core/{name}", fn=functools.partial(_moe_read, last, i))
         self._m_device_put = reg.timer("learner/device_put")
         self._m_train_step = reg.timer("learner/train_step")
         self._m_publish = reg.timer("learner/publish")
@@ -1344,7 +1371,7 @@ class Learner:
                 p = precision.cast_to_compute(p, self._train_cast)
             discounts = cfg.discount * cont
             if pa_cfg is None:
-                net_out, _ = self._agent.unroll(p, obs, first, agent_state)
+                net_out, stats = self._unroll(p, obs, first, agent_state)
                 values = jnp.squeeze(net_out.values, -1)  # [T+1, B]
                 out = impala_loss(
                     target_logits=net_out.policy_logits[:-1],
@@ -1356,7 +1383,7 @@ class Learner:
                     discounts=discounts,
                     config=cfg,
                 )
-                return out.total, (out.logs, popart_state)
+                return out.total, ({**out.logs, **stats}, popart_state)
             policy_logits, norm_values = self._popart_forward(
                 p, obs, first, agent_state, tasks
             )
@@ -1380,6 +1407,17 @@ class Learner:
             loss_fn, has_aux=True
         )(params)
         return grads, logs, new_popart
+
+    def _unroll(self, p, obs, first, agent_state):
+        """The unroll of the loss; with a routed core its load scalars
+        (`moe_held_rows`, `moe_load_max_share`) join the step's logs."""
+        if dict(self._agent.net.hybrid).get("mlp") != "moe":
+            net_out, _ = self._agent.unroll(p, obs, first, agent_state)
+            return net_out, {}
+        net_out, _, stats = self._agent.unroll_with_stats(
+            p, obs, first, agent_state
+        )
+        return net_out, jax.lax.stop_gradient(stats)
 
     def _popart_forward(self, params, obs, first, agent_state, tasks):
         """(policy_logits, norm_values) with each env's task column
@@ -2830,6 +2868,8 @@ class Learner:
         )
         # A device leaf of this step, taken before host counters join.
         probe = jax.tree.leaves(logs)[:1]
+        if self._moe_last is not None and "moe_held_rows" in logs:
+            self._moe_last[0] = (logs["moe_held_rows"], logs["moe_load_max_share"])
         step_dur_ns = dispatched - step_t0_ns
         self._step_intervals.append((step_t0_ns, dispatched))
         self._step_active_since_ns = None
