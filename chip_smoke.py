@@ -615,8 +615,9 @@ def phase_train(seed: int) -> dict:
         and workers_clean
         and learner.kernels["vtrace"] == "pallas"
         and learner.kernels["lstm"] == "fused"
-        # breakout: two sections' residual blocks (ops/conv_packed.py)
-        and len(learner.kernels["packed_convs"]) == 8
+        # breakout: the three section-entry convolutions and two
+        # sections' residual blocks (ops/conv_packed.py)
+        and len(learner.kernels["packed_convs"]) == 11
     )
     return out
 

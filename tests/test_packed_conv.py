@@ -1,4 +1,4 @@
-"""The residual blocks' W-packed weight gradient (ISSUE 31,
+"""The deep torso's W-packed weight gradients (
 ops/conv_packed.py), on the CPU: XLA computes the packed convolutions
 here as it computes any other, so tier-1 holds the identities the chip's
 program rests on.
@@ -6,13 +6,17 @@ program rests on.
 Claims: `unpack_kernel_sum` is the transpose of the block-Toeplitz
 packing `pack_kernel` below builds; the packed weight gradient is XLA's
 own to float32 rounding, and in bfloat16 within two ulps, for every
-`(C, W, p)` the rule can choose; the rule takes p = 1, XLA's gradient
-and no `custom_vjp`, where nothing divides; `ResidualBlock` has the
-parameter tree of two `nn.Conv`, and a checkpoint written from that tree
-loads; the packed products a torso's gradient holds are the ones
-`AtariDeepTorso.packed_convs` lists from the shapes; a torso nobody
+`(C_in, C_out, W, p)` the rule can choose, the rule keyed on the output
+channels; the rule takes p = 1, XLA's gradient and no `custom_vjp`,
+where nothing divides; `ResidualBlock` has the parameter tree of two
+`nn.Conv`, and a checkpoint written from that tree loads, as the deep
+torso loads one written from its tree of `nn.Conv` section-entry
+convolutions; the first convolution takes the packed gradient from the
+uint8 frames, which are its residual, and its space-to-depth path is
+left as it was; the packed products a torso's gradient holds are the
+ones `AtariDeepTorso.packed_convs` lists from the shapes; a torso nobody
 asked (`resolve_kernels` asks on a TPU only) traces the program of the
-two `nn.Conv`s, `custom_vjp` and all left out.
+`nn.Conv`s, `custom_vjp` and all left out.
 """
 
 import functools
@@ -24,7 +28,11 @@ import numpy as np
 import pytest
 from jax import lax
 
-from torched_impala_tpu.models.torsos import AtariDeepTorso, ResidualBlock
+from torched_impala_tpu.models.torsos import (
+    AtariDeepTorso,
+    ResidualBlock,
+    _FirstPixelConv,
+)
 from torched_impala_tpu.ops import conv_packed
 from torched_impala_tpu.ops.conv_packed import (
     conv3x3,
@@ -33,20 +41,29 @@ from torched_impala_tpu.ops.conv_packed import (
     plain_conv,
     unpack_kernel_sum,
 )
+from torched_impala_tpu.ops.maxpool_pallas import max_pool
 from torched_impala_tpu.utils.checkpoint import (
     load_state_file,
     save_state_file,
 )
 
 # (C, H, W): what the presets' residual blocks meet (84x84 Atari, 72x96
-# DMLab, 64x64 Procgen), a W that only 2 divides, and two nothing does.
+# DMLab, 64x64 Procgen), a W that only 2 divides, and two nothing does;
+# (C_in, C_out, H, W): what their section-entry convolutions meet, the
+# first over the frames, and one where nothing divides. The rule keys on
+# C_out: 16 output channels take 7 pixels a product whatever C_in is.
 RULE = {
     (16, 42, 42): 7, (32, 21, 21): 3, (32, 11, 11): 1,
     (16, 36, 48): 6, (32, 18, 24): 3, (32, 9, 12): 3,
     (16, 32, 32): 4, (32, 16, 16): 2, (32, 8, 8): 2,
     (16, 5, 22): 2, (16, 6, 13): 1, (64, 7, 7): 1,
+    (4, 16, 84, 84): 7, (3, 16, 72, 96): 6, (3, 16, 64, 64): 4,
+    (16, 32, 42, 42): 3, (16, 32, 36, 48): 3, (16, 32, 32, 32): 2,
+    (16, 32, 5, 11): 1,
 }
-PACKED = sorted((c, h, w, p) for (c, h, w), p in RULE.items() if p > 1)
+
+PACKED = sorted((*key, p) for key, p in RULE.items() if len(key) == 3 and p > 1)
+ENTRY = sorted((*key, p) for key, p in RULE.items() if len(key) == 4 and p > 1)
 DTYPES = [jnp.float32, jnp.bfloat16]
 
 
@@ -75,17 +92,20 @@ def plain_weight_gradient(x, dy, kernel):
     return jax.vjp(lambda k: plain_conv(x, k), kernel)[1](dy)[0]
 
 
-def _draw(c, h, w, dtype, n=3, seed=0):
+def _draw(c, h, w, dtype, n=3, seed=0, c_out=None):
     """Inputs whose values bfloat16 holds exactly, so that both dtypes
-    multiply the same numbers."""
+    multiply the same numbers; `c` channels in, `c_out` (`c`) out."""
     rng = np.random.default_rng(seed + 1000 * h + w)
+    c_out = c_out or c
 
     def make(*shape, scale=1.0):
         a = jnp.asarray(rng.normal(size=shape) * scale, jnp.bfloat16)
         return a.astype(dtype)
 
     return (
-        make(n, h, w, c), make(3, 3, c, c, scale=0.2), make(n, h, w, c)
+        make(n, h, w, c),
+        make(3, 3, c, c_out, scale=0.2),
+        make(n, h, w, c_out),
     )
 
 
@@ -105,10 +125,11 @@ def _close(got, want, dtype):
 
 @pytest.mark.parametrize("key", sorted(RULE), ids=str)
 def test_rule(key):
-    c, _, w = key
-    p = pack_width(w, c)
+    """`(C, H, W)` or `(C_in, C_out, H, W)`: the output channels decide."""
+    c_out, w = key[-3], key[-1]
+    p = pack_width(w, c_out)
     assert p == RULE[key]
-    assert w % p == 0 and p * c < 128
+    assert w % p == 0 and p * c_out < 128
 
 
 @pytest.mark.parametrize("p", [2, 3, 4, 6, 7])
@@ -129,7 +150,18 @@ def test_unpack_is_the_transpose_of_pack(p):
 @pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
 @pytest.mark.parametrize("c,h,w,p", PACKED)
 def test_packed_weight_gradient_is_xlas(c, h, w, p, dtype):
-    x, k, dy = _draw(c, h, w, dtype)
+    _packed_against_xla(c, c, h, w, p, dtype)
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+@pytest.mark.parametrize("c_in,c_out,h,w,p", ENTRY)
+def test_packed_entry_gradient_is_xlas(c_in, c_out, h, w, p, dtype):
+    """The section-entry convolutions' shapes, C_in != C_out."""
+    _packed_against_xla(c_in, c_out, h, w, p, dtype)
+
+
+def _packed_against_xla(c_in, c_out, h, w, p, dtype):
+    x, k, dy = _draw(c_in, h, w, dtype, c_out=c_out)
     got = packed_weight_gradient(x, dy, k, p)
     assert got.dtype == dtype and got.shape == k.shape
     # float32 truth from the same (bfloat16-exact) numbers
@@ -166,9 +198,9 @@ def test_conv3x3_under_vjp(c, h, w, p, dtype):
     np.testing.assert_array_equal(db_got, dparams["params"]["bias"])
 
 
-def _gradient_jaxpr(c, h, w, packed):
-    x, k, _ = _draw(c, h, w, jnp.float32)
-    b = jnp.zeros((c,))
+def _gradient_jaxpr(c, h, w, packed, c_out=None):
+    x, k, _ = _draw(c, h, w, jnp.float32, c_out=c_out)
+    b = jnp.zeros((c_out or c,))
     return str(
         jax.make_jaxpr(
             jax.grad(lambda k: conv3x3(x, k, b, packed=packed).sum())
@@ -186,6 +218,10 @@ def test_unasked_or_undivided_is_the_plain_convolution():
     unasked = _gradient_jaxpr(16, 5, 22, packed=False)
     assert "custom_vjp" not in unasked and "f32[3,4,16,32]" not in unasked
     assert "f32[3,4,16,32]" in _gradient_jaxpr(16, 5, 22, packed=True)
+    # 16 -> 32 channels over W = 11: asked, and nothing divides
+    entry = _gradient_jaxpr(16, 5, 11, packed=True, c_out=32)
+    assert "custom_vjp" not in entry
+    assert entry == _gradient_jaxpr(16, 5, 11, packed=False, c_out=32)
 
 
 class _TwoConvBlock(nn.Module):
@@ -231,26 +267,181 @@ def test_block_has_the_tree_of_two_convs_and_loads_their_checkpoint(
     )
 
 
-# The presets' frames: Atari, DMLab, Procgen.
+class _ParentDeepTorso(nn.Module):
+    """`AtariDeepTorso` as it stood before its section-entry convolutions
+    took the packed gradient: `nn.Conv` after the first."""
+
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        for i, channels in enumerate((16, 32, 32)):
+            if i == 0:
+                x = _FirstPixelConv(
+                    channels, (3, 3), dtype=self.dtype, name="Conv_0"
+                )(x)
+            else:
+                x = nn.Conv(
+                    channels, (3, 3), dtype=self.dtype, name=f"Conv_{i}"
+                )(x)
+            x = max_pool(x, kernel=False)
+            for _ in range(2):
+                x = ResidualBlock(channels, dtype=self.dtype)(x)
+        x = nn.relu(x)
+        x = x.reshape(*x.shape[:-3], -1)
+        return nn.relu(nn.Dense(256, dtype=self.dtype)(x))
+
+
+def _frames(frame, lead=(2,), seed=0):
+    return jnp.asarray(
+        np.random.default_rng(seed).integers(0, 256, (*lead, *frame)),
+        jnp.uint8,
+    )
+
+
+@pytest.mark.parametrize("frame", [(84, 84, 4), (72, 96, 3)], ids=str)
+def test_deep_torso_loads_the_parent_tree_and_traces_its_program(
+    frame, tmp_path
+):
+    """The deep torso has its parent's tree (`nn.Conv`'s names, shapes,
+    dtypes and draws from one key), loads a checkpoint written from it
+    and computes that torso's output from it, packed or not; unasked, its
+    gradient's jaxpr is the parent's equation for equation."""
+    obs = _frames(frame)
+    old = _ParentDeepTorso()
+    new = AtariDeepTorso(dtype=jnp.bfloat16, pool_kernel=False)
+    p_old = old.init(jax.random.key(3), obs)
+    p_new = new.init(jax.random.key(3), obs)
+    assert jax.tree.structure(p_old) == jax.tree.structure(p_new)
+    for a, b in zip(jax.tree.leaves(p_old), jax.tree.leaves(p_new)):
+        assert a.dtype == b.dtype == jnp.float32 and a.shape == b.shape
+        np.testing.assert_array_equal(a, b)
+    p_old = jax.tree.map(lambda a: a + 0.25, p_old)
+    path = str(tmp_path / "deep_torso.npz")
+    save_state_file(path, p_old)
+    loaded = load_state_file(path, p_new)
+    want = old.apply(p_old, obs)
+    np.testing.assert_array_equal(new.apply(loaded, obs), want)
+    packed = new.clone(packed_gradients=True)
+    np.testing.assert_array_equal(packed.apply(loaded, obs), want)
+
+    def jaxpr(torso):
+        return str(
+            jax.make_jaxpr(
+                jax.grad(
+                    lambda p: jnp.sum(torso.apply(p, obs).astype(jnp.float32))
+                )
+            )(p_old)
+        )
+
+    assert jaxpr(new) == jaxpr(old)
+    h, w, c = frame
+    p = pack_width(w, 16)
+    # the frames and a channel of ones, for the bias's gradient
+    assert f"f32[3,{p + 2},{c + 1},{16 * p}]" in jaxpr(packed)
+
+
+# The presets' frames: Atari, DMLab, Procgen. `(C_in, C_out, H, W, p)` of
+# each packed product, section by section: the entry convolution at the
+# section's input size, then its four block convolutions at the pool's.
 FRAMES = {
-    (84, 84, 4): [(16, 42, 42, 7)] * 4 + [(32, 21, 21, 3)] * 4,
+    (84, 84, 4): (
+        [(4, 16, 84, 84, 7)]
+        + [(16, 16, 42, 42, 7)] * 4
+        + [(16, 32, 42, 42, 3)]
+        + [(32, 32, 21, 21, 3)] * 4
+        + [(32, 32, 21, 21, 3)]
+    ),
     (72, 96, 3): (
-        [(16, 36, 48, 6)] * 4 + [(32, 18, 24, 3)] * 4 + [(32, 9, 12, 3)] * 4
+        [(3, 16, 72, 96, 6)]
+        + [(16, 16, 36, 48, 6)] * 4
+        + [(16, 32, 36, 48, 3)]
+        + [(32, 32, 18, 24, 3)] * 4
+        + [(32, 32, 18, 24, 3)]
+        + [(32, 32, 9, 12, 3)] * 4
     ),
     (64, 64, 3): (
-        [(16, 32, 32, 4)] * 4 + [(32, 16, 16, 2)] * 4 + [(32, 8, 8, 2)] * 4
+        [(3, 16, 64, 64, 4)]
+        + [(16, 16, 32, 32, 4)] * 4
+        + [(16, 32, 32, 32, 2)]
+        + [(32, 32, 16, 16, 2)] * 4
+        + [(32, 32, 16, 16, 2)]
+        + [(32, 32, 8, 8, 2)] * 4
     ),
 }
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+@pytest.mark.parametrize("frame", sorted(FRAMES), ids=str)
+def test_first_convolution_packed_from_the_uint8_frames(frame, dtype):
+    """The deep torso's `Conv_0` (`_FirstPixelConv`, 3x3 / stride 1 over
+    uint8 frames, the 1/255 fold on the kernel) with the packed gradient
+    against its plain program: the output to the bit, the kernel's
+    gradient through the fold within `_close`'s rounding, the bias's
+    (from the same product) the cotangent's sum within it; and the only
+    copy of the frames the backward keeps is the uint8 frames
+    themselves."""
+    h, w, c = frame
+    obs = _frames(frame, lead=(2, 3))
+    plain = _FirstPixelConv(16, (3, 3), dtype=dtype)
+    packed = plain.clone(packed_gradient=True)
+    params = jax.tree.map(
+        lambda a: a + 0.25, plain.init(jax.random.key(1), obs)
+    )
+    want, vjp = jax.vjp(lambda p: plain.apply(p, obs), params)
+    got, vjp_got = jax.vjp(lambda p: packed.apply(p, obs), params)
+    np.testing.assert_array_equal(got, want)
+    dy = _draw(16, h, w, dtype, n=6)[2].reshape(want.shape)
+    (g,), (g_got,) = vjp(dy), vjp_got(dy)
+    _close(g_got["params"]["kernel"], g["params"]["kernel"], dtype)
+    # the bias's gradient is the cotangent's sum, here in float32 and
+    # rounded once (the CPU's bfloat16 reduction of the plain program
+    # rounds as it goes, by up to 40% at these sizes)
+    total = np.asarray(dy, np.float32).sum(axis=(0, 1, 2, 3))
+    _close(g_got["params"]["bias"], total, dtype)
+    kept = [a for a in jax.tree.leaves(vjp_got) if a.shape[-3:] == frame]
+    assert kept and all(a.dtype == jnp.uint8 for a in kept)
+    assert any(
+        a.dtype != jnp.uint8
+        for a in jax.tree.leaves(vjp)
+        if a.shape[-3:] == frame
+    )
+
+
+@pytest.mark.parametrize("dtype", DTYPES, ids=lambda d: d.__name__)
+def test_space_to_depth_path_is_untouched(dtype):
+    """The Nature CNN's first convolution (8x8 / 4, space-to-depth; the
+    shallow torso's) traces the gradient it traced, asked for the packed
+    gradient or not."""
+    obs = _frames((84, 84, 4))
+    conv = _FirstPixelConv(
+        32, (8, 8), strides=(4, 4), padding="VALID", dtype=dtype
+    )
+    params = conv.init(jax.random.key(2), obs)
+
+    def jaxpr(module):
+        return str(
+            jax.make_jaxpr(
+                jax.grad(
+                    lambda p: jnp.sum(
+                        jnp.sin(module.apply(p, obs).astype(jnp.float32))
+                    )
+                )
+            )(params)
+        )
+
+    plain = jaxpr(conv)
+    assert "custom_vjp" not in plain
+    assert jaxpr(conv.clone(packed_gradient=True)) == plain
 
 
 @pytest.mark.parametrize("frame", sorted(FRAMES), ids=str)
 def test_torso_gradients_packed_against_plain(frame, monkeypatch):
     """The bfloat16 deep torso, every leaf's gradient with the packed
-    weight gradients against XLA's: the packed kernels within bfloat16
-    rounding by L2 norm, what lies below them (the section-entry
-    convolutions) likewise, everything above equal; and the products
-    the gradient holds are the ones `packed_convs` lists from the
-    shapes."""
+    weight gradients against XLA's: the packed kernels (the section-entry
+    convolutions' among them) within bfloat16 rounding by L2 norm, the
+    biases likewise, everything above equal; and the products the
+    gradient holds are the ones `packed_convs` lists from the shapes."""
     plain = AtariDeepTorso(dtype=jnp.bfloat16, pool_kernel=False)
     packed = plain.clone(packed_gradients=True)
     obs = jnp.asarray(
@@ -260,7 +451,7 @@ def test_torso_gradients_packed_against_plain(frame, monkeypatch):
     taken, packed_gradient = [], conv_packed.packed_weight_gradient
 
     def listed(x, dy, kernel, p):
-        taken.append((x.shape[3], *x.shape[1:3], p))
+        taken.append((*kernel.shape[2:], *x.shape[1:3], p))
         return packed_gradient(x, dy, kernel, p)
 
     monkeypatch.setattr(conv_packed, "packed_weight_gradient", listed)
@@ -271,10 +462,31 @@ def test_torso_gradients_packed_against_plain(frame, monkeypatch):
         )(params)
 
     want, got = grads(plain), grads(packed)
+    # `Conv_0`'s bias gradient, from its product, is the sum of its
+    # cotangent in float32 rounded once; the plain program's bfloat16
+    # reduction on the CPU rounds as it goes (by 1-2% here), so the sum
+    # of the cotangent itself stands in for it
+    h, w, _ = frame
+
+    def with_probe(probe):
+        def add(call, args, kwargs, context):
+            y = call(*args, **kwargs)
+            first = isinstance(context.module, _FirstPixelConv)
+            return y + probe if first else y
+
+        with nn.intercept_methods(add):
+            out = plain.apply(params, obs)
+        return jnp.sum(jnp.sin(out.astype(jnp.float32)))
+
+    dy = jax.grad(with_probe)(jnp.zeros((2, h, w, 16), jnp.bfloat16))
+    want["params"]["Conv_0"]["bias"] = dy.astype(jnp.float32).sum((0, 1, 2))
     # the backward meets them last section first
     assert taken[::-1] == packed.packed_convs(obs.shape) == FRAMES[frame]
     assert plain.packed_convs(obs.shape) == []
-    assert packed.clone(fused_blocks=True).packed_convs(obs.shape) == []
+    # fused blocks are Pallas kernels: the entry convolutions alone
+    assert packed.clone(fused_blocks=True).packed_convs(obs.shape) == [
+        FRAMES[frame][i] for i in (0, 5, 10)
+    ]
     for (path, a), b in zip(
         jax.tree.leaves_with_path(want), jax.tree.leaves(got)
     ):
@@ -336,7 +548,7 @@ def test_resolve_kernels_asks_on_a_tpu(platform, devices):
 
 
 def test_learner_lists_its_packed_convolutions(monkeypatch):
-    """`Learner.kernels["packed_convs"]`: the breakout preset's eight
+    """`Learner.kernels["packed_convs"]`: the breakout preset's eleven
     where `resolve_kernels` asked for them (none on the CPU:
     tests/test_pallas_maxpool.py's learner holds that)."""
     import dataclasses

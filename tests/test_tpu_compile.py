@@ -178,14 +178,36 @@ def test_conv_block(one_chip):
     assert "tpu_custom_call" in text
 
 
-# Per cell: the packed weight gradients the first two (DMLab: all three)
-# sections' blocks take, as `(p + 2, C, p * C)` of the packed product's
-# `f32[3, p+2, C, p*C]` result -> how many.
+def _reductions_over(text: str, elements: int) -> collections.Counter:
+    """`reduce`s anywhere in a compiled module whose operand holds at least
+    `elements` elements, counted by the operand's shape."""
+    shapes = dict(re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]", text))
+    return collections.Counter(
+        shapes[m.group(1)]
+        for m in re.finditer(r"\breduce\((%[\w.-]+)", text)
+        if m.group(1) in shapes
+        and math.prod(map(int, shapes[m.group(1)].split(","))) >= elements
+    )
+
+
+# Per cell: the packed weight gradients the three sections' entry
+# convolutions and the first two (DMLab: all three) sections' blocks
+# take, as `(p + 2, C_in, p * C_out)` of the packed product's
+# `f32[3, p+2, C_in, p*C_out]` result -> how many (the first's C_in is
+# the frames' channels and one of ones, for its bias's gradient).
 @pytest.mark.parametrize(
     "n,obs,packed",
     [
-        (21 * 256, (84, 84, 4), {(9, 16, 112): 4, (5, 32, 96): 4}),
-        (101 * 64, (72, 96, 3), {(8, 16, 96): 4, (5, 32, 96): 8}),
+        (
+            21 * 256,
+            (84, 84, 4),
+            {(9, 5, 112): 1, (9, 16, 112): 4, (5, 16, 96): 1, (5, 32, 96): 5},
+        ),
+        (
+            101 * 64,
+            (72, 96, 3),
+            {(8, 4, 96): 1, (8, 16, 96): 4, (5, 16, 96): 1, (5, 32, 96): 9},
+        ),
     ],
     ids=["breakout_cell", "dmlab_cell"],
 )
@@ -203,21 +225,27 @@ def test_deep_torso_pool_gradient(one_chip, n, obs, packed):
     outputs are no longer kept for the backward).
 
     ISSUE 31, in the same compile: with `packed_gradients`, as
-    `resolve_kernels` builds a TPU's torso, the residual blocks' weight
-    gradients are the packed products (`rhs_dilate=1xp`, `p*C` output
-    features, float32), one per convolution the torso's `packed_convs`
-    lists, XLA's own `[3,3,C,C]` weight gradient is left only where
-    nothing divides, and the reshape of the cotangent beside each is no
-    `copy` either (the same `moved` list)."""
+    `resolve_kernels` builds a TPU's torso, the weight gradients of the
+    section-entry convolutions (the first from the uint8 frames) and of
+    the residual blocks are the packed products (`rhs_dilate=1xp`,
+    `p*C_out` output features, float32), one per convolution the torso's
+    `packed_convs` lists, XLA's own `[3,3,C,C]` weight gradient is left
+    only where nothing divides (the 11x11 blocks: Breakout 4, DMLab 0),
+    and the reshape of the cotangent beside each is no `copy` either (the
+    same `moved` list); and the reduction over the first convolution's
+    cotangent, of the frames' size, that XLA's own bias gradient is in
+    the torso without `packed_gradients`, is gone (its product holds the
+    bias's gradient), none coming in its place."""
     torsos = {
         kernel: AtariDeepTorso(
             dtype=jnp.bfloat16, pool_kernel=kernel, packed_gradients=True
         )
         for kernel in (True, False)
     }
+    torsos["plain"] = AtariDeepTorso(dtype=jnp.bfloat16)
 
-    def compiled(pool_kernel):
-        torso = torsos[pool_kernel]
+    def compiled(which):
+        torso = torsos[which]
         params = jax.eval_shape(
             torso.init, jax.random.key(0), jnp.zeros((1, *obs), jnp.uint8)
         )
@@ -247,10 +275,14 @@ def test_deep_torso_pool_gradient(one_chip, n, obs, packed):
     )
     assert products == packed
     assert packed == collections.Counter(
-        (p + 2, c, p * c) for c, _, _, p in torsos[True].packed_convs(obs)
+        (p + 2, c_in + ((h, w, c_in) == obs), p * c_out)
+        for c_in, c_out, h, w, p in torsos[True].packed_convs(obs)
     )
     plain = len(re.findall(r"= \w+\[3,3,\d+,\d+\]\S* convolution\(", text))
     assert plain == 3 + 4 * 3 - sum(packed.values())
+    frames = n * 16 * math.prod(obs[:2])
+    assert _reductions_over(compiled("plain").as_text(), frames)
+    assert not _reductions_over(text, frames)
     assert text.count('custom_call_target="tpu_custom_call"') == 6
     assert "max_pool_forward" in text and "max_pool_backward" in text
     assert "select-and-scatter(" not in text
@@ -550,3 +582,38 @@ def test_packed_weight_gradients_partition_under_data_mesh(data_mesh):
     assert "tpu_custom_call" not in text
     assert "all-reduce" in text
     assert len(re.findall(r"convolution\([^\n]*rhs_dilate=1x7", text)) == 2
+
+
+def test_first_convolution_packed_gradient_partitions_under_data_mesh(
+    data_mesh,
+):
+    """The first convolution's packed product over the uint8 frames and a
+    channel of ones (its kernel's and bias's gradients in one) partitions
+    too: 84x84x4 frames sharded over `data`, one packed product, the
+    gradients' all-reduce, no Mosaic call."""
+    from torched_impala_tpu.models.torsos import _FirstPixelConv
+
+    conv = _FirstPixelConv(
+        16, (3, 3), dtype=jnp.bfloat16, packed_gradient=True
+    )
+    shape = (4 * 64, 84, 84, 4)
+    params = jax.eval_shape(
+        conv.init, jax.random.key(0), jnp.zeros((1, *shape[1:]), jnp.uint8)
+    )
+    rep = NamedSharding(data_mesh, spec_layout.replicated_spec())
+    rows = NamedSharding(data_mesh, spec_layout.state_spec())
+
+    def loss(p, x):
+        return jnp.sum(jnp.sin(conv.apply(p, x).astype(F32)))
+
+    text = _compile(
+        jax.grad(loss),
+        jax.tree.map(lambda a: _shape(rep, a.shape, a.dtype), params),
+        _shape(rows, shape, jnp.uint8),
+    )
+    assert "tpu_custom_call" not in text
+    assert "all-reduce" in text
+    products = re.findall(
+        r"= f32\[3,9,5,112\]\S* convolution\([^\n]*rhs_dilate=1x7", text
+    )
+    assert len(products) == 1

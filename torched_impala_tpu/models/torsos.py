@@ -17,11 +17,12 @@ space-to-depth form (`_FirstPixelConv`), the deep torso's max-pool,
 whose backward routes gradients from a one-byte winner index saved by
 the forward instead of XLA's `select-and-scatter` over the kept
 convolution output (`ops/maxpool_pallas.py`; not differentiated it is
-`nn.max_pool` as before), and the residual blocks' 3x3 convolutions,
-whose weight gradient in a step built for a TPU takes several adjacent
-pixels a product so that 16 channels fill the MXU's rows
-(`ops/conv_packed.py`; forward and input gradient are `nn.Conv`'s
-everywhere, and so is the weight gradient off a TPU).
+`nn.max_pool` as before), and the deep torso's 3x3 convolutions, the
+section-entry ones and the residual blocks', whose weight gradient in a
+step built for a TPU takes several adjacent pixels a product so that 16
+or 32 output channels fill the MXU's rows (`ops/conv_packed.py`; the
+first one's from the uint8 frames; forward and input gradient are
+`nn.Conv`'s everywhere, and so is the weight gradient off a TPU).
 """
 
 from __future__ import annotations
@@ -61,6 +62,12 @@ class _FirstPixelConv(nn.Module):
        pass alone was 22% of the r5 headline trace) into C_in=64.
        Input repack is a pure reshape/transpose on uint8 bytes; kernel
        repack is free (8 KB, constant-folded).
+
+    With `packed_gradient` (the deep torso's, set where `resolve_kernels`
+    builds a step for a TPU) a 3x3 / stride 1 / SAME convolution takes
+    the W-packed weight and bias gradients of `ops/conv_packed.py`, whose
+    residual is the uint8 frames themselves; the 1/255 fold stays outside
+    it, so the kernel's gradient flows through the fold as before.
     """
 
     features: int
@@ -68,6 +75,7 @@ class _FirstPixelConv(nn.Module):
     strides: tuple = (1, 1)
     padding: str = "SAME"
     dtype: jnp.dtype = jnp.float32
+    packed_gradient: bool = False
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
@@ -87,6 +95,16 @@ class _FirstPixelConv(nn.Module):
             kernel = kernel * (1.0 / 255.0)
         lead, (h, w) = x.shape[:-3], x.shape[-3:-1]
         xb = x.reshape(-1, h, w, cin)
+        if self.packed_gradient and (kh, kw, sh, sw, self.padding) == (
+            3, 3, 1, 1, "SAME"
+        ):
+            y = conv3x3(
+                xb,
+                kernel.astype(self.dtype),
+                bias.astype(self.dtype),
+                packed=True,
+            )
+            return y.reshape(*lead, *y.shape[1:])
         # Space-to-depth only understands the two string conventions; an
         # explicit pad-pair (or CIRCULAR etc.) routes to the plain conv.
         s2d = (
@@ -252,6 +270,12 @@ class _ConvParams(nn.Module):
         return kernel, bias
 
 
+def _conv(x, kernel, bias, dtype, packed: bool) -> jax.Array:
+    """`nn.Conv(dtype=dtype)`'s 3x3 convolution from its parameters
+    (`promote_dtype`: what it does with its input and parameters)."""
+    return conv3x3(*promote_dtype(x, kernel, bias, dtype=dtype), packed=packed)
+
+
 class ResidualBlock(nn.Module):
     """Two 3x3 convs with a skip connection (analog `haiku_nets.py:79-101`):
     relu, conv, relu, conv, add, the convolutions computed by
@@ -281,23 +305,18 @@ class ResidualBlock(nn.Module):
 
             return fused_residual_block(x.astype(self.dtype), k1, b1, k2, b2)
 
-        def conv(x, kernel, bias):
-            # `promote_dtype`: what `nn.Conv(dtype=...)` does with its
-            # input and parameters.
-            return conv3x3(
-                *promote_dtype(x, kernel, bias, dtype=self.dtype),
-                packed=self.packed_gradient,
-            )
-
-        out = conv(nn.relu(x), k1, b1)
-        out = conv(nn.relu(out), k2, b2)
+        out = _conv(nn.relu(x), k1, b1, self.dtype, self.packed_gradient)
+        out = _conv(nn.relu(out), k2, b2, self.dtype, self.packed_gradient)
         return x + out
 
 
 class AtariDeepTorso(nn.Module):
     """IMPALA deep ResNet: sections of (conv, maxpool, 2 residual blocks)
     with (16, 32, 32) channels, then Dense(256) (analog
-    `haiku_nets.py:104-130`; IMPALA paper fig. 3)."""
+    `haiku_nets.py:104-130`; IMPALA paper fig. 3). The section-entry
+    convolutions after the first are computed by `ops/conv_packed.py`
+    from the parameters of the `nn.Conv` they stand for (same names,
+    shapes, initialisers and checkpoints)."""
 
     channel_sections: Sequence[int] = (16, 32, 32)
     blocks_per_section: int = 2
@@ -312,10 +331,10 @@ class AtariDeepTorso(nn.Module):
     # the user's: `resolve_kernels` clears it for a learner on a mesh of
     # several TPU devices, where a Mosaic kernel cannot be partitioned.
     pool_kernel: bool = True
-    # The residual blocks' W-packed weight gradient (ops/conv_packed.py).
-    # Not a choice of the user's either: `resolve_kernels` sets it for a
-    # step that runs on a TPU; off it the torso traces `nn.Conv`'s own
-    # program.
+    # The W-packed weight gradient of every 3x3 convolution where a `p`
+    # divides (ops/conv_packed.py). Not a choice of the user's either:
+    # `resolve_kernels` sets it for a step that runs on a TPU; off it the
+    # torso traces `nn.Conv`'s own program.
     packed_gradients: bool = False
 
     @nn.compact
@@ -325,12 +344,15 @@ class AtariDeepTorso(nn.Module):
                 # Stride-1 3x3: no space-to-depth; still gets the
                 # kernel-side 1/255 fold for uint8 pixels.
                 x = _FirstPixelConv(
-                    channels, (3, 3), dtype=self.dtype, name="Conv_0"
+                    channels,
+                    (3, 3),
+                    dtype=self.dtype,
+                    packed_gradient=self.packed_gradients,
+                    name="Conv_0",
                 )(x)
             else:
-                x = nn.Conv(
-                    channels, (3, 3), dtype=self.dtype, name=f"Conv_{i}"
-                )(x)
+                kernel, bias = _ConvParams(channels, name=f"Conv_{i}")(x)
+                x = _conv(x, kernel, bias, self.dtype, self.packed_gradients)
             x = max_pool(x, kernel=self.pool_kernel)
             for _ in range(self.blocks_per_section):
                 x = ResidualBlock(
@@ -344,17 +366,23 @@ class AtariDeepTorso(nn.Module):
         return nn.relu(nn.Dense(self.hidden_size, dtype=self.dtype)(x))
 
     def packed_convs(self, obs_shape: Sequence[int]) -> list:
-        """`(C, H, W, p)` of each residual-block convolution over
-        `[..., H, W, C]` observations whose weight gradient is the packed
-        one (ops/conv_packed.py), from the shapes alone: a section's
-        pool halves H and W, rounded up."""
-        if self.fused_blocks or not self.packed_gradients:
+        """`(C_in, C_out, H, W, p)` of each convolution over `[..., H, W,
+        C]` observations whose weight gradient is the packed one
+        (ops/conv_packed.py), from the shapes alone: a section's entry
+        convolution at its input's size, then its blocks' (none where the
+        blocks are fused) at the pool's, which halves H and W, rounded
+        up."""
+        if not self.packed_gradients:
             return []
-        h, w = obs_shape[-3:-1]
+        h, w, c_in = obs_shape[-3:]
         taken = []
         for channels in self.channel_sections:
-            h, w = -(-h // 2), -(-w // 2)
             p = pack_width(w, channels)
             if p > 1:
-                taken += [(channels, h, w, p)] * (2 * self.blocks_per_section)
+                taken.append((c_in, channels, h, w, p))
+            h, w, c_in = -(-h // 2), -(-w // 2), channels
+            p = pack_width(w, channels)
+            if p > 1 and not self.fused_blocks:
+                block = (channels, channels, h, w, p)
+                taken += [block] * (2 * self.blocks_per_section)
         return taken

@@ -1,4 +1,4 @@
-"""The residual blocks' 3x3 convolution with a W-packed weight gradient
+"""The deep torso's 3x3 convolutions with a W-packed weight gradient
 (ISSUE 31): `p` adjacent pixels of a row are one product.
 
 XLA lays the deep torso's activations out batch on the lanes, channels on
@@ -16,6 +16,14 @@ gradient is that block-Toeplitz gradient with its diagonals summed:
 0.89 ms at 42x42x16 over 5376 frames; PERF.md section 6, PR 31). Every
 term of the plain sum is in the packed one once, accumulated in float32
 and rounded once, as XLA's own weight gradient does.
+
+The torso's section-entry convolutions take the same product (4 or 3 ->
+16 channels over the uint8 frames, 16 -> 32, 32 -> 32): a product's rows
+are `p` pixels of the output channels, `[p*C_out, 128] @ [128,
+(p+2)*C_in]`, so the rule keys on C_out. The first one reads the uint8
+frames: they are its residual in that dtype, converted where they are
+read, and its bias's gradient comes from the same product
+(`frames_conv_with_packed_gradient`).
 
 The packed gradient is taken where `conv3x3` is asked for it (a step
 that `runtime/learner.resolve_kernels` builds for a TPU) and some `p`
@@ -44,7 +52,8 @@ def pack_width(w: int, c: int) -> int:
     18x24x32: the fastest of those tried on the chip, PERF.md section 6,
     PR 31; at `p * C` = 128 XLA re-lays the cotangent out and the pass
     takes twice the plain one's time). 1, XLA's own weight gradient,
-    where nothing divides."""
+    where nothing divides. `C` is the output channels' count: `p` pixels
+    of them are a product's rows."""
     return max(
         (p for p in range(2, w + 1) if w % p == 0 and p * c < _ROWS),
         default=1,
@@ -70,9 +79,10 @@ def unpack_kernel_sum(packed: jax.Array, p: int) -> jax.Array:
 
 
 def plain_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
-    """3x3 / stride 1 / SAME over `[N, H, W, C]`, as `nn.Conv` calls it."""
+    """3x3 / stride 1 / SAME over `[N, H, W, C]`, as `nn.Conv` calls it;
+    uint8 frames converted to the kernel's dtype as they are read."""
     return lax.conv_general_dilated(
-        x, kernel, (1, 1), "SAME",
+        x.astype(kernel.dtype), kernel, (1, 1), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"),
     )
 
@@ -80,8 +90,8 @@ def plain_conv(x: jax.Array, kernel: jax.Array) -> jax.Array:
 def packed_weight_gradient(
     x: jax.Array, dy: jax.Array, kernel: jax.Array, p: int
 ) -> jax.Array:
-    """The same gradient from products of `p*C` rows of `dy` against
-    `(p+2)*C` of `x`, float32 until its diagonals are summed."""
+    """The same gradient from products of `p*C_out` rows of `dy` against
+    `(p+2)*C_in` of `x`, float32 until its diagonals are summed."""
     n, h, w, c_out = dy.shape
     packed = lax.conv_general_dilated(
         x, dy.reshape(n, h, w // p, p * c_out), (1, 1), ((1, 1), (1, 1)),
@@ -112,17 +122,52 @@ def conv_with_packed_gradient(p: int):
     return conv
 
 
+@functools.lru_cache(maxsize=None)
+def frames_conv_with_packed_gradient(p: int):
+    """`plain_conv` of uint8 frames plus its bias, whose weight and bias
+    gradients are one packed product. The frames are the residual as they
+    are and take no gradient. The bias's gradient, the cotangent's sum
+    over frames and pixels, comes from a channel of ones beside the
+    frames, read at the centre tap (which never meets SAME's padding).
+    Apart, it is a pass of its own over the cotangent that the pool's
+    backward writes at the frames' size (1.2-1.4 GB a step in the LSTM
+    cells). One channel more costs a product over 3 or 4 channels less
+    than that pass; over 16 or 32 channels it costs more (measured on a
+    TPU v5e), so the other convolutions keep their reduction."""
+
+    @jax.custom_vjp
+    def conv(frames, kernel, bias):
+        return plain_conv(frames, kernel) + bias.reshape(1, 1, 1, -1)
+
+    def forward(frames, kernel, bias):
+        return conv(frames, kernel, bias), (frames, kernel)
+
+    def backward(residuals, dy):
+        frames, kernel = residuals
+        ones = jnp.ones((*frames.shape[:3], 1), dy.dtype)
+        x = jnp.concatenate([frames.astype(dy.dtype), ones], axis=-1)
+        g = packed_weight_gradient(x, dy, kernel, p)
+        return None, g[:, :, :-1], g[1, 1, -1]
+
+    conv.defvjp(forward, backward)
+    return conv
+
+
 def conv3x3(
     x: jax.Array, kernel: jax.Array, bias: jax.Array, *, packed: bool
 ) -> jax.Array:
-    """`nn.Conv(C, (3, 3))` over `[..., H, W, C]` from its parameters,
-    already in the compute dtype; `packed`: with the packed weight
-    gradient where `pack_width` finds a `p`."""
+    """`nn.Conv(C_out, (3, 3))` over `[..., H, W, C_in]` from its
+    parameters, already in the compute dtype (`x` may be uint8 frames);
+    `packed`: with the packed weight gradient where `pack_width` finds a
+    `p` for W and C_out."""
     lead = x.shape[:-3]
     if len(lead) != 1:
         x = x.reshape(-1, *x.shape[-3:])
-    p = pack_width(x.shape[2], x.shape[3]) if packed else 1
-    conv = plain_conv if p == 1 else conv_with_packed_gradient(p)
-    # bias and shapes handled as `nn.Conv` does, equation for equation
-    y = conv(x, kernel) + bias.reshape(1, 1, 1, -1)
+    p = pack_width(x.shape[2], kernel.shape[-1]) if packed else 1
+    if p > 1 and jnp.issubdtype(x.dtype, jnp.integer):
+        y = frames_conv_with_packed_gradient(p)(x, kernel, bias)
+    else:
+        conv = plain_conv if p == 1 else conv_with_packed_gradient(p)
+        # bias and shapes handled as `nn.Conv` does, equation for equation
+        y = conv(x, kernel) + bias.reshape(1, 1, 1, -1)
     return y if len(lead) == 1 else y.reshape(*lead, *y.shape[1:])

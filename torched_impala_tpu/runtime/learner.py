@@ -518,8 +518,8 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
     a time — because Mosaic kernels cannot be
     auto-partitioned and nothing here wraps them per shard yet. That is decided HERE, by
     construction, and logged once; it is never recovered from a failed
-    lowering. On a TPU, one device or a mesh, a deep torso's residual
-    blocks take the W-packed weight gradient (ops/conv_packed.py: XLA
+    lowering. On a TPU, one device or a mesh, a deep torso's 3x3
+    convolutions take the W-packed weight gradient (ops/conv_packed.py: XLA
     convolutions, which partition like any other). The param tree is
     identical across implementations (tests/test_pallas_lstm.py), so
     actors may keep the fused net."""
@@ -806,8 +806,8 @@ class Learner:
         self._rng = rng
         self._params = agent.init_params(rng, jnp.asarray(example_obs))
         # Which of the torso's convolutions take the packed weight
-        # gradient in this learner's step, `(C, H, W, p)` each: none off
-        # a TPU (ops/conv_packed.py).
+        # gradient in this learner's step, `(C_in, C_out, H, W, p)` each:
+        # none off a TPU (ops/conv_packed.py).
         packed_convs = getattr(agent.net.torso, "packed_convs", None)
         self.kernels["packed_convs"] = (
             packed_convs(np.shape(example_obs)) if packed_convs else []
@@ -816,7 +816,7 @@ class Learner:
             import logging
 
             logging.getLogger(__name__).info(
-                "learner packed weight gradients (C, H, W, p): %s",
+                "learner packed weight gradients (C_in, C_out, H, W, p): %s",
                 self.kernels["packed_convs"],
             )
         self._opt_state = optimizer.init(self._params)
