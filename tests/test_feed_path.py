@@ -111,20 +111,33 @@ class TestDonatedRing:
         one past the K=8 ceiling the fused dispatch once had); donation
         must not change the training trajectory, stages nothing where
         the copy path stages every superbatch, and every batch fed is
-        counted as donated with its H2D time credited."""
+        counted as donated with its H2D dispatch timed."""
         p_copy, reg_copy, _ = _run_ring(donate=False, K=K, n=3)
         p_don, reg, _ = _run_ring(donate=True, K=K, n=3)
         jax.tree.map(np.testing.assert_array_equal, p_copy, p_don)
         assert reg_copy.counter("learner/ring_stage_bytes").value > 0
         assert reg.counter("learner/ring_stage_bytes").value == 0
         assert reg.counter("learner/donated_batches").value == 3
-        assert reg.counter("perf/h2d_ns_total").value > 0
+        assert reg.timer("learner/device_put").calls == 3
 
     def test_h2d_overlap_telemetry_populated(self):
+        """The donated path's H2D dispatch is a `learner/h2d` span a batch,
+        which `perf/report.py` scores against compute; each version the
+        step loop publishes observes the landing's three timers once."""
+        import time
+
+        from torched_impala_tpu.perf import analyze_records
+        from torched_impala_tpu.telemetry.tracing import get_recorder
+
+        since = time.monotonic_ns()
         _, reg, _ = _run_ring(donate=True)
-        assert reg.counter("perf/h2d_ns_total").value > 0
-        frac = reg.gauge("perf/h2d_overlap_frac").value
-        assert 0.0 <= frac <= 1.0
+        records = [r for r in get_recorder().tail() if r[0] >= since]
+        assert sum(r[3] == "learner/h2d" for r in records) == 4
+        learner = analyze_records(records)["learner"]
+        assert learner["h2d_total_s"] > 0
+        assert 0.0 <= learner["h2d_overlap_frac"] <= 1.0
+        for name in ("landing_wait", "landing_read", "landing_write"):
+            assert reg.timer(f"learner/{name}").calls == 4
 
     def test_donate_rejects_unsupported_combos(self):
         from torched_impala_tpu.replay import ReplayConfig
@@ -160,10 +173,11 @@ class TestDonatedRing:
         mesh = make_mesh(num_data=2, devices=jax.devices("cpu")[:2])
         _, reg, meshed = _run_ring(donate=False, n=3, mesh=mesh)
         np.testing.assert_allclose(single, meshed, rtol=1e-4)
-        # h2d overlap telemetry is credited per shard under the mesh.
-        assert reg.counter("perf/h2d_ns_total").value > 0
-        frac = reg.gauge("perf/h2d_overlap_frac").value
-        assert 0.0 <= frac <= 1.0
+        # the mesh learner's versions land as any other's: once a
+        # version, each part timed
+        assert reg.timer("learner/device_put").calls == 3
+        for name in ("landing_wait", "landing_read", "landing_write"):
+            assert reg.timer(f"learner/{name}").calls == 3
 
     def test_mesh_donated_ring_zero_staging(self):
         """Under the mesh learner the donated ring path stages ZERO
@@ -209,7 +223,7 @@ class TestDonatedRing:
         assert len(losses) == 3
         assert reg.counter("learner/ring_stage_bytes").value == 0
         assert reg.counter("learner/donated_batches").value == 3
-        assert reg.counter("perf/h2d_ns_total").value > 0
+        assert reg.timer("learner/device_put").calls == 3
 
     def test_mesh_donation_reuses_slot_backing_stores(self):
         """Donation aliasing under pjit: a sharded batch assembled by

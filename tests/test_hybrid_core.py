@@ -435,10 +435,10 @@ class _Walk:
                 ask(self),
             )[1],
         )
-        read = learner_mod.owned_array
+        read = learner_mod._read_piece
         monkeypatch.setattr(
-            learner_mod, "owned_array",
-            lambda piece, out=None: (
+            learner_mod, "_read_piece",
+            lambda piece, out: (
                 read(piece, out),
                 events.append(("read", id(piece), piece.shape, piece.nbytes)),
             )[0],

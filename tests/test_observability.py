@@ -557,13 +557,13 @@ class TestAlertEngine:
 
     def test_lower_kind_fires_on_floor_breach(self):
         eng = self._engine(
-            _spec(name="floor", key="perf/h2d_overlap_frac",
+            _spec(name="floor", key="pool/ready_fraction",
                   objective=0.5, kind="lower")
         )
         t, fired = 0.0, False
         while t <= 3.0:
             if eng.evaluate(
-                {"telemetry/perf/h2d_overlap_frac": 0.1}, now=t
+                {"telemetry/pool/ready_fraction": 0.1}, now=t
             ):
                 fired = True
                 break
@@ -589,7 +589,10 @@ class TestAlertEngine:
         keys = {s.key for s in specs}
         assert "serving/request_wait_ms_p99" in keys
         assert "pool/worker_step_ms_p99" in keys
-        assert "perf/h2d_overlap_frac" in keys
+        assert "ring/occupancy" in keys
+        assert "learner/param_lag_frames" in keys
+        # no objective on an estimate nothing on a chip reads
+        assert not any(k.startswith("perf/h2d") for k in keys)
         names = [s.name for s in specs]
         assert len(set(names)) == len(names)
 

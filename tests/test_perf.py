@@ -442,3 +442,192 @@ def test_write_report_non_json_suffix(tmp_path):
     txt = write_report({"schema": 1, "span_counts": {}}, path)
     assert txt == path + ".txt"
     assert os.path.exists(path) and os.path.exists(txt)
+
+
+# ---- the device's idle, put down to the host's phases ----------------------
+
+EPOCH_MS = 1000  # the epoch clock reads this far ahead of the monotonic one
+# epoch ms: the device's programs and operations
+_PROGRAMS = [
+    ("jit__train_step_impl(7)", 1000, 1040),
+    ("jit__publish_snapshot(8)", 1040, 1042),
+    ("jit__identity_fn(9)", 1045, 1047),
+    ("jit__train_step_impl(7)", 1050, 1090),
+    ("jit__train_step_impl(7)", 1100, 1140),
+]
+# monotonic ms: the host's spans
+_HOST = [
+    _span(40, 6, "learner/publish_copy", {"step": 1}),
+    _span(42, 2, "learner/stack_slot_wait", {"batch": 3}),
+    _span(42, 6, "learner/host_stack", {"batch": 3}),
+    _span(44, 4, "learner/stack_copy", {"batch": 3}),
+    _span(46, 6, "learner/batch_wait", {"step": 2}),
+    _span(48, 1, "learner/device_put", {"batch": 3}),
+    _span(50, 2, "learner/train_step", {"step": 2}),
+    _span(90, 6, "learner/publish_copy", {"step": 2}),
+    _span(92, 3, "learner/stack_slot_wait", {"batch": 4}),
+]
+
+
+def _device():
+    from torched_impala_tpu.perf.report import DeviceTimeline
+
+    return DeviceTimeline(
+        ops=[(a * MS, b * MS) for _, a, b in _PROGRAMS],
+        modules=[(n, a * MS, b * MS) for n, a, b in _PROGRAMS],
+    )
+
+
+@pytest.mark.parametrize(
+    "dropped, expected",
+    [
+        # idle (epoch ms): [1042, 1045), [1047, 1050), [1090, 1100)
+        (0, {
+            ("publish_copy", "stack_slot_wait"): 5,  # 1042-1044, 1092-1095
+            ("unattributed", "unattributed"): 4,  # 1096-1100: no span at all
+            ("publish_copy", "queue"): 2,  # 1090-1092: between batcher spans
+            ("publish_copy", "stack_copy"): 1,
+            ("batch_wait", "stack_copy"): 1,
+            ("batch_wait", "device_put"): 1,
+            ("batch_wait", "queue"): 1,
+            ("publish_copy", "unattributed"): 1,  # past the batcher's last
+        }),
+        # the ring overwrote the three oldest records: the first kept
+        # starts at 1044, so 1042-1044 has no records
+        (3, {
+            ("publish_copy", "stack_slot_wait"): 3,
+            ("unattributed", "unattributed"): 4,
+            ("no_records", "no_records"): 2,
+            ("publish_copy", "queue"): 2,
+            ("unattributed", "stack_copy"): 1,
+            ("batch_wait", "stack_copy"): 1,
+            ("batch_wait", "device_put"): 1,
+            ("batch_wait", "queue"): 1,
+            ("publish_copy", "unattributed"): 1,
+        }),
+    ],
+)
+def test_the_devices_idle_is_put_down_to_host_phases(dropped, expected):
+    """Whole periods of the step program ([1000, 1100) epoch ms, two
+    periods); the host's records are on the monotonic clock and the clock
+    pair lays them on the device's. Every idle millisecond lands in one
+    (loop phase, batcher phase) cell, none is dropped, and the device's
+    own label for each gap stands beside them."""
+    records = _HOST[dropped:]
+    pair = (5 * MS, (5 + EPOCH_MS) * MS)
+    rep = analyze_records(
+        records, device=_device(), clock_pair=pair, records_dropped=dropped
+    )
+    idle = rep["idle"]
+    assert (idle["window_s"], idle["periods"]) == (pytest.approx(0.1), 2)
+    assert idle["idle_s"] == pytest.approx(0.016)
+    assert idle["attributed_s"] == pytest.approx(idle["idle_s"], abs=1e-12)
+    got = {(p, f): round(sec * 1e3, 6) for p, f, sec in idle["by_pair"]}
+    assert got == {k: float(v) for k, v in expected.items()}
+    assert idle["largest"][:2] == ["publish_copy", "stack_slot_wait"]
+    assert dict(map(tuple, idle["by_device_label"])) == pytest.approx({
+        "after jit__publish_snapshot, before jit__identity_fn": 0.003,
+        "after jit__identity_fn, before jit__train_step_impl": 0.003,
+        "after jit__train_step_impl, before jit__train_step_impl": 0.010,
+    })
+    assert sum(row[-1] for row in idle["rows"]) == pytest.approx(0.016)
+    # compute is the device's own over the same window
+    learner = rep["learner"]
+    assert learner["compute_source"] == "device"
+    assert learner["steps"] == 2
+    assert learner["compute_s"] == pytest.approx(0.084)
+    assert learner["wall_clock_s"] == pytest.approx(0.100)
+    assert learner["coverage_frac"] == pytest.approx(1.0)
+    text = render_report(rep)
+    assert "device idle 0.016000s of 0.100000s" in text
+    assert "device's operations" in text
+
+
+def test_without_a_clock_pair_the_host_spans_miss_the_device():
+    """The same records not laid on the device's clock cover nothing."""
+    idle = analyze_records(_HOST, device=_device())["idle"]
+    assert {(p, f) for p, f, _ in idle["by_pair"]} == {
+        ("unattributed", "unattributed")
+    }
+
+
+def test_a_capture_with_one_period_falls_back_to_the_hosts_brackets():
+    from torched_impala_tpu.perf.report import DeviceTimeline
+
+    one = DeviceTimeline([(0, 5 * MS)], [("jit__train_step_impl(7)", 0, 5 * MS)])
+    rep = analyze_records(_HOST, device=one)
+    assert "idle" not in rep
+    assert rep["learner"]["compute_source"] == "train_step"
+
+
+def test_host_spans_file_gives_back_the_recorders_records(tmp_path):
+    """`host_spans.json` is on the epoch clock; read back, its records are
+    the recorder's, on the monotonic clock, to the rounding of an epoch
+    reading in float microseconds (a quarter of one), with the pair and
+    the count of dropped records."""
+    from torched_impala_tpu.perf.report import host_records
+    from torched_impala_tpu.telemetry import FlightRecorder
+
+    rec = FlightRecorder(capacity=64)
+    rec.complete("learner/stack_copy", 123_456_789, 4_321, {"batch": 1})
+    rec.complete("learner/publish_copy", 223_456_789, 1_234, {"step": 2})
+    rec.instant("queue/enqueue", {"lid": "a0u0"})
+    mono, wall = rec.clock_pair
+    path = str(tmp_path / "host_spans.json")
+    rec.export(
+        path, epoch=True,
+        metadata={"clock_pair_ns": {"monotonic": mono, "epoch": wall},
+                  "dropped": 7},
+    )
+    with open(path) as f:
+        records, pair, dropped = host_records(json.load(f))
+    assert (pair, dropped) == ((mono, wall), 7)
+    kept = [r for r in rec.tail() if r[2] == "X"]
+    assert [r[2:4] + (r[5],) for r in records] == [
+        r[2:4] + (r[5],) for r in kept
+    ]
+    for got, want in zip(records, kept):
+        assert abs(got[0] - want[0]) <= 256 and abs(got[1] - want[1]) <= 1
+
+
+def test_the_report_reads_a_capture_from_the_command_line(tmp_path, capsys):
+    from torched_impala_tpu.perf import report
+
+    assert report.main([]) == 2
+    with pytest.raises(FileNotFoundError):
+        report.main([str(tmp_path)])
+
+
+def test_the_device_side_labels_are_the_benchmarks():
+    """Where the device's programs overlap on the `XLA Modules` line (a
+    re-layout program whose event opens inside the step and closes after
+    the snapshot program, waiting for its bytes), each idle second carries
+    the label `benchmark/trace.py idle_gaps` gives it."""
+    from benchmark import trace as btrace
+    from torched_impala_tpu.perf.report import DeviceTimeline
+
+    programs = [  # epoch ms
+        ("jit__train_step_impl(7)", 1000, 1040),
+        ("jit__identity_fn(9)", 1020, 1048),
+        ("jit__publish_snapshot(8)", 1041, 1043),
+        ("jit__train_step_impl(7)", 1050, 1090),
+        ("jit__train_step_impl(7)", 1100, 1140),
+    ]
+    ops = [(1000, 1040), (1041, 1043), (1046, 1048), (1050, 1090), (1100, 1140)]
+    device = DeviceTimeline(
+        [(a * MS, b * MS) for a, b in ops],
+        [(n, a * MS, b * MS) for n, a, b in programs],
+    )
+    idle = analyze_records(_HOST, device=device)["idle"]
+    theirs = btrace.idle_gaps(
+        [btrace.Event("op", a / 1e3, (b - a) / 1e3) for a, b in ops],
+        [btrace.Event(n, a / 1e3, (b - a) / 1e3) for n, a, b in programs],
+        1.0, 1.1,
+    )
+    ours = dict(map(tuple, idle["by_device_label"]))
+    assert ours == pytest.approx(dict(map(tuple, theirs)))
+    assert ours == pytest.approx({
+        "after jit__train_step_impl, before jit__train_step_impl": 0.010,
+        "inside jit__identity_fn": 0.004,
+        "after jit__publish_snapshot, before jit__train_step_impl": 0.002,
+    })

@@ -264,3 +264,132 @@ def test_a_timed_out_wait_is_counted_and_leaves_the_period_open():
     assert wait[5] == {"step": 1}
     assert _spans(rec, "learner/batch_wait")[3][0] > wait[0] + wait[1]
     assert learner.param_store.version == T * B
+
+
+@pytest.mark.parametrize("K", [1, 2])
+@pytest.mark.parametrize("reuse", ["off", "on"])
+def test_the_feed_splits_the_slots_wait_from_the_copy(K, reuse):
+    """Both stacking paths observe `learner/stack_slot_wait` and
+    `learner/stack_copy` from the stamps `learner/host_stack` takes. One
+    batch at a time (K=1) the two split it: as many calls, the same
+    seconds. A superbatch (K=2) waits for its slot once, outside the
+    slices that `host_stack` and `stack_copy` time alike. With reuse off
+    nothing is ever pending, so the wait is 0."""
+    steps = 3
+    reg, rec = Registry(), FlightRecorder(capacity=1 << 14)
+    learner = Learner(
+        agent=_agent(),
+        optimizer=optax.sgd(1e-2),
+        config=LearnerConfig(
+            batch_size=B, unroll_length=T, steps_per_dispatch=K,
+            stack_buffer_reuse=reuse, queue_capacity=steps * K * B,
+        ),
+        example_obs=np.zeros((4,), np.float32),
+        rng=jax.random.key(0),
+        telemetry=reg,
+        tracer=rec,
+    )
+    for i in range(steps * K * B):
+        learner.enqueue(_trajectory(i))
+    learner.start()
+    try:
+        for _ in range(steps):
+            learner.step_once(timeout=60)
+    finally:
+        learner.stop()
+    stack, wait, copy = (
+        reg.timer(f"learner/{name}")
+        for name in ("host_stack", "stack_slot_wait", "stack_copy")
+    )
+    assert stack.calls == copy.calls == steps * K
+    assert wait.calls == steps
+    if K == 1:
+        assert wait.seconds + copy.seconds == pytest.approx(
+            stack.seconds, abs=1e-3 * steps
+        )
+    else:
+        assert copy.seconds == pytest.approx(stack.seconds, rel=1e-9)
+    if reuse == "off":
+        assert wait.seconds == 0.0
+    for name, calls in (("stack_slot_wait", steps), ("stack_copy", steps * K)):
+        spans = _spans(rec, f"learner/{name}")
+        assert len(spans) == calls
+        assert sorted({s[5]["batch"] for s in spans}) == list(range(steps))
+    if K == 1:  # the two spans tile the batch's `host_stack` span
+        for whole, w, c in zip(
+            _spans(rec, "learner/host_stack"),
+            _spans(rec, "learner/stack_slot_wait"),
+            _spans(rec, "learner/stack_copy"),
+        ):
+            assert (w[0], w[0] + w[1]) == (whole[0], c[0])
+            assert c[0] + c[1] == whole[0] + whole[1]
+
+
+@pytest.mark.parametrize("cut", [True, False])
+def test_a_version_lands_by_its_wait_read_and_write(monkeypatch, cut):
+    """Each version the step loop publishes observes the landing's three
+    timers once, and its `learner/publish_copy` span carries them with the
+    version's pieces and bytes. With a bound that cuts the 64x64 kernels
+    (8 rows a piece) their pieces are read on the reader threads; a tree
+    with no cut leaf is read on the loop's thread alone, which then waits
+    for every piece as long as it reads and writes it."""
+    from torched_impala_tpu.models import Agent, ImpalaNet, MLPTorso
+    from torched_impala_tpu.runtime import learner as learner_mod
+
+    if cut:
+        monkeypatch.setattr(learner_mod, "SNAPSHOT_COPY_AHEAD_BYTES", 8192)
+    threads = []
+    read = learner_mod._read_piece
+    monkeypatch.setattr(
+        learner_mod, "_read_piece",
+        lambda piece, out: (
+            threads.append(threading.current_thread()), read(piece, out)
+        )[1],
+    )
+    reg, rec = Registry(), FlightRecorder(capacity=1 << 14)
+    learner = Learner(
+        agent=Agent(
+            ImpalaNet(num_actions=2, torso=MLPTorso(hidden_sizes=(64, 64)))
+        ),
+        optimizer=optax.sgd(1e-2),
+        config=LearnerConfig(batch_size=B, unroll_length=T),
+        example_obs=np.zeros((4,), np.float32),
+        rng=jax.random.key(0),
+        telemetry=reg,
+        tracer=rec,
+    )
+    leaves = len(jax.tree.leaves(learner.params))
+    del threads[:]  # construction's publish is not the step loop's
+    learner.start()
+    try:
+        for step in range(2):
+            for i in range(B):
+                learner.enqueue(_trajectory(B * step + i))
+            learner.step_once(timeout=60)
+    finally:
+        learner.stop()  # drains: the second version lands here
+    timers = [
+        reg.timer(f"learner/landing_{part}") for part in ("wait", "read", "write")
+    ]
+    assert [t.calls for t in timers] == [2, 2, 2]  # two versions, once each
+    landings = [
+        s for s in _spans(rec, "learner/publish_copy") if "wait_ns" in s[5]
+    ]
+    assert len(landings) == 2
+    nbytes = sum(leaf.nbytes for leaf in jax.tree.leaves(learner.params))
+    for span in landings:
+        args = span[5]
+        assert 0 <= args["wait_ns"] <= span[1]  # landing_wait <= publish_copy
+        assert args["bytes"] == nbytes
+        assert (args["pieces"] > leaves) == cut
+    wait, read_, write = (t.seconds for t in timers)
+    assert wait == pytest.approx(sum(s[5]["wait_ns"] for s in landings) / 1e9)
+    assert read_ == pytest.approx(sum(s[5]["read_ns"] for s in landings) / 1e9)
+    assert write == pytest.approx(sum(s[5]["write_ns"] for s in landings) / 1e9)
+    loop = threading.current_thread()
+    assert threads
+    if cut:
+        assert any(t is not loop for t in threads)
+    else:
+        assert all(t is loop for t in threads)
+        assert wait >= read_ + write

@@ -497,6 +497,33 @@ def test_capture_leaves_the_recorders_spans_beside_the_xplane(tmp_path):
     assert "epoch" in doc["metadata"]["clock"]
 
 
+@pytest.mark.parametrize("written, capacity", [(3, 64), (20, 8)])
+def test_a_capture_states_the_records_its_ring_lost(tmp_path, written, capacity):
+    """`host_spans.json` says how many of the capture's records the ring
+    had overwritten by its end (`dropped`): the oldest ones, so what it
+    holds is the newest `capacity`."""
+    import glob
+
+    from torched_impala_tpu.telemetry.profiling import HOST_SPANS_FILE
+
+    rec = FlightRecorder(capacity=capacity)
+    for i in range(5):  # before the capture: not the capture's
+        rec.instant("queue/enqueue", {"lid": f"old{i}"})
+    cap = ProfilerCapture(
+        str(tmp_path / "traces"), registry=Registry(), recorder=rec
+    )
+    path = cap.start(tag="t")
+    for i in range(written):
+        rec.instant("queue/enqueue", {"lid": f"a0u{i}"})
+    cap.stop()
+    found = glob.glob(os.path.join(path, "**", HOST_SPANS_FILE), recursive=True)
+    with open(found[0]) as f:
+        doc = json.load(f)
+    assert doc["metadata"]["dropped"] == max(0, written - capacity)
+    lids = [e["args"]["lid"] for e in doc["traceEvents"] if e["ph"] != "M"]
+    assert lids == [f"a0u{i}" for i in range(written)][-capacity:]
+
+
 def test_recorder_export_since_and_epoch(tmp_path):
     rec = FlightRecorder(capacity=64)
     mono, wall = rec.clock_pair

@@ -101,12 +101,11 @@ REPLAY_PREFIXES = ("reuse_", "target_", "evict_", "staleness_")
 # Rule 3e (performance observatory, ISSUE 10): the perf/* family is
 # pinned to the sub-families docs/OBSERVABILITY.md documents —
 # model-flop utilization, memory bandwidth, flop counts, gap
-# attribution, fused-dispatch fallbacks, (ISSUE 13) host-to-device
-# transfer overlap, and (ISSUE 18) gradient all-reduce overlap. Checked
-# on `<sub>_` so the bare family names (perf/mfu) pass while
-# perf/mfuzzy does not.
+# attribution, fused-dispatch fallbacks and gradient all-reduce
+# overlap. Checked on `<sub>_` so the bare family names (perf/mfu) pass
+# while perf/mfuzzy does not.
 PERF_PREFIXES = (
-    "mfu_", "membw_", "flops_", "gap_", "fused_", "h2d_", "allreduce_",
+    "mfu_", "membw_", "flops_", "gap_", "fused_", "allreduce_",
 )
 # Rule 3f (control plane, ISSUE 12): the control/* family is pinned to
 # the four sub-families docs/CONTROL.md documents — decision accounting,

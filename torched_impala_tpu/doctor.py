@@ -354,8 +354,6 @@ def _check_mesh_feed() -> tuple[str, str]:
         donated = int(snap.get("telemetry/learner/donated_batches", 0))
         if donated == 0:
             return "FAIL", "no batch donated on the mesh ring path"
-        if snap.get("telemetry/perf/h2d_ns_total", 0.0) <= 0:
-            return "FAIL", "per-shard H2D telemetry never credited"
         batches = int(snap.get("telemetry/ring/batches", 0))
         aborted = int(snap.get("telemetry/ring/aborted_slots", 0))
         if batches != n or aborted != 0:

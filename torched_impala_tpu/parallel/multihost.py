@@ -283,7 +283,7 @@ def local_shard_slices(
     return out
 
 
-def place_batch(shardings: Any, arrays: Any, *, on_shard=None) -> Any:
+def place_batch(shardings: Any, arrays: Any) -> Any:
     """Host-local batch tree -> globally sharded device arrays.
 
     Single-process this shards each leaf with ONE `device_put` PER
@@ -296,20 +296,15 @@ def place_batch(shardings: Any, arrays: Any, *, on_shard=None) -> Any:
     `[T, B_local, ...]` frame) and
     `jax.make_array_from_single_device_arrays` stitches the global
     `[T, B_global, ...]` jax.Array from every host's pieces — no data
-    leaves the host, and H2D crediting works identically on both paths.
-    Leaves whose local layout can't be enumerated fall back to
-    `jax.make_array_from_process_local_data` (uncredited).
-
-    `on_shard(nbytes, t0_ns, t1_ns)`, when given, is invoked once per
-    completed per-device put so the caller can credit each shard's H2D
-    interval to its overlap telemetry (runtime/learner.py `_note_h2d`).
+    leaves the host. Leaves whose local layout can't be enumerated fall
+    back to `jax.make_array_from_process_local_data`.
     """
     multi = process_count() > 1
 
     def _place(sh, x):
         if not multi:
-            return _put_sharded(sh, x, on_shard)
-        return _put_process_local(sh, x, on_shard)
+            return _put_sharded(sh, x)
+        return _put_process_local(sh, x)
 
     def _apply(sh, subtree):
         # `shardings` may be a prefix tree (one sharding covering a
@@ -324,10 +319,8 @@ def place_batch(shardings: Any, arrays: Any, *, on_shard=None) -> Any:
     )
 
 
-def _put_process_local(sharding, x, on_shard=None):
+def _put_process_local(sharding, x):
     """One host-local leaf -> global jax.Array (multi-process path)."""
-    import time
-
     shape = getattr(x, "shape", None)
     if shape is not None and hasattr(
         sharding, "addressable_devices_indices_map"
@@ -338,14 +331,9 @@ def _put_process_local(sharding, x, on_shard=None):
             # Shape mismatches from a bad rebase surface as ValueError in
             # the assembler below and drop to the stock path.
             try:
-                pieces = []
-                for dev, idx in slices.items():
-                    t0 = time.monotonic_ns()
-                    piece = jax.device_put(x[idx], dev)
-                    if on_shard is not None:
-                        piece.block_until_ready()
-                        on_shard(piece.nbytes, t0, time.monotonic_ns())
-                    pieces.append(piece)
+                pieces = [
+                    jax.device_put(x[idx], dev) for dev, idx in slices.items()
+                ]
                 return jax.make_array_from_single_device_arrays(
                     tuple(global_shape), sharding, pieces
                 )
@@ -354,7 +342,7 @@ def _put_process_local(sharding, x, on_shard=None):
     return jax.make_array_from_process_local_data(sharding, x)
 
 
-def _put_sharded(sharding, x, on_shard=None):
+def _put_sharded(sharding, x):
     """One leaf -> global jax.Array via one device_put per shard.
 
     Each shard is a numpy view (`x[idx]` with the slice tuple from the
@@ -363,24 +351,13 @@ def _put_sharded(sharding, x, on_shard=None):
     single-device shardings keep the plain put (identical dispatch, no
     assembly overhead).
     """
-    import time
-
     shape = getattr(x, "shape", None)
     if shape is None or not hasattr(sharding, "addressable_devices"):
         return jax.device_put(x, sharding)
     idx_map = sharding.addressable_devices_indices_map(tuple(shape))
     if len(idx_map) <= 1:
         return jax.device_put(x, sharding)
-    pieces = []
-    for dev, idx in idx_map.items():
-        t0 = time.monotonic_ns()
-        piece = jax.device_put(x[idx], dev)
-        if on_shard is not None:
-            # Block so the interval covers the transfer, not just its
-            # dispatch — the overlap fraction must stay honest.
-            piece.block_until_ready()
-            on_shard(piece.nbytes, t0, time.monotonic_ns())
-        pieces.append(piece)
+    pieces = [jax.device_put(x[idx], dev) for dev, idx in idx_map.items()]
     return jax.make_array_from_single_device_arrays(
         tuple(shape), sharding, pieces
     )

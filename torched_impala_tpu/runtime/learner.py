@@ -341,6 +341,17 @@ class _Slabs:
             self._free.append(raw)
 
 
+def _read_piece(piece, block):
+    """`owned_array(piece, block)`, timed on the thread that reads it:
+    `(the array, ns in np.asarray (the wait for the piece's D2H bytes),
+    ns writing them into their leaf)`."""
+    t0 = time.monotonic_ns()
+    array = np.asarray(piece)
+    t1 = time.monotonic_ns()
+    array = owned_array(array, block)
+    return array, t1 - t0, time.monotonic_ns() - t1
+
+
 def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
     """`host_snapshot` of a version that is on the device as one tuple of
     pieces a leaf (`_publish_snapshot`), the first `requested` pieces
@@ -362,7 +373,10 @@ def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
     the host are freed then, and the runtime's host side of a version is
     what stands requested, not the version (whole leaves die with the
     caller's list as they always did: freeing 48 small buffers one by
-    one cost an LSTM preset's landing 3 ms on the chip)."""
+    one cost an LSTM preset's landing 3 ms on the chip). The third item
+    returned is `[wait, read, write]` ns: this thread's time blocked on a
+    piece (a reader's result, or a piece it read itself), and over every
+    piece and thread the `np.asarray` and the write (`_read_piece`)."""
     flat = [piece for leaf in snapshot for piece in leaf]
     leaf_of = [i for i, leaf in enumerate(snapshot) for _ in leaf]
     # Where each piece lands: a whole leaf in an array of its own (None
@@ -391,11 +405,19 @@ def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
     snapshot[:] = [leaf if len(leaf) == 1 else () for leaf in snapshot]
     unread = peak = sum(piece.nbytes for piece in flat[:requested])
     pending = collections.deque()  # (a read, its bytes, the whole leaf it is)
+    spent = [0, 0, 0]  # wait, read, write
+
+    def count(t0, read):
+        array, read_ns, write_ns = read
+        spent[0] += time.monotonic_ns() - t0
+        spent[1] += read_ns
+        spent[2] += write_ns
+        return array
 
     def landed():
         nonlocal unread
         read, nbytes, whole = pending.popleft()
-        array = read.result()
+        array = count(time.monotonic_ns(), read.result())
         unread -= nbytes
         if whole is not None:
             host[whole] = array
@@ -415,14 +437,14 @@ def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
         if block is not None:
             flat[at] = None
         if readers is None or (block is None and not pending):
-            array = owned_array(piece, block)
+            array = count(time.monotonic_ns(), _read_piece(piece, block))
             unread -= nbytes
             if block is None:
                 host[leaf_of[at]] = array
         else:
             whole = leaf_of[at] if block is None else None
             pending.append(
-                (readers.submit(owned_array, piece, block), nbytes, whole)
+                (readers.submit(_read_piece, piece, block), nbytes, whole)
             )
             while pending and pending[0][0].done():
                 landed()
@@ -430,7 +452,7 @@ def _snapshot_to_host(snapshot, requested: int, slabs, readers=None):
         peak = max(peak, unread)
     while pending:
         landed()
-    return host, peak
+    return host, peak, spent
 
 
 @functools.partial(jax.jit, static_argnames="rows")
@@ -891,6 +913,9 @@ class Learner:
         self._ring_checked: list = [False] * ring_size
         self._ring_idx = 0
         self._last_slot: Optional[int] = None
+        # Batcher thread only: the stamp at which `_stack_out`'s wait for
+        # the slot's previous transfer returned (None: it did not wait).
+        self._slot_ready_ns: Optional[int] = None
         self._stack_reuse: Optional[bool] = None
         self._stop = threading.Event()
         self._batcher_thread: Optional[threading.Thread] = None
@@ -936,7 +961,13 @@ class Learner:
         self._tracer = tracer if tracer is not None else get_recorder()
         self._batch_seq = 0
         self._last_lineage = BatchLineage(batch=-1)
+        # `host_stack` brackets the whole of a batch's stacking: the wait
+        # for the reused buffer's previous transfer (`stack_slot_wait`,
+        # observed on every batch, 0 where nothing was pending) and the
+        # copy into it (`stack_copy`); the two split it, stamp for stamp.
         self._m_host_stack = reg.timer("learner/host_stack")
+        self._m_stack_slot_wait = reg.timer("learner/stack_slot_wait")
+        self._m_stack_copy = reg.timer("learner/stack_copy")
         # Bytes the stacking path COPIES per batch (the number the
         # trajectory ring drives to 0) and, ring mode only, bytes staged
         # through the aliasing-fallback owning copy before device_put.
@@ -1006,6 +1037,15 @@ class Learner:
             "learner/publish_outstanding_peak_bytes"
         )
         self._m_publish_rate = reg.gauge("learner/publish_gb_per_s")
+        # A version's landing by its parts, observed once a version the
+        # step loop publishes: `landing_wait` the loop thread blocked on
+        # a piece (a reader's result, or a piece it read itself),
+        # `landing_read` the pieces' `np.asarray` (the wait for their D2H
+        # bytes) and `landing_write` their copy into the version's leaves,
+        # both summed over pieces and threads (thread time, not wall).
+        self._m_landing_wait = reg.timer("learner/landing_wait")
+        self._m_landing_read = reg.timer("learner/landing_read")
+        self._m_landing_write = reg.timer("learner/landing_write")
         self._m_outside_step = reg.timer("learner/outside_step")
         self._m_loop_overhead = reg.timer("learner/loop_overhead")
         self._m_dispatch_lead = reg.timer("learner/dispatch_lead")
@@ -1037,17 +1077,6 @@ class Learner:
         # after a jit-boundary layout refusal (perf observatory; the
         # companion perf/mfu gauges register lazily in _observe_perf).
         self._m_fused_fallbacks = reg.counter("perf/fused_fallbacks")
-        # Zero-copy feed path (donate_batch): how much of the H2D
-        # dispatch wall-time landed inside a train step's compute window
-        # (the overlapped-H2D design point). ns counters so a reader can
-        # take window deltas; the gauge is the cumulative fraction. Read
-        # by tests/test_feed_path.py and doctor; by nothing on a chip
-        # (ROADMAP D6).
-        # `learner/donated_batches` counts batches fed without a staging
-        # copy (the donation gauge OBSERVABILITY.md documents).
-        self._m_h2d_total_ns = reg.counter("perf/h2d_ns_total")
-        self._m_h2d_overlap_ns = reg.counter("perf/h2d_ns_overlapped")
-        self._m_h2d_overlap_frac = reg.gauge("perf/h2d_overlap_frac")
         # Gradient all-reduce overlap (meshes whose data axis spans >1
         # device — multi-host pods ride the same axis): XLA fuses the
         # collective into the step program, so it can't be timed
@@ -1072,27 +1101,9 @@ class Learner:
         self._allreduce_stall_ns = 0  # lint: guarded-by(gil)
         self._allreduce_total_ns = 0  # lint: guarded-by(gil)
         self._allreduce_overlap_ns = 0  # lint: guarded-by(gil)
+        # Batches fed without a staging copy (the donation gauge
+        # OBSERVABILITY.md documents).
         self._m_donated_batches = reg.counter("learner/donated_batches")
-        # Written only by the batcher thread (directly or via the
-        # place_batch per-shard callback); main thread only reads at
-        # snapshot time — int updates are atomic under the GIL.
-        self._h2d_total_ns = 0  # lint: guarded-by(gil)
-        self._h2d_overlap_ns = 0  # lint: guarded-by(gil)
-        # Recent train-step compute intervals + the in-flight step's
-        # start, read by the batcher thread to score each H2D dispatch
-        # against compute. Benign cross-thread race: stale reads only
-        # under-count overlap.
-        self._step_intervals: collections.deque = collections.deque(
-            maxlen=64
-        )
-        self._step_active_since_ns: Optional[int] = None  # lint: guarded-by(gil)
-        # Per-shard H2D accounting for the sharded place_batch path:
-        # place_batch invokes _on_shard_h2d once per per-device put, so
-        # the put's overlap credit comes from the shard intervals
-        # themselves, not the whole dispatch window (batcher thread
-        # only — reset by _put_batch before each placement).
-        self._put_shards = 0  # lint: guarded-by(gil)
-        self._put_overlap_ns = 0  # lint: guarded-by(gil)
         reg.gauge("queue/capacity").set(capacity)
         # Live depth, read lazily at snapshot time. Weakref: the global
         # registry must not keep a dead learner's queue (and its queued
@@ -1898,7 +1909,10 @@ class Learner:
         self, trajs: list[Trajectory], K: Optional[int] = None
     ) -> Optional[Trajectory]:
         """Next ring stacking buffer (None when reuse is off). Blocks out
-        the slot's previous device transfer before handing it back."""
+        the slot's previous device transfer before handing it back, and
+        leaves the stamp at which that wait returned in `_slot_ready_ns`
+        (None where nothing was pending)."""
+        self._slot_ready_ns = None
         if not self._stack_reuse_enabled():
             return None
         i = self._ring_idx % len(self._ring)
@@ -1921,6 +1935,7 @@ class Learner:
             ]
             if pending:
                 jax.block_until_ready(pending)
+                self._slot_ready_ns = time.monotonic_ns()
             self._ring_pending[i] = None
         if self._ring[i] is None:
             self._ring[i] = alloc_stack_buffers(trajs, K)
@@ -2000,18 +2015,42 @@ class Learner:
             (t.param_version for t in trajs),
         )
         t0_ns = time.monotonic_ns()
-        with self._m_host_stack.time():
-            batch = stack_trajectories(trajs, out=self._stack_out(trajs))
+        batch = stack_trajectories(trajs, out=self._stack_out(trajs))
+        end_ns = time.monotonic_ns()
+        self._m_host_stack.observe((end_ns - t0_ns) / 1e9)
         self._tracer.complete(
             "learner/host_stack",
             t0_ns,
-            time.monotonic_ns() - t0_ns,
+            end_ns - t0_ns,
             {"batch": meta.batch, "lineage": list(meta.lineage)},
         )
+        ready_ns = self._observe_slot_wait(meta.batch, t0_ns)
+        self._observe_stack_copy(meta.batch, ready_ns, end_ns)
         self._count_stack_bytes(batch)
         if self._m_core_resets is not None:
             self._m_core_resets.set(int(np.count_nonzero(batch.first)))
         return batch
+
+    def _observe_slot_wait(self, batch: int, t0_ns: int) -> int:
+        """Observe `learner/stack_slot_wait` of a stacking that began at
+        `t0_ns`: up to where `_stack_out`'s wait returned, 0 where it did
+        not wait. Returns the stamp where the wait ended."""
+        ready = self._slot_ready_ns
+        ready_ns = t0_ns if ready is None else ready
+        self._m_stack_slot_wait.observe((ready_ns - t0_ns) / 1e9)
+        self._tracer.complete(
+            "learner/stack_slot_wait", t0_ns, ready_ns - t0_ns,
+            {"batch": batch},
+        )
+        return ready_ns
+
+    def _observe_stack_copy(self, batch: int, t0_ns: int, end_ns: int) -> None:
+        """Observe `learner/stack_copy`: the stacking less the slot's
+        wait, from `t0_ns` to `end_ns`."""
+        self._m_stack_copy.observe((end_ns - t0_ns) / 1e9)
+        self._tracer.complete(
+            "learner/stack_copy", t0_ns, end_ns - t0_ns, {"batch": batch}
+        )
 
     def _count_stack_bytes(self, batch: Trajectory) -> None:
         """Account the bytes `stack_trajectories` just copied — the
@@ -2050,7 +2089,10 @@ class Learner:
                 int(t.param_version) for t in trajs
             )
             if sb is None:
+                t0_ns = time.monotonic_ns()
                 sb = self._stack_out(trajs, K)
+                # once a superbatch, outside `host_stack`'s slices
+                self._observe_slot_wait(self._batch_seq, t0_ns)
                 if sb is None:  # reuse off: fresh allocation
                     sb = alloc_stack_buffers(trajs, K)
             view = Trajectory(
@@ -2065,10 +2107,11 @@ class Learner:
                 param_version=0,
                 task=sb.task[k],
             )
-            with self._m_host_stack.time():
-                versions.append(
-                    stack_trajectories(trajs, out=view).param_version
-                )
+            t0_ns = time.monotonic_ns()
+            versions.append(stack_trajectories(trajs, out=view).param_version)
+            end_ns = time.monotonic_ns()
+            self._m_host_stack.observe((end_ns - t0_ns) / 1e9)
+            self._observe_stack_copy(self._batch_seq, t0_ns, end_ns)
             self._count_stack_bytes(view)
         self._next_batch_lineage(lids, unroll_versions)
         return sb._replace(param_version=min(versions))
@@ -2109,51 +2152,10 @@ class Learner:
                 return jax.tree.map(_put_format, arrays, fmts)
             return jax.device_put(arrays)
         # Single-host: one device_put PER DATA SHARD, sliced straight
-        # from the host buffer (a ring slot view on the zero-copy path)
-        # and credited shard-by-shard to the h2d overlap telemetry.
+        # from the host buffer (a ring slot view on the zero-copy path).
         # Multi-host: this host's local slice becomes its shards of the
         # global batch array.
-        self._put_shards = 0
-        self._put_overlap_ns = 0
-        return multihost.place_batch(
-            self._batch_shardings, arrays, on_shard=self._on_shard_h2d
-        )
-
-    def _on_shard_h2d(self, nbytes: int, t0_ns: int, t1_ns: int) -> None:
-        """place_batch per-shard completion callback (batcher thread):
-        credit each shard's own transfer interval so
-        perf/h2d_overlap_frac stays honest under the mesh (the whole
-        dispatch window would over-count idle gaps between shards)."""
-        self._put_shards += 1
-        self._put_overlap_ns += self._note_h2d(t0_ns, t1_ns)
-
-    def _note_h2d(self, t0_ns: int, t1_ns: int) -> int:
-        """Score one H2D dispatch interval against the learner's recent
-        train-step compute intervals (batcher thread; the overlap half
-        of the zero-copy feed path). Returns the overlapped ns and
-        updates the perf/h2d_* counters plus the cumulative
-        perf/h2d_overlap_frac gauge."""
-        total = max(0, t1_ns - t0_ns)
-        ov = 0
-        for s0, s1 in tuple(self._step_intervals):
-            ov += max(0, min(t1_ns, s1) - max(t0_ns, s0))
-        active = self._step_active_since_ns
-        if active is not None:
-            # The in-flight step has no end yet; everything past its
-            # start overlaps compute. The min() cap below absorbs the
-            # benign race where it finishes mid-call and lands in
-            # _step_intervals too.
-            ov += max(0, t1_ns - max(t0_ns, active))
-        ov = min(ov, total)
-        self._h2d_total_ns += total
-        self._h2d_overlap_ns += ov
-        self._m_h2d_total_ns.inc(total)
-        self._m_h2d_overlap_ns.inc(ov)
-        if self._h2d_total_ns:
-            self._m_h2d_overlap_frac.set(
-                self._h2d_overlap_ns / self._h2d_total_ns
-            )
-        return ov
+        return multihost.place_batch(self._batch_shardings, arrays)
 
     def _timed_sync(self, tree) -> None:
         """block_until_ready(tree), crediting only the GENUINE device
@@ -2265,16 +2267,10 @@ class Learner:
             put_span.__enter__()
             on_device = self._put_batch(arrays)
             put_span.__exit__()
-            put_dur = time.monotonic_ns() - put_t0
-            if self._put_shards == 0:
-                # Sharded placement already credited each per-device
-                # put interval via _on_shard_h2d; only the unsharded
-                # paths score the whole dispatch window.
-                self._note_h2d(put_t0, put_t0 + put_dur)
             self._tracer.complete(
                 "learner/device_put",
                 put_t0,
-                put_dur,
+                time.monotonic_ns() - put_t0,
                 {"batch": meta.batch},
             )
             self._record_pending_transfer(on_device)
@@ -2348,21 +2344,14 @@ class Learner:
             on_device = self._put_batch(arrays)
             put_span.__exit__()
             put_dur = time.monotonic_ns() - put_t0
-            if self._put_shards:
-                # Sharded placement: per-device put intervals were
-                # credited shard-by-shard via _on_shard_h2d.
-                overlap_ns = self._put_overlap_ns
-            else:
-                overlap_ns = self._note_h2d(put_t0, put_t0 + put_dur)
             if donate:
-                # Distinct span name for the overlapped path: report.py
-                # scores learner/h2d* against compute intervals and must
-                # not double-charge the overlapped part as gap.
+                # Distinct span name for the donated path (perf/report.py
+                # puts both names in its h2d category).
                 self._tracer.complete(
                     "learner/h2d",
                     put_t0,
                     put_dur,
-                    {"batch": meta.batch, "overlap_ns": overlap_ns},
+                    {"batch": meta.batch},
                 )
             else:
                 self._tracer.complete(
@@ -2469,23 +2458,27 @@ class Learner:
             "learner/publish", t0, dur, {"version": self.num_frames}
         )
 
-    def _land(self, version: int, snapshot, requested: int, t0: int) -> None:
+    def _land(self, version: int, snapshot, requested: int, t0: int) -> dict:
         """Bring a version's pieces to the host (`_snapshot_to_host`)
         and publish it; `t0`: from where its landing is timed. Under
-        `_settle_lock`, where `stop()` takes the readers away."""
+        `_settle_lock`, where `stop()` takes the readers away. Returns
+        the landing's parts in ns, its pieces and bytes (the args of its
+        `learner/publish_copy` span)."""
         pieces = sum(len(leaf) for leaf in snapshot)
-        host, peak = _snapshot_to_host(
+        host, peak, (wait, read, write) = _snapshot_to_host(
             snapshot, requested, self._slabs, self._readers
         )
         self.param_store.publish(
             version, jax.tree.unflatten(jax.tree.structure(self._params), host)
         )
+        nbytes = sum(leaf.nbytes for leaf in host)
         self._m_publish_pieces.set(pieces)
         self._m_publish_outstanding.set(peak)
-        self._m_publish_rate.set(
-            sum(leaf.nbytes for leaf in host)
-            / max(time.monotonic_ns() - t0, 1)
-        )
+        self._m_publish_rate.set(nbytes / max(time.monotonic_ns() - t0, 1))
+        return {
+            "wait_ns": wait, "read_ns": read, "write_ns": write,
+            "pieces": pieces, "bytes": nbytes,
+        }
 
     def drain(self) -> None:
         """Settle the step in flight now: wait for it, publish its
@@ -2533,13 +2526,17 @@ class Learner:
                 # snapshot was queued, so this finds those bytes on the
                 # host already; of a larger tree the next pieces travel
                 # while the one before them is written into its leaf.
-                self._land(
+                parts = self._land(
                     done.version, done.snapshot, done.requested, ready
                 )
                 landed = time.monotonic_ns()
                 self._m_publish.observe((landed - t0) / 1e9)
+                self._m_landing_wait.observe(parts["wait_ns"] / 1e9)
+                self._m_landing_read.observe(parts["read_ns"] / 1e9)
+                self._m_landing_write.observe(parts["write_ns"] / 1e9)
                 self._tracer.complete(
-                    "learner/publish_copy", ready, landed - ready, tag
+                    "learner/publish_copy", ready, landed - ready,
+                    dict(tag, **parts),
                 )
                 # Publish closes the lineage loop: the version stamped
                 # here is what the next unrolls' lineage records carry
@@ -2619,9 +2616,6 @@ class Learner:
             self._tracer.complete(
                 "learner/batch_wait", wait_from, wait_ns, step_tag
             )
-        # Mark the step in flight for the batcher's H2D-overlap scoring
-        # (_note_h2d); _finish_step records the closed interval.
-        self._step_active_since_ns = step_t0_ns
         if self._replay_step is not None:
             # IMPACT path: the pinned target params ride as a fourth
             # (non-donated) state arg. current() raises past the
@@ -2871,8 +2865,6 @@ class Learner:
         if self._moe_last is not None and "moe_held_rows" in logs:
             self._moe_last[0] = (logs["moe_held_rows"], logs["moe_load_max_share"])
         step_dur_ns = dispatched - step_t0_ns
-        self._step_intervals.append((step_t0_ns, dispatched))
-        self._step_active_since_ns = None
         self._m_train_step.observe(step_dur_ns / 1e9)
         self._observe_perf(step_dur_ns)
         T = self._config.unroll_length

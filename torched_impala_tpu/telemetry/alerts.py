@@ -1,7 +1,7 @@
 """SLO burn-rate alerting over the aggregated telemetry snapshot.
 
 A declarative SloSpec table names the objectives (serving p99 vs its
-SLO, h2d overlap floor, ring occupancy ceiling, param-lag budget, pool
+SLO, ring occupancy ceiling, param-lag budget, pool
 step latency) and a multi-window burn-rate engine evaluates them the
 SRE way: each evaluation classifies the current sample good/bad, and
 the *burn rate* over a window is
@@ -232,13 +232,6 @@ def default_slo_specs(
             key="pool/worker_step_ms_p99",
             objective=pool_step_budget_ms,
             budget=0.1,
-        ),
-        SloSpec(
-            name="h2d_overlap",
-            key="perf/h2d_overlap_frac",
-            objective=0.5,
-            kind="lower",
-            budget=0.2,
         ),
         SloSpec(
             name="ring_occupancy",

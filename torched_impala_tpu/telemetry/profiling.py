@@ -149,6 +149,7 @@ class ProfilerCapture:
         self._lock = threading.Lock()
         self._active_dir: Optional[str] = None
         self._since_ns = 0
+        self._since_records = 0
         self._captures = 0
         reg = registry if registry is not None else get_registry()
         self._capture_counter = reg.counter("profiler/captures")
@@ -173,6 +174,7 @@ class ProfilerCapture:
             path = os.path.join(self.trace_dir, tag)
             os.makedirs(path, exist_ok=True)
             self._since_ns, _ = self._recorder.sync_clock()
+            self._since_records = self._recorder.total_recorded
             jax.profiler.start_trace(
                 path, profiler_options=profile_options()
             )
@@ -208,7 +210,10 @@ class ProfilerCapture:
     def _write_host_spans(self, path: str) -> None:
         """The recorder's records of this capture, as a Chrome trace on the
         epoch clock, beside the capture's `.xplane.pb` (in `path` itself
-        when the profiler wrote none)."""
+        when the profiler wrote none). `dropped` in its metadata: records
+        written during the capture that the ring had overwritten by its
+        end, the oldest ones (`perf/report.py` marks the stretch they
+        covered as having no records)."""
         written = glob.glob(
             os.path.join(path, "**", "*.xplane.pb"), recursive=True
         )
@@ -218,6 +223,7 @@ class ProfilerCapture:
             else path
         )
         mono_ns, wall_ns = self._recorder.clock_pair
+        written = self._recorder.total_recorded - self._since_records
         self._recorder.export(
             os.path.join(where, HOST_SPANS_FILE),
             since_ns=self._since_ns,
@@ -226,6 +232,7 @@ class ProfilerCapture:
                 "clock": CLOCK_NOTE,
                 "clock_pair_ns": {"monotonic": mono_ns, "epoch": wall_ns},
                 "host_tracer_level": HOST_TRACER_LEVEL,
+                "dropped": max(0, written - self._recorder.capacity),
             },
         )
 

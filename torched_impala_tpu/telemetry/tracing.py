@@ -59,7 +59,10 @@ PH_END = "E"
 PH_INSTANT = "i"
 PH_COMPLETE = "X"
 
-DEFAULT_CAPACITY = 1 << 14  # ~16k records, ~2 MB — minutes of pipeline
+# ~65k records, ~8 MB: a 5 s profiler capture of the Breakout cell writes
+# about 27k (270 a step, 256 of them `queue/enqueue`), so the ring holds
+# the whole of one (`ProfilerCapture` states what it did not hold).
+DEFAULT_CAPACITY = 1 << 16
 
 
 def _check_trace_name(name: str, _seen=set()) -> None:  # noqa: B006
