@@ -585,8 +585,57 @@ class TestStackBufferReuse:
     (the regime where a bug would silently serve a previous batch's
     data), for both the plain-batch and superbatch assembly paths."""
 
+    RELAID = (0, 3)  # the batch's frames and behaviour logits
+
+    @classmethod
+    def _relay(cls, learner, monkeypatch, K):
+        """Have the step take the frames and the behaviour logits in a
+        layout of their own (the last two axes swapped), as a TPU's AUTO
+        layouts do, so their put goes in two stages; and keep every
+        transfer a copy, as on a TPU. The CPU backend's `device_put`
+        aliases a 64-byte-aligned host buffer instead of copying it,
+        which would make the ring give itself up (the alignment lottery
+        below), so the ring's buffers start 16 bytes past such a line."""
+        from jax.experimental.layout import Format, Layout
+        from jax.sharding import SingleDeviceSharding
+
+        from torched_impala_tpu.runtime import learner as learner_mod
+
+        nd = 3 if K == 1 else 4
+        swapped = Format(
+            Layout(major_to_minor=tuple(range(nd - 2)) + (nd - 1, nd - 2)),
+            SingleDeviceSharding(jax.devices()[0]),
+        )
+        auto = Format(Layout.AUTO)
+        fmts = [auto] * 11  # params, opt, popart, then the batch's 8
+        for i in cls.RELAID:
+            fmts[3 + i] = swapped
+        learner._auto_jit = jax.jit(
+            learner._train_step.__wrapped__,
+            donate_argnums=(0, 1, 2),
+            in_shardings=tuple(fmts),
+            out_shardings=auto,
+        )
+
+        def off_line(x):
+            if not isinstance(x, np.ndarray):
+                return x
+            raw = np.empty(x.nbytes + 80, np.uint8)
+            start = (16 - raw.ctypes.data) % 64
+            return raw[start : start + x.nbytes].view(x.dtype).reshape(
+                x.shape
+            )
+
+        alloc = learner_mod.alloc_stack_buffers
+        monkeypatch.setattr(
+            learner_mod,
+            "alloc_stack_buffers",
+            lambda *a: jax.tree.map(off_line, alloc(*a)),
+        )
+
     def _drain_batches(self, K, reuse, n_batches, T=4, B=3,
-                       use_lstm=True):
+                       use_lstm=True, relay=None, auto_layouts=True,
+                       telemetry=None):
         learner = Learner(
             agent=_agent(use_lstm=use_lstm),
             optimizer=optax.sgd(1e-2),
@@ -596,10 +645,14 @@ class TestStackBufferReuse:
                 steps_per_dispatch=K,
                 queue_capacity=n_batches * K * B,
                 stack_buffer_reuse=reuse,
+                auto_layouts=auto_layouts,
             ),
             example_obs=np.zeros((4,), np.float32),
             rng=jax.random.key(0),
+            telemetry=telemetry,
         )
+        if relay is not None:
+            self._relay(learner, relay, K)
         _push_unrolls(
             learner, learner._agent, n_batches * K * B, T
         )
@@ -624,12 +677,29 @@ class TestStackBufferReuse:
             learner.stop()
         return trajs, drained, learner
 
-    @pytest.mark.parametrize("K", [1, 2])
-    def test_matches_fresh_stacking_through_ring_wrap(self, K):
+    @pytest.mark.parametrize(
+        "K, relaid",
+        [
+            pytest.param(1, False, id="1"),
+            pytest.param(2, False, id="2"),
+            pytest.param(1, True, id="1-relaid"),
+            pytest.param(2, True, id="2-relaid"),
+        ],
+    )
+    def test_matches_fresh_stacking_through_ring_wrap(
+        self, K, relaid, monkeypatch
+    ):
         # 6 batches > the double-buffer ring: it wraps and every buffer
-        # is restacked at least twice.
+        # is restacked at least twice. Relaid: the frames and logits
+        # take the two-stage put, so a slot is restacked as soon as its
+        # transfer is done, before its batch's re-layout has to be.
         T, B, n = 4, 3, 6
-        trajs, drained, learner = self._drain_batches(K, "on", n, T=T, B=B)
+        trajs, drained, learner = self._drain_batches(
+            K, "on", n, T=T, B=B, relay=monkeypatch if relaid else None
+        )
+        if relaid:  # every transfer a copy: the ring must hold
+            assert learner._stack_reuse
+            assert learner._ring_idx >= 3 * len(learner._ring)
         if learner._stack_reuse:
             assert any(b is not None for b in learner._ring), (
                 "ring never engaged"
@@ -662,6 +732,108 @@ class TestStackBufferReuse:
             jax.tree.map(
                 np.testing.assert_array_equal, state, ref.agent_state
             )
+
+    def test_slot_waits_for_the_transfer_not_the_relayout(
+        self, monkeypatch
+    ):
+        """A slot's pending set is what its host buffers were copied into:
+        for a leaf the step takes in a layout of its own, the transfer in
+        the default layout, not the re-laid-out array the step receives
+        (a device program queued behind the steps in flight); for every
+        other leaf, the pushed array itself."""
+        from torched_impala_tpu.telemetry import Registry
+
+        T, B = 4, 3
+        reg = Registry()
+        learner = Learner(
+            agent=_agent(use_lstm=True),
+            optimizer=optax.sgd(1e-2),
+            config=LearnerConfig(
+                batch_size=B, unroll_length=T, stack_buffer_reuse="on",
+            ),
+            example_obs=np.zeros((4,), np.float32),
+            rng=jax.random.key(0),
+            telemetry=reg,
+        )
+        self._relay(learner, monkeypatch, 1)
+        _push_unrolls(learner, learner._agent, B, T)
+        learner.start()
+        try:
+            # One batch's unrolls only: the batcher records the slot's
+            # transfers before it pushes, then waits for more unrolls.
+            arrays, _, _ = learner._batch_q.get(timeout=60)
+            pending = learner._ring_pending[0]
+        finally:
+            learner.stop()
+        assert learner._stack_reuse
+        pushed = jax.tree.leaves(arrays)
+        fmts = jax.tree.leaves(learner._batch_formats)
+        assert len(pending) == len(pushed) == len(fmts)
+        relaid = [i in self.RELAID for i in range(len(pushed))]
+        assert [p is not t for p, t in zip(pushed, pending)] == relaid
+        for p, t, fmt in zip(pushed, pending, fmts):
+            # (a leaf the step does not read has no layout: a plain put)
+            assert fmt.layout is None or p.format == fmt
+            assert t.format.layout.major_to_minor == tuple(range(t.ndim))
+            np.testing.assert_array_equal(np.asarray(p), np.asarray(t))
+        assert pushed[0].format.layout.major_to_minor == (0, 2, 1)
+        assert reg.counter("learner/relayout_puts").value == 2
+
+    def test_a_relayout_into_another_layout_raises_at_the_put(
+        self, monkeypatch
+    ):
+        """Where the put into the step's format hands back another layout
+        (seen on a TPU with the frames), the put raises and names the
+        leaf and both layouts, instead of queueing a batch that the
+        compiled step refuses later."""
+        from jax.experimental.layout import Format, Layout
+        from jax.sharding import SingleDeviceSharding
+
+        from torched_impala_tpu.runtime import learner as learner_mod
+
+        x = np.arange(24, dtype=np.uint8).reshape(2, 3, 4)
+        # the format as a compiled step states it: XLA's layout, tiling
+        # included
+        fmt = jax.device_put(
+            x,
+            Format(
+                Layout(major_to_minor=(0, 2, 1)),
+                SingleDeviceSharding(jax.devices()[0]),
+            ),
+        ).format
+        placed, transfer = learner_mod._put_in_two(x, fmt)
+        assert placed is not transfer and placed.format == fmt
+        put = jax.device_put
+        monkeypatch.setattr(
+            learner_mod.jax,
+            "device_put",
+            lambda a, *d: a if d and isinstance(d[0], Format) else put(a, *d),
+        )
+        with pytest.raises(RuntimeError, match=r"uint8\[2, 3, 4\].*came back"):
+            learner_mod._put_in_two(x, fmt)
+
+    @pytest.mark.parametrize(
+        "layouts, per_batch", [("relaid", 2), ("auto", 0), ("off", 0)]
+    )
+    def test_relayout_puts_counts_the_two_stage_leaves(
+        self, layouts, per_batch, monkeypatch
+    ):
+        """`learner/relayout_puts` counts, per batch, the leaves re-laid
+        after their transfer: the two the step takes in layouts of their
+        own; none where AUTO chose the default layout for every leaf (the
+        CPU's choice), whose transfer is what the step takes; none with
+        `auto_layouts=False`."""
+        from torched_impala_tpu.telemetry import Registry
+
+        reg, n = Registry(), 3
+        _, drained, _ = self._drain_batches(
+            1, "on", n,
+            relay=monkeypatch if layouts == "relaid" else None,
+            auto_layouts=layouts != "off",
+            telemetry=reg,
+        )
+        assert len(drained) == n
+        assert reg.counter("learner/relayout_puts").value == per_batch * n
 
     def test_off_mode_never_allocates_ring(self):
         _, drained, learner = self._drain_batches(1, "off", 3)

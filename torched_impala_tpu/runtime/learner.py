@@ -516,6 +516,32 @@ def _put_format(x, fmt):
     return jax.device_put(x, fmt)
 
 
+def _put_in_two(x, fmt):
+    """A batch leaf into the step's format `fmt`, as `(placed, transfer)`.
+
+    Where the format carries a concrete layout the host bytes first go
+    over in the device's default layout (`transfer`: a DMA, whose
+    completion nothing on the compute queue gates), and the put into
+    `fmt` then lays that array out on the device: the re-layout program
+    a `Format` put runs, queued behind the device's work, or the transfer
+    itself where its layout already is `fmt`. A leaf without a concrete
+    layout takes the default put, which is its own transfer. A put that
+    hands back another layout than `fmt` (the compiled step would refuse
+    the batch) raises here, where the leaf and its format are known."""
+    if fmt.layout is None:
+        placed = jax.device_put(x)
+        return placed, placed
+    transfer = jax.device_put(x, fmt.sharding)
+    placed = jax.device_put(transfer, fmt)
+    if placed.format.layout != fmt.layout:
+        raise RuntimeError(
+            f"auto_layouts: a batch leaf {placed.dtype}{list(placed.shape)} "
+            f"put into {fmt.layout} came back in {placed.format.layout}, "
+            "which the compiled step refuses"
+        )
+    return placed, transfer
+
+
 def _moe_read(last: list, i: int) -> float:
     """A routed core's gauge: entry `i` of the last step's load scalars
     (device arrays until read), NaN before the first step."""
@@ -896,10 +922,14 @@ class Learner:
         # non-aliasing backends only, which the "auto" probe guarantees),
         # so the batcher stacks into slot B while slot A's transfer
         # drains, and _ring_pending blocks out A's transfer before
-        # restacking it. A deeper ring would only pin more batches of
-        # device memory (the pending refs) for no extra overlap — a
-        # measured 6x throughput collapse at B=256,K=4 on a RAM-bound
-        # host. Buffers allocate lazily (shapes come from the first
+        # restacking it. What it waits for is the transfer alone, in the
+        # device's default layout (`_put_batch`'s `transfers`): a leaf's
+        # re-layout into the step's format is a device program queued
+        # behind the steps in flight, which the host buffer need not
+        # wait for. A deeper ring would only pin more batches of device
+        # memory (the pending refs) for no extra overlap (a 6x throughput
+        # collapse at B=256,K=4, measured on a RAM-bound CPU host, not on
+        # the chip). Buffers allocate lazily (shapes come from the first
         # batch); `_stack_reuse` resolves lazily too (the aliasing probe
         # does a device_put).
         if config.stack_buffer_reuse not in ("auto", "on", "off"):
@@ -973,6 +1003,9 @@ class Learner:
         # through the aliasing-fallback owning copy before device_put.
         self._m_host_stack_bytes = reg.counter("learner/host_stack_bytes")
         self._m_ring_stage_bytes = reg.counter("learner/ring_stage_bytes")
+        # Batch leaves placed in two stages (`_put_in_two`): a transfer in
+        # the default layout, then a re-layout program into the step's.
+        self._m_relayout_puts = reg.counter("learner/relayout_puts")
         # The hybrid core's carry (models/hybrid.py): what one row of it
         # weighs, and how many episode starts the batch just stacked
         # holds (each resets the scan and the convolution inside the
@@ -1942,18 +1975,17 @@ class Learner:
         self._last_slot = i
         return self._ring[i]
 
-    def _record_pending_transfer(self, on_device) -> None:
-        """Remember the device arrays built from the last ring slot so the
-        slot blocks them out before reuse. Strong references by design:
-        a dead weakref cannot prove the (possibly background-dispatched)
-        copy ran, so the block must never be skippable. The refs pin at
-        most the two ring slots' batches in device memory — usually still
-        alive in the device queue anyway — and are dropped as the ring
-        wraps."""
+    def _record_pending_transfer(self, leaves) -> None:
+        """Remember the device arrays the last ring slot was copied into
+        (`_put_batch`'s `transfers`: not the re-laid-out arrays the step
+        takes) so the slot blocks them out before reuse. Strong
+        references by design: a dead weakref cannot prove the (possibly
+        background-dispatched) copy ran, so the block must never be
+        skippable. The refs pin at most the two ring slots' transfers in
+        device memory, and are dropped as the ring wraps."""
         if not self._stack_reuse_enabled() or self._last_slot is None:
             return
         slot, self._last_slot = self._last_slot, None
-        leaves = jax.tree.leaves(on_device)
         if not self._ring_checked[slot]:
             # One-time per-slot safety net (covers a force-"on" config on
             # an aliasing backend the auto probe would have rejected): if
@@ -2133,7 +2165,11 @@ class Learner:
     def _put_batch(self, arrays):
         """H2D placement of one assembled batch 8-tuple, honoring
         AUTO-layout formats / the mesh — shared by the queue and
-        trajectory-ring batcher loops."""
+        trajectory-ring batcher loops. Returns `(on_device, transfers)`:
+        the batch as the step takes it, and the list of device arrays the
+        host buffers were copied into, whose readiness frees those
+        buffers. They differ only for leaves re-laid after their transfer
+        (`_put_in_two`), which `learner/relayout_puts` counts."""
         if self._mesh is None:
             # Locals, not repeated attribute reads: step_once's
             # layout-mismatch fallback nulls these from the main
@@ -2149,13 +2185,22 @@ class Learner:
             else:
                 fmts = None
             if fmts is not None:
-                return jax.tree.map(_put_format, arrays, fmts)
-            return jax.device_put(arrays)
-        # Single-host: one device_put PER DATA SHARD, sliced straight
-        # from the host buffer (a ring slot view on the zero-copy path).
-        # Multi-host: this host's local slice becomes its shards of the
-        # global batch array.
-        return multihost.place_batch(self._batch_shardings, arrays)
+                leaves, tree = jax.tree.flatten(arrays)
+                placed, transfers = zip(
+                    *map(_put_in_two, leaves, tree.flatten_up_to(fmts))
+                )
+                self._m_relayout_puts.inc(
+                    sum(p is not t for p, t in zip(placed, transfers))
+                )
+                return jax.tree.unflatten(tree, placed), list(transfers)
+            on_device = jax.device_put(arrays)
+        else:
+            # Single-host: one device_put PER DATA SHARD, sliced straight
+            # from the host buffer (a ring slot view on the zero-copy
+            # path). Multi-host: this host's local slice becomes its
+            # shards of the global batch array.
+            on_device = multihost.place_batch(self._batch_shardings, arrays)
+        return on_device, jax.tree.leaves(on_device)
 
     def _timed_sync(self, tree) -> None:
         """block_until_ready(tree), crediting only the GENUINE device
@@ -2265,7 +2310,7 @@ class Learner:
             put_t0 = time.monotonic_ns()
             put_span = self._m_device_put.time()
             put_span.__enter__()
-            on_device = self._put_batch(arrays)
+            on_device, transfers = self._put_batch(arrays)
             put_span.__exit__()
             self._tracer.complete(
                 "learner/device_put",
@@ -2273,7 +2318,7 @@ class Learner:
                 time.monotonic_ns() - put_t0,
                 {"batch": meta.batch},
             )
-            self._record_pending_transfer(on_device)
+            self._record_pending_transfer(transfers)
             if not self._push_device_batch(
                 on_device, batch.param_version, meta
             ):
@@ -2341,7 +2386,7 @@ class Learner:
             put_t0 = time.monotonic_ns()
             put_span = self._m_device_put.time()
             put_span.__enter__()
-            on_device = self._put_batch(arrays)
+            on_device, transfers = self._put_batch(arrays)
             put_span.__exit__()
             put_dur = time.monotonic_ns() - put_t0
             if donate:
@@ -2366,7 +2411,7 @@ class Learner:
                 # The staged copy owns its memory; the slot is free now.
                 ring.release(view.slot)
             else:
-                leaves = jax.tree.leaves(on_device)
+                leaves = transfers
                 if not alias_checked:
                     # One-time safety net (covers a force-"on"
                     # stack_buffer_reuse on an aliasing backend the auto
