@@ -342,3 +342,26 @@ def test_block_sizes_never_widen_padding():
             assert Sp == tight, (T, S, Sp, tight)
             assert Sp % Sb == 0 and 128 <= Sb <= 512, (T, S, Sb, Sp)
             assert Tp % Tb == 0 and Tb % 8 == 0 and Tp >= T, (T, S)
+
+
+@pytest.mark.parametrize("dk,dv", [(192, 128), (24, 8)])
+def test_a_value_width_other_than_the_key_width(dk, dv):
+    """Latent attention's shapes (keys of 192 = 128 + a rotary part of 64,
+    values of 128; and a small pair): forward and every gradient of the
+    kernels, interpreted, against the einsum path, the scores scaled by
+    the key width; and the hybrid core's written-out mask path (its step
+    mode) gives the same output."""
+    from torched_impala_tpu.models.hybrid import _masked_attention
+
+    rng = np.random.default_rng(12)
+    q, k, _, seg_q, seg_ctx, W = random_case(rng, B=2, T=9, H=2, dh=dk, W=7)
+    v = jnp.asarray(rng.normal(size=(2, 16, 2, dv)), jnp.float32)
+    out = attention_pallas.windowed_attention(q, k, v, seg_q, seg_ctx, W, True)
+    ref = reference_attention(q, k, v, seg_q, seg_ctx, W)
+    assert out.shape == (2, 9, 2, dv)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(_masked_attention(q, k, v, seg_q, seg_ctx, W, None)),
+        np.asarray(ref), rtol=1e-5, atol=1e-5,
+    )
+    assert_grads_match_reference((q, k, v, seg_q, seg_ctx, W), msg=f"dk={dk} dv={dv}")

@@ -358,6 +358,37 @@ def test_hybrid_attention(one_chip, kind, cache, window):
         assert f"attention_{kind}_{part}" in text, part
 
 
+def test_latent_attention(one_chip):
+    """The latent layers' attention at the published widths
+    (`pong_joyai_flash`): 32 heads, keys of 192 (128 + a rotary part of
+    64) and values of 128, bfloat16, B=2, 2,048 positions behind a cache
+    of 2,048: forward, dQ and dK/dV kernels under their own names, the
+    values' width in their blocks."""
+    B, T, H, W = 2, 2048, 32, 2048
+    S = W + T
+
+    def loss(q, k, v, seg_q, seg_ctx):
+        return jnp.sum(
+            windowed_attention(
+                q, k, v, seg_q, seg_ctx, W, None, None, "attention_latent"
+            ).astype(F32)
+        )
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        _shape(one_chip, (B, T, H, 192), jnp.bfloat16),
+        _shape(one_chip, (B, S, H, 192), jnp.bfloat16),
+        _shape(one_chip, (B, S, H, 128), jnp.bfloat16),
+        _shape(one_chip, (B, T), jnp.int32),
+        _shape(one_chip, (B, S), jnp.int32),
+    ).compile()
+    text = compiled.as_text()
+    for part in ("forward", "backward_dq", "backward_dkv"):
+        assert f"attention_latent_{part}" in text, part
+    # the forward's output and the values' gradient are 128 wide
+    assert re.search(r"f32\[2,32,2048,128\]", text)
+    assert re.search(r"f32\[2,32,4096,128\]", text)
+
+
 def test_selective_scan(one_chip):
     """The scan's forward and backward kernels at the published widths:
     B=2, 2,048 positions, d_inner 5120, d_state 16; the state is never

@@ -150,6 +150,20 @@ class ExperimentConfig:
     hybrid_experts_per_token: int = 4
     hybrid_experts_held: tuple = (0, 8)
     hybrid_expert_width: int = 1792
+    # A routed core's other choices: the first `hybrid_dense_layers` blocks
+    # take the gated MLP (`hybrid_d_intermediate`); an expert of width
+    # `hybrid_shared_expert` (0: none) that every token takes; the chosen
+    # scores over their sum, times `hybrid_routed_scale`.
+    hybrid_dense_layers: int = 0
+    hybrid_shared_expert: int = 0
+    hybrid_routed_scale: float = 1.0
+    # Layers of kind "latent" (multi-head latent attention): (q_rank,
+    # kv_rank, nope_dim, rope_dim, v_dim); `hybrid_rope_theta` is the
+    # rotary base of "full_rope" and "latent" layers, `hybrid_eps` every
+    # norm's epsilon.
+    hybrid_latent: tuple = ()
+    hybrid_rope_theta: float = 1e6
+    hybrid_eps: float = 1e-5
     # Shard the unroll's time axis over this many devices (the 'seq' mesh
     # axis); 0 = off. Combined with dp_devices as a ('data','seq') mesh.
     sp_devices: int = 0
@@ -474,6 +488,12 @@ def make_agent(cfg: ExperimentConfig, mesh=None) -> Agent:
             ("experts_per_token", cfg.hybrid_experts_per_token),
             ("experts_held", tuple(cfg.hybrid_experts_held)),
             ("expert_width", cfg.hybrid_expert_width),
+            ("dense_layers", cfg.hybrid_dense_layers),
+            ("shared_expert", cfg.hybrid_shared_expert),
+            ("routed_scale", cfg.hybrid_routed_scale),
+            ("latent", tuple(cfg.hybrid_latent)),
+            ("rope_theta", cfg.hybrid_rope_theta),
+            ("ln_eps", cfg.hybrid_eps),
         )
     transformer = (
         ("d_model", cfg.transformer_d_model),
@@ -961,6 +981,33 @@ PONG_LFM2_MOE = dataclasses.replace(
     hybrid_expert_width=1792,
 )
 
+# JoyAI-LLM-Flash's leading dense layer and its first four expert layers
+# (a DeepSeek-V3 decoder: latent attention of 32 heads, query/key width 192
+# = 128 + a rotary part of 64 shared by every head, values of 128; a
+# latent cache of c_kv 512 and k_pe 64 a position) as the temporal core on
+# Pong's shapes, at the published widths. The learner is one of the 32
+# chips that share each layer's 256 routed experts: it holds 8 of them
+# (top-8 over all 256, sigmoid scores, scale 2.5) beside the shared
+# expert; 347.8M parameters in the core.
+# benchmark/configs/pong_joyai_flash_core.json states the cut.
+PONG_JOYAI_FLASH = dataclasses.replace(
+    PONG_LFM2_MOE,
+    name="pong_joyai_flash",
+    hybrid_layers=("latent",) * 5,
+    hybrid_heads=32,
+    hybrid_latent=(1536, 512, 128, 64, 128),
+    hybrid_rope_theta=32_000_000.0,
+    hybrid_eps=1e-6,
+    hybrid_d_intermediate=7168,
+    hybrid_dense_layers=1,
+    hybrid_experts=256,
+    hybrid_experts_per_token=8,
+    hybrid_experts_held=(0, 8),
+    hybrid_expert_width=768,
+    hybrid_shared_expert=768,
+    hybrid_routed_scale=2.5,
+)
+
 # On-device (Anakin) presets: the whole actor-learner is one XLA program
 # over pure-JAX envs (runtime/anakin.py). batch_size = on-device env count.
 # Same MDPs as their host counterparts (envs/jax_envs.py parity tests), so
@@ -1027,6 +1074,7 @@ REGISTRY: dict[str, ExperimentConfig] = {
         PONG_TRANSFORMER,
         PONG_PHI4FLASH,
         PONG_LFM2_MOE,
+        PONG_JOYAI_FLASH,
         CARTPOLE_ANAKIN,
         CATCH_ANAKIN,
         PIXELS_ANAKIN,

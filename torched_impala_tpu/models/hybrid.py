@@ -1,21 +1,25 @@
 """Hybrid temporal core: state-space (Mamba-1), gated short-convolution,
-sliding-window and full attention layers in one stack, each followed by a
-gated MLP or a mixture of experts — the self-decoder of SambaY
-(arXiv:2507.06607; Phi-4-mini-flash-reasoning) and LFM2-8B-A1B's hybrid
-(huggingface.co/LiquidAI/LFM2-8B-A1B) as an agent's recurrence.
+sliding-window, full and latent attention layers in one stack, each
+followed by a gated MLP or a mixture of experts — the self-decoder of
+SambaY (arXiv:2507.06607; Phi-4-mini-flash-reasoning), LFM2-8B-A1B's hybrid
+(huggingface.co/LiquidAI/LFM2-8B-A1B) and a DeepSeek-V3 decoder
+(arXiv:2412.19437 section 2.1; JoyAI-LLM-Flash) as an agent's recurrence.
 
 Every block is `h = x + Mixer(N(x)); y = h + MLP(N(h))`, `N` a LayerNorm
 (`norm="layer"`) or an RMSNorm (`norm="rms"`), no biases in the
 projections. `mlp="gated"`: `MLP(u) = W_down(silu(W_gate u) * W_up u)`.
 `mlp="moe"`: a routed mixture of such MLPs (`_MoE`): `s = sigmoid(W_r u)`
 over all `num_experts` experts of the layer, the top `experts_per_token`
-of `s + expert_bias` chosen (the bias only chooses), their `s` normalised
-to sum to one (a routed scaling factor of 1); the layer holds the experts
-`experts_held = (first, count)` of them and computes their part of the sum
-alone, as a chip of an expert-parallel deployment does (no exchange, and
-nothing stands in for the experts held elsewhere). Dropless: the rows
-routed to the held experts go through ops/grouped_matmul.py whatever the
-load. `layers` names each block's mixer:
+of `s + expert_bias` chosen (the bias only chooses), their `s` divided by
+their sum plus 1e-6 and times `routed_scale`; the layer holds the
+experts `experts_held = (first, count)` of them and computes their part of
+the sum alone, as a chip of an expert-parallel deployment does (no
+exchange, and nothing stands in for the experts held elsewhere), and adds
+a gated MLP of width `shared_expert` that every token takes, whole on
+every chip (none where 0). Dropless: the rows routed to the held experts
+go through ops/grouped_matmul.py whatever the load. With `mlp="moe"` the
+first `dense_layers` blocks take the gated MLP instead. `layers` names
+each block's mixer:
 
 - `"mamba"`: `[x, z] = W_in u`; `x = silu(conv1d_causal(x) + b)`;
   `(d, B_t, C_t) = W_x x`; `dt = softplus(W_dt d + b_dt)`;
@@ -41,8 +45,19 @@ load. `layers` names each block's mixer:
   `[B, C, x] = W_in u`; `y = C * conv1d_causal(B * x)` (depthwise,
   `conv_taps` taps, no bias); out `= W_out y`. Its carry is the last
   `conv_taps - 1` gated inputs `B * x`, of the running episode or zero.
+- `"latent"` (multi-head latent attention), `latent = (q_rank, kv_rank,
+  nope, rope, v)`: `c_q = RMSNorm(W_qa u)`, `[q_nope, q_pe]_h = (W_qb
+  c_q)_h`; `[c_kv, k_pe] = W_kva u`, `c_kv = RMSNorm(c_kv)`; `q_pe` and
+  `k_pe` (one head, shared by every head) take rotary embeddings on
+  interleaved pairs `(x_2i, x_2i+1)` at each position's absolute step;
+  `[k_nope, v]_h = (W_kvb c_kv)_h` over cache and unroll alike; attention
+  of `[q_nope, q_pe]` on `[k_nope, k_pe]` (width nope + rope, scaled by
+  it) over values of width `v`, the full layers' visibility; out `= W_o
+  o`. The cache holds per position `c_kv` after its norm and `k_pe` after
+  its rotation (`kv_rank + rope` numbers), and is up-projected once an
+  unroll, into the fused kernels' operands.
 
-The carry holds state of up to four kinds and two cache lengths, per row
+The carry holds state of up to five kinds and two cache lengths, per row
 (batch-major, so the learner's batcher concatenates it leaf by leaf like
 any recurrent state; float32/int32); a kind no layer has is `None`:
 
@@ -50,15 +65,24 @@ any recurrent state; float32/int32); a kind no layer has is `None`:
   ssm    `[B, Lm, N, Di]`        the scan state (the scan kernels' layout)
   k_win, v_win   `[B, Lw, W, Hkv*dh]`, win_seg/win_pos `[B, W]`
   k_full, v_full `[B, Lf, F, Hkv*dh]`, full_seg/full_pos `[B, F]`
-                                 (Lf: the "full" and "full_rope" layers)
+                                 (Lf: the "full" and "full_rope" layers;
+                                 the slots' counters are the latent
+                                 layers' too)
   pos `[B]` next absolute position, seg `[B]` running episode counter
   sconv  `[B, Ls, conv_taps-1, D]` the short convolutions' gated inputs
+  lat_ckv `[B, Ll, F, kv_rank]`  the latent layers' normalised `c_kv`
+  lat_kpe `[B, Ll, F*rope/128, 128]` their rotated `k_pe`, 128/rope
+                                 positions to a row
 
 Every large leaf ends in a dimension that is a multiple of 128 (channels,
 or a position's key/value heads side by side), so that the learner's
 `device_put` of a stacked carry is a straight copy: the chip's tiled
 layout would pad a trailing `[20, 64]` or `[5120, 16]` and the host would
-re-lay 54 MB a batch (0.33 s a batch; my chip run, PR 34).
+re-lay 54 MB a batch (0.33 s a batch, measured on one v5e chip). So a latent
+cache keeps `c_kv` and `k_pe` apart, and lays `k_pe` (64 numbers a
+position at the published widths) two positions to a row: no padded
+lanes, where one leaf of `kv_rank + rope` = 576 padded to 640 would carry
+11% of zeros through every batch.
 
 Cache slots are ordered oldest first and hold consecutive positions (slot
 j of a cache of W holds position `pos - W + j` once written; `*_seg` is -1
@@ -100,7 +124,7 @@ from torched_impala_tpu.ops.selective_scan import selective_scan
 
 F32 = jnp.float32
 NEG_INF = -1e30
-KINDS = ("mamba", "window", "full", "full_rope", "short_conv")
+KINDS = ("mamba", "window", "full", "full_rope", "short_conv", "latent")
 
 
 class HybridCoreState(NamedTuple):
@@ -117,6 +141,8 @@ class HybridCoreState(NamedTuple):
     pos: jax.Array
     seg: jax.Array
     sconv: Any = None
+    lat_ckv: Any = None
+    lat_kpe: Any = None
 
 
 def _a_log_init(key, shape, dtype=F32):
@@ -165,10 +191,11 @@ class _Linear(nn.Module):
 class _MLP(nn.Module):
     d_intermediate: int
     dtype: Any = F32
+    trace_name: str = "core/mlp"
 
     @nn.compact
     def __call__(self, u):
-        with jax.named_scope("core/mlp"):
+        with jax.named_scope(self.trace_name):
             gate = _Linear(self.d_intermediate, self.dtype, name="gate")(u)
             up = _Linear(self.d_intermediate, self.dtype, name="up")(u)
             return _Linear(u.shape[-1], self.dtype, name="down")(
@@ -199,6 +226,17 @@ def _rope(x, pos, theta: float):
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     a, b = x[..., :half], x[..., half:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
+
+
+def _rope_pairs(x, pos, theta: float):
+    """Rotary embedding on interleaved pairs: `(x[2i], x[2i+1])` of `x`
+    `[B, T, H, d]` float32 turns by `pos * theta^(-2i/d)`, `pos` `[B, T]`."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=F32) / d)
+    ang = pos.astype(F32)[..., None, None] * inv  # [B, T, 1, d/2]
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    a, b = x[..., 0::2], x[..., 1::2]
+    return jnp.stack([a * cos - b * sin, b * cos + a * sin], -1).reshape(x.shape)
 
 
 def _causal_conv(x, window0, w, seg_q, seg0, bias=0.0):
@@ -254,6 +292,8 @@ class _MoE(nn.Module):
     experts_held: tuple  # (first, count)
     d_ff: int
     dtype: Any = F32
+    shared: int = 0  # width of the expert every token takes; 0: none
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, u):
@@ -276,6 +316,8 @@ class _MoE(nn.Module):
             _, chosen = jax.lax.top_k(jax.lax.stop_gradient(scores + bias), k)
             weight = jnp.take_along_axis(scores, chosen, axis=1)
             weight = weight / (jnp.sum(weight, 1, keepdims=True) + 1e-6)
+            if self.routed_scale != 1.0:
+                weight = weight * self.routed_scale
             local = chosen - first
             mine = (local >= 0) & (local < held)
             weight = jnp.where(mine, weight, 0.0)
@@ -296,7 +338,12 @@ class _MoE(nn.Module):
             # a slot not held here reads no row: the buffer's rows past
             # the groups hold no defined value (ops/grouped_matmul.py)
             out = combine(y, weight, pos, slot, filled)
-        return out.reshape(B, T, D), rows
+        out = out.reshape(B, T, D)
+        if self.shared:
+            out = out + _MLP(
+                self.shared, self.dtype, "core/moe_shared", name="shared"
+            )(u)
+        return out, rows
 
 
 class _Mamba(nn.Module):
@@ -403,9 +450,75 @@ class _Attention(nn.Module):
         return out, new_k, new_v
 
 
+class _Latent(nn.Module):
+    """Multi-head latent attention (module docstring, kind `"latent"`)."""
+
+    num_heads: int
+    latent: tuple  # (q_rank, kv_rank, nope, rope, v)
+    rope_theta: float
+    dtype: Any = F32
+    kernel: str = "einsum"
+    eps: float = 1e-6  # the latent norms
+
+    @nn.compact
+    def __call__(self, u, ckv_cache, kpe_cache, cache_seg, seg_q, pos_q):
+        """u `[B, T, D]`; the cache's `c_kv` `[B, F, kv_rank]` and rotated
+        `k_pe` `[B, F*rope/128, 128]` (float32), cache_seg `[B, F]`, seg_q
+        and pos_q `[B, T]`. Returns (out `[B, T, D]` float32, new `c_kv`
+        cache, new `k_pe` cache)."""
+        B, T, D = u.shape
+        H = self.num_heads
+        q_rank, kv_rank, nope, rope, dv = self.latent
+        F = ckv_cache.shape[1]
+        c_q = _RMSNorm(self.eps, name="q_a_norm")(
+            _Linear(q_rank, self.dtype, name="q_a_proj")(u, F32)
+        )
+        q = _Linear(H * (nope + rope), self.dtype, name="q_b_proj")(
+            c_q, F32
+        ).reshape(B, T, H, nope + rope)
+        q = jnp.concatenate(
+            [q[..., :nope], _rope_pairs(q[..., nope:], pos_q, self.rope_theta)],
+            -1,
+        ).astype(self.dtype)
+        kv = _Linear(kv_rank + rope, self.dtype, name="kv_a_proj")(u, F32)
+        c_kv = _RMSNorm(self.eps, name="kv_a_norm")(kv[..., :kv_rank])
+        k_pe = _rope_pairs(kv[..., None, kv_rank:], pos_q, self.rope_theta)
+        ckv_ctx = jnp.concatenate([ckv_cache, c_kv], axis=1)  # [B, S, kv_rank]
+        kpe_ctx = jnp.concatenate(
+            [kpe_cache.reshape(B, F, rope), k_pe[:, :, 0]], axis=1
+        )
+        S = F + T
+        with jax.named_scope("core/latent_kv"):
+            kv = _Linear(H * (nope + dv), self.dtype, name="kv_b_proj")(
+                ckv_ctx
+            ).reshape(B, S, H, nope + dv)
+            k = jnp.concatenate(
+                [
+                    kv[..., :nope],
+                    jnp.broadcast_to(
+                        kpe_ctx.astype(self.dtype)[:, :, None], (B, S, H, rope)
+                    ),
+                ],
+                -1,
+            )
+            v = kv[..., nope:]
+        seg_ctx = jnp.concatenate([cache_seg, seg_q], axis=1)
+        with jax.named_scope("core/attention_latent"):
+            if self.kernel == "pallas" and T > 1:
+                out = windowed_attention(
+                    q, k, v, seg_q, seg_ctx, F, None, None, "attention_latent"
+                )
+            else:
+                out = _masked_attention(q, k, v, seg_q, seg_ctx, F, None)
+        out = _Linear(D, self.dtype, name="o_proj")(
+            out.reshape(B, T, H * dv), F32
+        )
+        return out, ckv_ctx[:, -F:], kpe_ctx[:, -F:].reshape(kpe_cache.shape)
+
+
 def _masked_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, window):
     """Attention under the written-out mask (step mode, the CPU, a mesh):
-    scores and softmax in float32."""
+    scores and softmax in float32, scaled by the query/key width."""
     B, T, H, dh = q.shape
     S, Hkv = k_ctx.shape[1:3]
     mask = _visibility(seg_q, seg_ctx, T, S, W, window)  # the kernels' own
@@ -419,7 +532,7 @@ def _masked_attention(q, k_ctx, v_ctx, seg_q, seg_ctx, W, window):
         "bkgts,bskd->btkgd", probs.astype(q.dtype), v_ctx,
         preferred_element_type=F32,
     )
-    return out.reshape(B, T, H, dh).astype(q.dtype)
+    return out.reshape(B, T, H, v_ctx.shape[-1]).astype(q.dtype)
 
 
 class _Block(nn.Module):
@@ -451,6 +564,11 @@ class _Block(nn.Module):
             out, *new = _ShortConv(
                 dtype=self.dtype, name="short_conv", **self.mixer
             )(u, *state, seg_q, seg0)
+        elif self.kind == "latent":
+            out, *new = _Latent(
+                dtype=self.dtype, eps=self.eps, name="latent_attention",
+                **self.mixer,
+            )(u, *state, seg_q, pos_q)
         else:
             k_cache, v_cache, cache_seg = state
             out, *new = _Attention(
@@ -497,6 +615,12 @@ class HybridCore(nn.Module):
     experts_per_token: int = 4
     experts_held: tuple = (0, 8)  # (first, count) of the experts held here
     expert_width: int = 1792
+    dense_layers: int = 0  # mlp="moe": the leading blocks with a gated MLP
+    shared_expert: int = 0  # mlp="moe": its width; 0 = none
+    routed_scale: float = 1.0
+    # "latent" layers: (q_rank, kv_rank, nope, rope, v); `num_heads` heads,
+    # a cache of `full_cache` positions, rotary base `rope_theta`
+    latent: tuple = ()
     dtype: Any = F32  # the matrix products' operands
     # Recompute each block in the backward pass (nn.remat): a block's
     # activations (the MLP's hidden layer, the scan's inputs) are then
@@ -518,6 +642,15 @@ class HybridCore(nn.Module):
         B, kv = batch_size, self.num_kv_heads * self.head_dim
         lm, lw = self._count("mamba"), self._count("window")
         lf, ls = self._count("full", "full_rope"), self._count("short_conv")
+        ll = self._count("latent")
+        kv_rank = rope = 0
+        if ll:
+            _, kv_rank, _, rope, _ = self.latent
+            if self.full_cache * rope % 128:
+                raise ValueError(
+                    f"a latent cache of {self.full_cache} positions of "
+                    f"{rope} rotary numbers does not fill rows of 128"
+                )
 
         def zeros(n, *s):
             return jnp.zeros((B, n, *s), F32) if n else None
@@ -534,11 +667,13 @@ class HybridCore(nn.Module):
             win_pos=slots(lw, self.window, 0),
             k_full=zeros(lf, self.full_cache, kv),
             v_full=zeros(lf, self.full_cache, kv),
-            full_seg=slots(lf, self.full_cache, -1),
-            full_pos=slots(lf, self.full_cache, 0),
+            full_seg=slots(lf + ll, self.full_cache, -1),
+            full_pos=slots(lf + ll, self.full_cache, 0),
             pos=jnp.zeros((B,), jnp.int32),
             seg=jnp.zeros((B,), jnp.int32),
             sconv=zeros(ls, self.conv_taps - 1, self.d_model),
+            lat_ckv=zeros(ll, self.full_cache, kv_rank),
+            lat_kpe=zeros(ll, self.full_cache * rope // 128, 128),
         )
 
     def state_bytes_per_row(self) -> int:
@@ -575,6 +710,10 @@ class HybridCore(nn.Module):
             "full": attention,
             "full_rope": dict(attention, rope_theta=self.rope_theta),
             "short_conv": dict(taps=self.conv_taps),
+            "latent": dict(
+                num_heads=self.num_heads, latent=tuple(self.latent),
+                rope_theta=self.rope_theta, kernel=self.attention_kernel,
+            ),
         }
         if self.mlp == "moe":
             mlp = dict(
@@ -582,15 +721,18 @@ class HybridCore(nn.Module):
                 experts_per_token=self.experts_per_token,
                 experts_held=tuple(self.experts_held),
                 d_ff=self.expert_width,
+                shared=self.shared_expert,
+                routed_scale=self.routed_scale,
             )
         elif self.mlp == "gated":
             mlp = dict(d_intermediate=self.d_intermediate)
         else:
             raise ValueError(f"unknown mlp {self.mlp!r}; expected 'gated' or 'moe'")
+        dense = dict(d_intermediate=self.d_intermediate)
         block_cls = nn.remat(_Block) if self.remat else _Block
         cache = {
             "mamba": "mamba", "window": "window", "full": "full",
-            "full_rope": "full", "short_conv": "short_conv",
+            "full_rope": "full", "short_conv": "short_conv", "latent": "latent",
         }
         seen = dict.fromkeys(cache.values(), 0)
         new = {k: [] for k in seen}
@@ -604,12 +746,14 @@ class HybridCore(nn.Module):
                 part = (state.k_win[:, j], state.v_win[:, j], state.win_seg)
             elif kind == "short_conv":
                 part = (state.sconv[:, j],)
+            elif kind == "latent":
+                part = (state.lat_ckv[:, j], state.lat_kpe[:, j], state.full_seg)
             else:
                 part = (state.k_full[:, j], state.v_full[:, j], state.full_seg)
             x, out, rows = block_cls(
                 kind=kind,
                 mixer=mixers[kind],
-                mlp=mlp,
+                mlp=dense if i < self.dense_layers else mlp,
                 norm=self.norm,
                 eps=self.ln_eps,
                 dtype=self.dtype,
@@ -654,5 +798,7 @@ class HybridCore(nn.Module):
             pos=state.pos + T,
             seg=seg_q[:, -1],
             sconv=stacked("short_conv", 0, state.sconv),
+            lat_ckv=stacked("latent", 0, state.lat_ckv),
+            lat_kpe=stacked("latent", 1, state.lat_kpe),
         )
         return out.transpose(1, 0, 2), new_state

@@ -138,14 +138,14 @@ def _tile_probs(q, k, seg_q, seg_c, lse, t_off, s_off, scale, W, window):
 def _fwd_kernel(
     q_ref,  # [1, 1, Tb, dh]
     k_ref,  # [1, 1, Sb, dh]
-    v_ref,  # [1, 1, Sb, dh]
+    v_ref,  # [1, 1, Sb, dv]
     segq_ref,  # [1, Tb, 1] int32 (sublane-oriented)
     segc_ref,  # [1, 1, Sb] int32 (lane-oriented)
-    o_ref,  # [1, 1, Tb, dh]
+    o_ref,  # [1, 1, Tb, dv]
     lse_ref,  # [1, 1, Tb, 1]
     m_scr,  # [Tb, 1] scratch: running row max
     l_scr,  # [Tb, 1] scratch: running normalizer
-    acc_scr,  # [Tb, dh] scratch: running output accumulator
+    acc_scr,  # [Tb, dv] scratch: running output accumulator
     *,
     scale: float,
     W: int,
@@ -241,7 +241,8 @@ def _tile_specs(
     interpret mode under the CPU conftest:
 
     - q/k/v/g/o: `[B, H, seq, dh]`, block (1, 1, Tb|Sb, dh) — seq is a
-      multiple of 8, dh equals the array dim;
+      multiple of 8, dh equals the array dim (called once more with the
+      value width for v, g and o where it differs from q's and k's);
     - lse/D rows: `[B, H, Tp, 1]`, block (1, 1, Tb, 1) — sublane rows
       broadcast directly against [Tb, Sb] tiles;
     - seg_q: `[B, Tp, 1]` (sublane), seg_c: `[B, 1, Sp]` (lane) so the
@@ -291,9 +292,9 @@ def _forward(
     q, k_ctx, v_ctx, seg_q, seg_ctx, W: int, interpret, window=None,
     name="attention",
 ):
-    """Returns (out `[B, T, H, dh]` f32, lse `[B, H, Tp, 1]` f32)."""
+    """Returns (out `[B, T, H, dv]` f32, lse `[B, H, Tp, 1]` f32)."""
     B, T, H, dh = q.shape
-    S = k_ctx.shape[1]
+    S, dv = k_ctx.shape[1], v_ctx.shape[-1]
     group = _groups(q, k_ctx)
     f32 = jnp.float32
 
@@ -328,20 +329,21 @@ def _forward(
     q_spec, kv_spec, lse_spec, segq_spec, segc_spec = _tile_specs(
         Tb, Sb, dh, t_inner=False, group=group
     )
+    o_spec, v_spec = _tile_specs(Tb, Sb, dv, t_inner=False, group=group)[:2]
     out, lse = pallas_call(
         kernel,
         name=f"{name}_forward",
         grid=(B, H, Tp // Tb, Sp // Sb),
-        in_specs=[q_spec, kv_spec, kv_spec, segq_spec, segc_spec],
-        out_specs=(q_spec, lse_spec),
+        in_specs=[q_spec, kv_spec, v_spec, segq_spec, segc_spec],
+        out_specs=(o_spec, lse_spec),
         out_shape=(
-            jax.ShapeDtypeStruct((B, H, Tp, dh), f32),
+            jax.ShapeDtypeStruct((B, H, Tp, dv), f32),
             jax.ShapeDtypeStruct((B, H, Tp, 1), f32),
         ),
         scratch_shapes=[
             pltpu.VMEM((Tb, 1), f32),
             pltpu.VMEM((Tb, 1), f32),
-            pltpu.VMEM((Tb, dh), f32),
+            pltpu.VMEM((Tb, dv), f32),
         ],
         interpret=interpret,
     )(qp, kp, vp, segq_p, segc_p)
@@ -351,8 +353,8 @@ def _forward(
 def _dq_kernel(
     q_ref,  # [1, 1, Tb, dh]
     k_ref,  # [1, 1, Sb, dh]
-    v_ref,  # [1, 1, Sb, dh]
-    g_ref,  # [1, 1, Tb, dh] output cotangent
+    v_ref,  # [1, 1, Sb, dv]
+    g_ref,  # [1, 1, Tb, dv] output cotangent
     lse_ref,  # [1, 1, Tb, 1]
     dcap_ref,  # [1, 1, Tb, 1]  D_i = sum_d O_id dO_id
     segq_ref,  # [1, Tb, 1]
@@ -401,16 +403,16 @@ def _dq_kernel(
 def _dkv_kernel(
     q_ref,  # [1, 1, Tb, dh]
     k_ref,  # [1, 1, Sb, dh]
-    v_ref,  # [1, 1, Sb, dh]
-    g_ref,  # [1, 1, Tb, dh]
+    v_ref,  # [1, 1, Sb, dv]
+    g_ref,  # [1, 1, Tb, dv]
     lse_ref,  # [1, 1, Tb, 1]
     dcap_ref,  # [1, 1, Tb, 1]
     segq_ref,  # [1, Tb, 1]
     segc_ref,  # [1, 1, Sb]
     dk_ref,  # [1, 1, Sb, dh]
-    dv_ref,  # [1, 1, Sb, dh]
+    dv_ref,  # [1, 1, Sb, dv]
     dk_scr,  # [Sb, dh] scratch
-    dv_scr,  # [Sb, dh] scratch
+    dv_scr,  # [Sb, dv] scratch
     *,
     scale: float,
     W: int,
@@ -446,7 +448,7 @@ def _dkv_kernel(
         )  # [Tb, Sb]
         dv_scr[...] += _dot(
             p.astype(g.dtype), g, ((0,), (0,))
-        )  # [Sb, dh]
+        )  # [Sb, dv]
         dp = _dot(g, v, ((1,), (1,)))  # [Tb, Sb]
         ds = p * (dp - dcap_ref[0, 0])
         dk_scr[...] += _dot(ds.astype(q.dtype), q, ((0,), (0,))) * scale
@@ -464,7 +466,7 @@ def _bwd_pallas(
     """S-tiled flash backward: two pallas_calls (dQ sweep over S; dK/dV
     sweep over T) sharing the tile-probability recomputation."""
     B, T, H, dh = q.shape
-    S = k_ctx.shape[1]
+    S, dv = k_ctx.shape[1], v_ctx.shape[-1]
     group = _groups(q, k_ctx)
     Hkv = H // group
     f32 = jnp.float32
@@ -491,6 +493,7 @@ def _bwd_pallas(
     t_spec, s_spec, row_spec, segq_spec, segc_spec = _tile_specs(
         Tb, Sb, dh, t_inner=False, group=group
     )
+    tv_spec, sv_spec = _tile_specs(Tb, Sb, dv, t_inner=False, group=group)[:2]
     dq = pallas_call(
         functools.partial(
             _dq_kernel, scale=scale, W=W, num_s=Sp // Sb, window=window
@@ -498,7 +501,7 @@ def _bwd_pallas(
         name=f"{name}_backward_dq",
         grid=(B, H, Tp // Tb, Sp // Sb),
         in_specs=[
-            t_spec, s_spec, s_spec, t_spec, row_spec, row_spec,
+            t_spec, s_spec, sv_spec, tv_spec, row_spec, row_spec,
             segq_spec, segc_spec,
         ],
         out_specs=t_spec,
@@ -513,6 +516,9 @@ def _bwd_pallas(
     t_spec2, s_spec2, row_spec2, segq_spec2, segc_spec2 = _tile_specs(
         Tb, Sb, dh, t_inner=True, group=group, num_t=num_t
     )
+    tv_spec2, sv_spec2 = _tile_specs(
+        Tb, Sb, dv, t_inner=True, group=group, num_t=num_t
+    )[:2]
     dk, dv = pallas_call(
         functools.partial(
             _dkv_kernel, scale=scale, W=W, num_t=num_t, group=group,
@@ -521,17 +527,17 @@ def _bwd_pallas(
         name=f"{name}_backward_dkv",
         grid=(B, Hkv, Sp // Sb, group * num_t),
         in_specs=[
-            t_spec2, s_spec2, s_spec2, t_spec2, row_spec2, row_spec2,
+            t_spec2, s_spec2, sv_spec2, tv_spec2, row_spec2, row_spec2,
             segq_spec2, segc_spec2,
         ],
-        out_specs=(s_spec2, s_spec2),
+        out_specs=(s_spec2, sv_spec2),
         out_shape=(
             jax.ShapeDtypeStruct((B, Hkv, Sp, dh), f32),
-            jax.ShapeDtypeStruct((B, Hkv, Sp, dh), f32),
+            jax.ShapeDtypeStruct((B, Hkv, Sp, dv), f32),
         ),
         scratch_shapes=[
             pltpu.VMEM((Sb, dh), f32),
-            pltpu.VMEM((Sb, dh), f32),
+            pltpu.VMEM((Sb, dv), f32),
         ],
         interpret=interpret,
     )(qp, kp, vp, gp, lse, dcap, segq_p, segc_p)
@@ -563,9 +569,11 @@ def windowed_attention(
     """Masked single-device flash attention, Pallas-fused fwd + bwd.
 
     Args:
-      q: `[B, T, H, dh]` rotary'd queries.
-      k_ctx/v_ctx: `[B, S, Hkv, dh]` context (W cache slots then T
-        current tokens, S = W + T; keys already rotary'd). `Hkv` divides
+      q: `[B, T, H, dh]` rotary'd queries; the scores are scaled by
+        `1/sqrt(dh)`.
+      k_ctx/v_ctx: `[B, S, Hkv, dh]` / `[B, S, Hkv, dv]` context (W cache
+        slots then T current tokens, S = W + T; keys already rotary'd;
+        the values' width `dv` may differ from the keys'). `Hkv` divides
         `H`: query head `h` reads key/value head `h // (H // Hkv)`.
       seg_q: `[B, T]` int32 query segment (episode) ids.
       seg_ctx: `[B, S]` int32 context segment ids (-1 = empty cache slot).
@@ -581,7 +589,7 @@ def windowed_attention(
       name: the kernels' names in the compiled program and a trace:
         `<name>_forward`, `<name>_backward_dq`, `<name>_backward_dkv`.
 
-    Returns `[B, T, H, dh]` attention output in q's dtype (math in f32),
+    Returns `[B, T, H, dv]` attention output in q's dtype (math in f32),
     differentiable w.r.t. q/k_ctx/v_ctx.
     """
     out, _ = _forward(

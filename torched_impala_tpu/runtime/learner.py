@@ -554,8 +554,8 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
     Returns `(agent, loss, resolved)`: `loss.vtrace_implementation` is
     never 'auto' afterwards, and `resolved` names what was chosen
     (`vtrace`, `lstm`, `fused_conv`, `max_pool`, `attention`,
-    `selective_scan`, `moe`, `devices`, `why`; a `Learner` adds `packed_convs`,
-    which needs the observations' shape).
+    `selective_scan`, `attention_latent`, `moe`, `devices`, `why`; a
+    `Learner` adds `packed_convs`, which needs the observations' shape).
 
     On one device the choice is per platform: the Pallas kernels on a
     TPU, the scan elsewhere (the model's own kernels pick compiled vs
@@ -639,6 +639,14 @@ def resolve_kernels(agent: Agent, loss: ImpalaLossConfig, mesh):
             if not dict(net.hybrid).get("scan_kernel", True)
             or platform != "tpu"
             else "pallas"
+        ),
+        # The hybrid core's latent attention (kind "latent"): the fused
+        # kernels `attention_latent_*` where `attention` is "pallas".
+        "attention_latent": (
+            dict(net.hybrid).get("attention_kernel")
+            if kind == "hybrid"
+            and "latent" in dict(net.hybrid).get("layers", ())
+            else None
         ),
         # The held experts' products of a routed core: XLA's ragged product
         # everywhere (ops/grouped_matmul.py).
